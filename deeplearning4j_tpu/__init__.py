@@ -26,4 +26,9 @@ workspaces); updaters are pure functions over optimizer-state pytrees.
 
 __version__ = "0.1.0"
 
+from deeplearning4j_tpu.common import compilecache as _compilecache
 from deeplearning4j_tpu.common.dtypes import DataType  # noqa: F401
+
+# the one place every entry path passes, before the process compiles
+# anything: decide where the persistent XLA compilation cache lives
+_compilecache.configure()

@@ -6,11 +6,23 @@ compile latency, which every fresh process pays in full at the first
 a content-addressed on-disk compilation cache (the TVM compile-cache
 idea): keyed by (HLO, compile options, backend version), so a second
 process compiling the SAME network loads the serialized executable
-instead of re-running XLA. :func:`enable_persistent_cache` points jax at
-a per-user cache dir; every train-step/inference funnel calls it before
-its first ``jax.jit`` so the cache is on by default
-(``DL4J_TPU_COMPILE_CACHE=0`` opts out, ``DL4J_TPU_COMPILE_CACHE_DIR``
-relocates it).
+instead of re-running XLA.
+
+Where the cache lives is decided ONCE, by :func:`configure`, which the
+package ``__init__`` calls — the one place every entry path passes, and
+early enough that the process has not compiled anything yet (jax
+decides "is there a cache?" at its first compile and never asks again):
+
+* ``JAX_COMPILATION_CACHE_DIR`` set: jax itself uses that directory and
+  this package sets none (the operator placed the cache; e.g. a machine
+  that keeps one across runs).
+* unset: ``<checkout>/.jax_cache``, computed from this file's path —
+  never ``$HOME``, a temp name, a pid or the time, because the path is
+  part of where a later run looks. The directory is git-ignored.
+* a process asked for the CPU platform gets no cache unless
+  ``DL4J_TPU_COMPILE_CACHE=1`` says so explicitly (see
+  :func:`resolve_cache_dir`); ``DL4J_TPU_COMPILE_CACHE=0`` opts out
+  everywhere.
 
 :class:`RetraceGuard` is the other half of compile-latency hygiene: the
 cache cannot help a process that keeps compiling NEW programs. jit
@@ -23,88 +35,68 @@ from __future__ import annotations
 
 import logging
 import os
-import threading
-from typing import Optional
+from typing import Mapping, Optional
 
 from deeplearning4j_tpu.common import telemetry
 from deeplearning4j_tpu.common.environment import Environment
 
 log = logging.getLogger("deeplearning4j_tpu")
 
-_lock = threading.Lock()
-_enabled_dir: Optional[str] = None
+#: jax's own variable; when it is set this package sets no directory
+JAX_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+_TRUE = ("1", "true", "True", "yes")
 
 
-def default_cache_dir() -> str:
-    return os.path.join(os.path.expanduser("~"), ".cache",
-                        "deeplearning4j_tpu", "xla-cache")
+def checkout_cache_dir() -> str:
+    """``<checkout>/.jax_cache`` — next to the package directory."""
+    pkg = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return os.path.join(os.path.dirname(pkg), ".jax_cache")
 
 
-def enable_persistent_cache() -> Optional[str]:
-    """Idempotently enable jax's on-disk compilation cache. Returns the
-    cache dir, or None when disabled. Safe to call from every funnel:
-    only the first call mutates jax config.
+def resolve_cache_dir(environ: Mapping[str, str],
+                      platforms: Optional[str]) -> Optional[str]:
+    """The directory this package sets, or None when it sets none.
 
-    Default ON for accelerator backends (TPU/GPU — where XLA compiles
-    for minutes and D2H copies are real copies). On the CPU backend the
-    cache requires an EXPLICIT ``DL4J_TPU_COMPILE_CACHE=1``: cpu
+    ``platforms`` is what the process was ASKED to run on
+    (``jax.config.jax_platforms``: ``JAX_PLATFORMS`` or an earlier
+    ``config.update``) — asking the backend itself would initialise it,
+    and take the chip, at import. A process pinned to ``cpu`` gets no
+    cache without an explicit ``DL4J_TPU_COMPILE_CACHE=1``: cpu
     ``device_get``/``np.asarray`` return zero-copy views of XLA
     buffers, and a cache-loaded executable honors buffer donation that
     a freshly-compiled CPU one may not — code holding views across a
     donating step (a pattern CPU-only tests get away with) would see
     its arrays mutate."""
-    global _enabled_dir
-    env = Environment.get()
-    if not env.compile_cache:
+    if environ.get(JAX_CACHE_ENV):
         return None
-    with _lock:
-        if _enabled_dir is not None:
-            return _enabled_dir
-        import jax
-        if "DL4J_TPU_COMPILE_CACHE" not in os.environ and \
-                jax.default_backend() == "cpu":
-            return None
-        d = env.compile_cache_dir or default_cache_dir()
-        try:
-            os.makedirs(d, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", d)
-            # cache unconditionally: the default gates (>=1s compile,
-            # min entry size) exist for shared-filesystem TPU pods;
-            # here losing sub-second CPU entries would make the
-            # second-process win untestable and skip exactly the
-            # programs unit-scale users compile
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              -1)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.0)
-            # jax memoizes "is the cache used?" at the FIRST compile of
-            # the process — which has usually already happened (PRNGKey
-            # init, dtype conversions) by the time a train step is
-            # built. Drop that verdict so the new dir takes effect.
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()
-        except Exception as e:          # unwritable dir / exotic jax
-            log.warning("persistent compilation cache disabled: %s", e)
-            return None
-        _enabled_dir = d
-        log.debug("persistent XLA compilation cache at %s", d)
-        return d
+    flag = environ.get("DL4J_TPU_COMPILE_CACHE")
+    if flag is not None and flag not in _TRUE:
+        return None
+    on_cpu = (platforms or "").split(",")[0].strip() == "cpu"
+    if on_cpu and flag is None:
+        return None
+    return checkout_cache_dir()
 
 
-def _reset_for_tests():
-    """Disable the cache and forget the enabled state so a test can
-    exercise enablement without leaving the persistent cache live for
-    the rest of the process (cache-LOADED executables honor donation —
-    see enable_persistent_cache — which would perturb unrelated tests
-    holding numpy views of donated buffers)."""
-    global _enabled_dir
-    with _lock:
-        if _enabled_dir is not None:
-            import jax
-            from jax._src import compilation_cache as _cc
-            jax.config.update("jax_compilation_cache_dir", None)
-            _cc.reset_cache()
-        _enabled_dir = None
+def configure() -> Optional[str]:
+    """Apply :func:`resolve_cache_dir` to jax's config; returns the
+    directory set here (None: jax's own configuration stands). Called
+    from the package ``__init__``; touches no file and no backend —
+    jax creates the directory at its first cache write, and an
+    unwritable one fails there, loudly."""
+    import jax
+    d = resolve_cache_dir(os.environ, jax.config.jax_platforms)
+    if d is None:
+        return None
+    jax.config.update("jax_compilation_cache_dir", d)
+    # cache unconditionally: the default gates (>=1s compile, min
+    # entry size) exist for shared-filesystem TPU pods; here losing
+    # sub-second entries would skip exactly the programs unit-scale
+    # users compile, and make "a warm run adds no entries" untestable
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    log.debug("persistent XLA compilation cache at %s", d)
+    return d
 
 
 def signature_of(*xs) -> tuple:

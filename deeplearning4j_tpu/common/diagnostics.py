@@ -250,6 +250,31 @@ def memory_report(model=None) -> dict:
     return report
 
 
+#: published single-chip peaks, keyed by jax ``device_kind`` — THE
+#: table (layerprof, bench.py and the benchmarks read it). A device
+#: that is not here gets no percent-of-peak number from anything.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {
+        "tflops": 197.0,        # bf16
+        "hbm_gbps": 819.0,
+        "source": "Google Cloud documentation, 'TPU v5e': 197 TFLOP/s "
+                  "bf16, 16 GB HBM at 819 GB/s per chip",
+    },
+}
+
+
+def device_peaks(device_kind: Optional[str] = None) -> Optional[dict]:
+    """The :data:`DEVICE_PEAKS` row of ``device_kind`` (default: the
+    process's first device), or None when the device is not in the
+    table — callers then report no ``pct_of_roof`` /
+    ``pct_compute_peak`` / ``pct_hbm_peak`` instead of assuming a
+    chip."""
+    if device_kind is None:
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    return DEVICE_PEAKS.get(device_kind)
+
+
 def roofline(flops: float, bytes_moved: float, step_seconds: float,
              peak_tflops: Optional[float] = None,
              peak_hbm_gbps: Optional[float] = None) -> dict:

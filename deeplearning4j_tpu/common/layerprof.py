@@ -69,10 +69,11 @@ log = logging.getLogger(__name__)
 #: prefix all scope annotations carry inside HLO metadata
 SCOPE_PREFIX = "dl4j."
 
-#: v5e peaks, mirroring benchmarks/cost_util.py (library code must not
-#: import the benchmarks package)
-DEFAULT_PEAK_TFLOPS = 197.0
-DEFAULT_HBM_GBPS = 819.0
+#: the device whose ridge point weighs the static partition when the
+#: present device has no row in ``diagnostics.DEVICE_PEAKS`` (the CPU
+#: backend): a weighting for ``bound``/``est_ms``, never a peak — the
+#: report says so and carries no percent-of-roof
+WEIGHTING_DEVICE_KIND = "TPU v5 lite"
 
 _layer_seconds = telemetry.histogram(
     "dl4j_layer_seconds",
@@ -663,8 +664,24 @@ def attribute_compiled(compiled, *, model_name: Optional[str] = None,
     sf = (total_flops / raw_flops) if raw_flops else 0.0
     sb = (total_bytes / raw_bytes) if raw_bytes else 0.0
 
-    peak_tf = peak_tflops or DEFAULT_PEAK_TFLOPS
-    peak_bw = peak_hbm_gbps or DEFAULT_HBM_GBPS
+    from deeplearning4j_tpu.common import diagnostics
+    kind = jax.devices()[0].device_kind
+    row = diagnostics.device_peaks(kind)
+    if peak_tflops and peak_hbm_gbps:
+        peaks = {"tflops": peak_tflops, "hbm_gbps": peak_hbm_gbps,
+                 "basis": "caller-supplied peaks"}
+    elif row:
+        peaks = {"tflops": row["tflops"], "hbm_gbps": row["hbm_gbps"],
+                 "basis": f"DEVICE_PEAKS[{kind!r}]: {row['source']}"}
+    else:
+        row = diagnostics.DEVICE_PEAKS[WEIGHTING_DEVICE_KIND]
+        peaks = {"tflops": row["tflops"], "hbm_gbps": row["hbm_gbps"],
+                 "weighting_only": True,
+                 "basis": f"device {kind!r} is not in DEVICE_PEAKS: "
+                          f"the {WEIGHTING_DEVICE_KIND!r} ridge weighs "
+                          "the static partition (bound, est_ms) and "
+                          "no percent-of-roof is reported"}
+    peak_tf, peak_bw = peaks["tflops"], peaks["hbm_gbps"]
     ridge = peak_tf * 1e12 / (peak_bw * 1e9)
 
     layers = {}
@@ -705,7 +722,7 @@ def attribute_compiled(compiled, *, model_name: Optional[str] = None,
 
     report = {
         "model": model_name,
-        "peaks": {"tflops": peak_tf, "hbm_gbps": peak_bw},
+        "peaks": peaks,
         "totals": {
             "flops": total_flops,
             "bytes": total_bytes,
@@ -799,6 +816,8 @@ def join_dynamic(report: dict, layer_ms: Dict[str, dict],
     b% of roof"."""
     from deeplearning4j_tpu.common import diagnostics
     peaks = report.get("peaks", {})
+    if peaks.get("weighting_only"):
+        peaks = {}                  # no peak for this device: no percent
     for name, ent in report["layers"].items():
         ms = layer_ms.get(name)
         if not ms:

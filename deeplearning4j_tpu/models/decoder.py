@@ -177,8 +177,7 @@ class DecoderLM:
 
     # -- one fused decode step over the paged pool ----------------------
     def decode_step(self, params, tokens, positions, k_pool, v_pool,
-                    block_tables, *, paged: bool = False,
-                    interpret=None):
+                    block_tables, *, paged: bool = False):
         """One token for every sequence in the decode batch.
 
         ``tokens``/``positions`` ``[b]`` int32 (position = index of
@@ -215,8 +214,7 @@ class DecoderLM:
             vp = jnp.reshape(vf[i], (nb, bs, c.n_heads, c.head_dim))
             if paged:
                 a = paged_decode_attention(q, kp, vp, block_tables,
-                                           lengths,
-                                           interpret=interpret)
+                                           lengths)
             else:
                 a = paged_attention_reference(q, kp, vp, block_tables,
                                               lengths)

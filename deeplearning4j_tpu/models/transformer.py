@@ -300,42 +300,6 @@ class DistributedTransformerLM:
             loss = lax.pmean(loss, "seq")
         return loss
 
-    # -- gradient reduction -------------------------------------------
-    def _reduce_grads(self, grads, specs):
-        """Cross-rank gradient reduction.
-
-        Under jax's VMA-typed shard_map (jax >= 0.8, ``lax.pcast``
-        exists) this is a NO-OP: every implicit unvarying→varying cast
-        in the forward (a replicated param meeting a data/seq/time-
-        sharded activation) transposes to a psum over exactly the
-        right axes, so the grads arriving here are already complete —
-        verified leaf-for-leaf against a single-device reference in
-        test_transformer_5d. On older jax the manual rule applies:
-        psum each leaf over EVERY mesh axis absent from its
-        PartitionSpec. Size-1 axes are psummed too — numerically a
-        no-op, but it is what marks the leaf replicated over that
-        axis for the shard_map replication checker (skipping them is
-        why the ring-CP step used to be rejected by check_rep: a
-        size-1 ``data`` axis never entered the grads' inferred
-        replication set, so the params' out_specs failed)."""
-        if hasattr(lax, "pcast"):
-            return grads
-        present = set(self.mesh.axis_names)
-
-        def red(g, spec):
-            named = set()
-            for entry in tuple(spec):
-                if entry is None:
-                    continue
-                if isinstance(entry, (tuple, list)):
-                    named.update(entry)
-                else:
-                    named.add(entry)
-            todo = tuple(ax for ax in present if ax not in named)
-            return lax.psum(g, todo) if todo else g
-
-        return _zip_map(red, grads, specs)
-
     # -- public API ----------------------------------------------------
     def data_specs(self):
         if self.ring:
@@ -360,25 +324,22 @@ class DistributedTransformerLM:
             # same CE). Autodiff sums all rank-copies through the
             # collective transposes, so each rank must contribute
             # loss/n_copies for the grads to come out exactly dL/dθ
-            # (verified leaf-for-leaf in test_transformer_5d). Under
-            # VMA-typed jax (>= 0.8) the copy count is the product of
-            # the loss's varying axes; on older jax there is no vma
-            # type and every rank of the whole mesh seeds cotangent 1
-            # through the rep-checked transpose, so the count is the
-            # full mesh size.
-            if hasattr(lax, "pcast"):
-                vma = tuple(getattr(getattr(loss, "aval", None),
-                                    "vma", ()))
-                scale = int(np.prod([sizes.get(a, 1)
-                                     for a in vma])) or 1
-            else:
-                scale = int(np.prod(list(sizes.values()))) or 1
+            # (verified leaf-for-leaf in test_transformer_5d): the
+            # copy count is the product of the loss's varying axes.
+            scale = int(np.prod([sizes.get(a, 1)
+                                 for a in jax.typeof(loss).vma])) or 1
             return loss / scale, loss
 
         def body(params, opt_state, ids, labels, it):
+            # no manual gradient reduction: under VMA-typed shard_map
+            # every implicit unvarying->varying cast in the forward (a
+            # replicated param meeting a data/seq/time-sharded
+            # activation) transposes to a psum over exactly the right
+            # axes, so the grads arrive complete — verified
+            # leaf-for-leaf against a single-device reference in
+            # test_transformer_5d
             grads, loss = jax.grad(objective, has_aux=True)(
                 params, ids, labels)
-            grads = self._reduce_grads(grads, specs)
             upd, new_state = self.updater.apply(grads, opt_state, it)
             new_params = jax.tree_util.tree_map(
                 lambda p_, u: p_ - u, params, upd)
@@ -445,7 +406,6 @@ def _unvary(x, mesh):
     total size, so divide it back out — numerically a no-op that gives
     the checker the collective it wants."""
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    axes = tuple(getattr(getattr(x, "aval", None), "vma", ())
-                 ) or tuple(mesh.axis_names)
+    axes = tuple(jax.typeof(x).vma) or tuple(mesh.axis_names)
     n = int(np.prod([sizes.get(a, 1) for a in axes]))
     return lax.psum(x, axes) / n

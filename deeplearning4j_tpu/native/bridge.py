@@ -28,6 +28,8 @@ _SO_PATH = os.environ.get(
 _lib = None
 _lock = threading.Lock()
 _build_attempted = False
+#: how this process got (or did not get) the library — see status()
+_status = ("unattempted", "")
 
 
 def _configure(lib):
@@ -77,17 +79,34 @@ def _configure(lib):
     return lib
 
 
+def _so_mtime() -> float:
+    try:
+        return os.path.getmtime(_SO_PATH)
+    except OSError:
+        return -1.0
+
+
 def ensure_built(force: bool = False) -> bool:
-    """Build (once) and load the native library. Returns success."""
-    global _lib, _build_attempted
+    """Bring the native library up to date and load it (once per
+    process). Returns success; :func:`status` says how it went.
+
+    The build always defers to ``make -C native``: a no-op when
+    ``native/build/`` is current, a rebuild when ``dl4j_native.cpp`` is
+    newer or the directory is missing — so a disk that carries a stale
+    ``.so`` and a fresh checkout that carries none end up with the
+    same library. A failed build selects the Python fallbacks with a
+    warning; a path that must not run on fallbacks checks
+    :func:`status` (``chip_smoke.py`` does)."""
+    global _lib, _build_attempted, _status
     if os.environ.get("DL4J_TPU_DISABLE_NATIVE"):
+        _status = ("absent", "DL4J_TPU_DISABLE_NATIVE is set")
         return False
     if _lib is not None and not force:
         # lock-free fast path: every native entry point calls this,
         # so the loaded case must not serialize threads
         return True
     with _lock:
-        if _lib is not None:
+        if _lib is not None and not force:
             return True
         if _build_attempted and not force:
             return False
@@ -102,33 +121,44 @@ def ensure_built(force: bool = False) -> bool:
                     f"(build it first, e.g. `make -C native "
                     f"sanitize`)")
             _lib = _configure(ctypes.CDLL(_SO_PATH))
+            _status = ("loaded", f"DL4J_TPU_NATIVE_LIB={_SO_PATH}")
             return True
-        if not os.path.exists(_SO_PATH) or force:
-            if not os.path.isdir(_NATIVE_DIR):
-                return False
-            import logging
-            log = logging.getLogger(__name__)
-            log.info("building native runtime (make -C %s) — one-time,"
-                     " may take up to ~2 min", _NATIVE_DIR)
-            try:
-                subprocess.run(["make", "-C", _NATIVE_DIR],
-                               check=True, capture_output=True,
-                               timeout=120)
-            except subprocess.CalledProcessError as e:
-                log.warning("native build failed, using Python "
-                            "fallbacks:\n%s",
-                            e.stderr.decode(errors="replace")[-2000:])
-                return False
-            except Exception as e:
-                log.warning("native build unavailable (%s), using "
-                            "Python fallbacks", e)
-                return False
+        import logging
+        log = logging.getLogger(__name__)
+        before = _so_mtime()
+        try:
+            subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
+                           capture_output=True, timeout=120)
+        except subprocess.CalledProcessError as e:
+            err = e.stderr.decode(errors="replace")[-2000:]
+            log.warning("native build failed, using Python "
+                        "fallbacks:\n%s", err)
+            _status = ("absent", f"make failed: {err.strip()[-300:]}")
+            return False
+        except (OSError, subprocess.TimeoutExpired) as e:
+            log.warning("native build unavailable (%s), using Python "
+                        "fallbacks", e)
+            _status = ("absent", f"make unavailable: {e}")
+            return False
         try:
             _lib = _configure(ctypes.CDLL(_SO_PATH))
-            return True
-        except OSError:
+        except OSError as e:
             _lib = None
+            log.warning("native library failed to load (%s), using "
+                        "Python fallbacks", e)
+            _status = ("absent", f"load failed: {e}")
             return False
+        _status = ("built" if _so_mtime() != before else "loaded",
+                   _SO_PATH)
+        return True
+
+
+def status() -> Tuple[str, str]:
+    """``(state, detail)`` of the native library in this process:
+    ``built`` (make compiled it just now), ``loaded`` (make found it
+    current), ``absent`` (Python fallbacks in use; detail says why) or
+    ``unattempted`` (nothing has asked for it yet)."""
+    return _status
 
 
 def available() -> bool:

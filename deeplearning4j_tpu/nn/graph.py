@@ -25,6 +25,7 @@ from deeplearning4j_tpu.nn.conf.constraints import apply_constraints
 from deeplearning4j_tpu.nn.conf.layers import BaseOutputLayer
 from deeplearning4j_tpu.nn.gradient import apply_gradient_normalization
 from deeplearning4j_tpu.nn.multilayer import _as_jnp
+from deeplearning4j_tpu.ops import kernel_select
 from deeplearning4j_tpu.optimize.listeners import TrainingListener
 
 log = logging.getLogger("deeplearning4j_tpu")
@@ -350,9 +351,6 @@ class ComputationGraph:
 
     # ------------------------------------------------------------------
     def _build_train_step(self):
-        from deeplearning4j_tpu.common.compilecache import \
-            enable_persistent_cache
-        enable_persistent_cache()    # second process loads, not compiles
         conf = self.conf
         out_confs = self.output_layer_confs()
         updaters = {name: (conf.vertices[name].content.updater
@@ -751,6 +749,7 @@ class ComputationGraph:
         return params_to_dense(self.params, self._fsdp_specs)
 
     # ------------------------------------------------------------------
+    @kernel_select.marks_partitions
     def fit(self, data, labels=None, *, n_epochs: int = 1):
         """fit(x, y) | fit(DataSet/MultiDataSet) | fit(iterator)."""
         if not self._initialized:
@@ -886,6 +885,7 @@ class ComputationGraph:
         return pool.pop()
 
     # ------------------------------------------------------------------
+    @kernel_select.marks_partitions
     def fit_steps(self, ds, steps: int):
         """Run ``steps`` train iterations on one device-resident batch
         in ONE jit dispatch (lax.fori_loop over the compiled step — the
@@ -1091,6 +1091,7 @@ class ComputationGraph:
                                self.epoch_count)
 
     # ------------------------------------------------------------------
+    @kernel_select.marks_partitions
     def output(self, *inputs, train: bool = False, mask=None):
         """Returns list of output activations (single array if one
         output) — reference: ComputationGraph.output(INDArray...)."""
@@ -1229,6 +1230,7 @@ class ComputationGraph:
         lm = getattr(ds, "labels_mask", None)
         return [lm] if lm is not None else None
 
+    @kernel_select.marks_partitions
     def score(self, dataset=None) -> float:
         if dataset is None:
             return float(self._score)
@@ -1286,15 +1288,14 @@ class ComputationGraph:
                 out[f"{name}_{pname}"] = p
         return out
 
-    def layer_report(self, data=None, labels=None, **roofline_kw):
-        """Per-vertex flops/bytes/roofline attribution of the compiled
-        train step (common.layerprof): lowers the jitted step at the
-        given batch (or the last fitted batch's shapes), partitions
-        ``cost_analysis()`` by the ``dl4j.<vertex>`` scopes, and joins
-        the kernel-select decisions recorded at trace time.  Also
-        published to ``GET /api/layers`` and the ``dl4j_layer_*``
-        metrics.  Lowering only — nothing executes, buffers are not
-        donated."""
+    @kernel_select.marks_partitions
+    def lower_train_step(self, data=None, labels=None):
+        """The jitted train step lowered at the given batch (or the
+        last fitted batch's shapes) — the one home of the coupling to
+        the step's argument list (``layer_report``, the benchmarks'
+        cost analysis and ``chip_smoke.py`` read the compiled program
+        through it).  Lowering only — nothing executes, buffers are
+        not donated."""
         if not self._initialized:
             self.init()
         self._sync_updater_layout()
@@ -1308,8 +1309,8 @@ class ComputationGraph:
             shapes = getattr(self, "_layerprof_shapes", None)
             if shapes is None:
                 raise ValueError(
-                    "layer_report needs a batch: pass (data, labels) "
-                    "or fit at least one batch first")
+                    "lowering the train step needs a batch: pass "
+                    "(data, labels) or fit at least one batch first")
             xs, ys = shapes
             data = [np.zeros(s, dtype=d) for s, d in xs]
             labels = [np.zeros(s, dtype=d) for s, d in ys]
@@ -1321,9 +1322,19 @@ class ComputationGraph:
         labs = [_as_jnp(y, self._dtype) for y in labels]
         states_in = self._with_zero_rnn_states(
             self.states, int(inputs[0].shape[0]))
-        lowered = self._train_step.lower(
+        return self._train_step.lower(
             self.params, states_in, self.updater_states, inputs, labs,
             None, None, jnp.asarray(0), jax.random.PRNGKey(0))
+
+    def layer_report(self, data=None, labels=None, **roofline_kw):
+        """Per-vertex flops/bytes/roofline attribution of the compiled
+        train step (common.layerprof): lowers the jitted step at the
+        given batch (or the last fitted batch's shapes), partitions
+        ``cost_analysis()`` by the ``dl4j.<vertex>`` scopes, and joins
+        the kernel-select decisions recorded at trace time.  Also
+        published to ``GET /api/layers`` and the ``dl4j_layer_*``
+        metrics."""
+        lowered = self.lower_train_step(data, labels)
         types = {layerprof.sanitize(n):
                  type(self.conf.vertices[n].content).__name__
                  for n in self._topo}

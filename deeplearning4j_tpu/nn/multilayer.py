@@ -29,6 +29,7 @@ from deeplearning4j_tpu.nn.conf.builders import (BackpropType,
                                                  MultiLayerConfiguration)
 from deeplearning4j_tpu.nn.conf.layers import BaseOutputLayer
 from deeplearning4j_tpu.nn.gradient import apply_gradient_normalization
+from deeplearning4j_tpu.ops import kernel_select
 from deeplearning4j_tpu.optimize.listeners import TrainingListener
 
 log = logging.getLogger("deeplearning4j_tpu")
@@ -288,9 +289,6 @@ class MultiLayerNetwork:
 
     # ------------------------------------------------------------------
     def _build_train_step(self):
-        from deeplearning4j_tpu.common.compilecache import \
-            enable_persistent_cache
-        enable_persistent_cache()    # second process loads, not compiles
         conf = self.conf
         out_layer = self.output_layer_conf
         want_logits = out_layer.wants_logits()
@@ -692,6 +690,7 @@ class MultiLayerNetwork:
         return params_to_dense(self.params, self._fsdp_specs)
 
     # ------------------------------------------------------------------
+    @kernel_select.marks_partitions
     def fit(self, data, labels=None, *, n_epochs: int = 1):
         """fit(x, y) | fit(DataSet) | fit(iterator[, n_epochs])."""
         if not self._initialized:
@@ -736,6 +735,7 @@ class MultiLayerNetwork:
         return self
 
     # ------------------------------------------------------------------
+    @kernel_select.marks_partitions
     def fit_steps(self, ds, steps: int):
         """Run ``steps`` train iterations on one device-resident batch
         in ONE jit dispatch (lax.fori_loop over the compiled step; the
@@ -1042,6 +1042,7 @@ class MultiLayerNetwork:
         return self._rnn_stream_states.get(f"layer_{layer_idx}")
 
     # ------------------------------------------------------------------
+    @kernel_select.marks_partitions
     def output(self, x, train: bool = False, mask=None):
         """Inference forward pass (reference: ``output(INDArray)``)."""
         if not self._initialized:
@@ -1053,6 +1054,7 @@ class MultiLayerNetwork:
                                want_logits=False, mask=mask)
         return out
 
+    @kernel_select.marks_partitions
     def feed_forward(self, x, train: bool = False) -> list:
         """All layer activations (reference: feedForward)."""
         if not self._initialized:
@@ -1083,6 +1085,7 @@ class MultiLayerNetwork:
         """Argmax class predictions (reference: predict)."""
         return np.asarray(jnp.argmax(self.output(x), axis=-1))
 
+    @kernel_select.marks_partitions
     def score(self, dataset=None) -> float:
         """Latest minibatch score, or score of a given DataSet."""
         if dataset is None:
@@ -1171,6 +1174,7 @@ class MultiLayerNetwork:
                 lambda a: a, self.updater_states)
         return net
 
+    @kernel_select.marks_partitions
     def layer_report(self, data=None, labels=None, **roofline_kw):
         """Per-layer flops/bytes/roofline attribution of the compiled
         train step (common.layerprof): lowers the jitted step at the

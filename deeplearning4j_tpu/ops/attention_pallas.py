@@ -30,8 +30,9 @@ off (``0``); unset, the kernel auto-engages on TPU when t_k reaches
 ``FLASH_MIN_SEQ`` (below ~4k the XLA dense lowering wins outright —
 BENCH_notes_r03) OR when the would-be scores tensor alone would eat
 more than ``HBM_HEADROOM_FRACTION`` of the device's free HBM.
-Off-TPU the kernel runs in Pallas interpret mode (the bn_pallas.py
-pattern), so CPU tests exercise the SAME code path the chip runs.
+Off-TPU the kernel runs in Pallas interpret mode
+(``kernel_select.interpret_mode`` — the platform alone decides), so
+CPU tests exercise the SAME code path the chip runs.
 """
 from __future__ import annotations
 
@@ -127,7 +128,7 @@ def select_attention_backend(q_shape: Tuple[int, ...],
     def _auto():
         plat = platform
         if plat is None:
-            plat = jax.devices()[0].platform
+            plat = kernel_select.platform()
         if plat != "tpu":
             return False, f"auto: platform '{plat}' is not tpu"
         t_k = k_shape[-2]
@@ -152,14 +153,13 @@ def select_attention_backend(q_shape: Tuple[int, ...],
 
 
 def flash_sdpa(q, k, v, scale: Optional[float] = None, key_mask=None,
-               block_q: int = 1024, block_k: int = 1024,
-               interpret: Optional[bool] = None):
+               block_q: int = 1024, block_k: int = 1024):
     """Run sdpa_core semantics on the Pallas kernel:
     softmax(q k^T * scale, masked) v. q/k/v [b, h, t, d] or
     [b, t, d]; key_mask [b, t_k] (0 = masked) or None. Differentiable
     (the kernel carries its own custom VJP; the scale pre-multiply
-    composes). ``interpret=None`` resolves to interpret mode off-TPU,
-    so gradient checks exercise the chip's code path."""
+    composes). Off-TPU the kernel runs in interpret mode, so gradient
+    checks exercise the chip's code path."""
     from deeplearning4j_tpu.parallel.sequence import flash_attention
     squeeze_heads = q.ndim == 3
     if squeeze_heads:
@@ -179,7 +179,7 @@ def flash_sdpa(q, k, v, scale: Optional[float] = None, key_mask=None,
     # metadata without stealing the attribution match
     with jax.named_scope("pallas.flash_attention"):
         out = flash_attention(q, k, v, False, block_q, block_k,
-                              interpret, key_mask)
+                              key_mask)
     return out[:, 0] if squeeze_heads else out
 
 
@@ -227,18 +227,45 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables,
     return out.astype(q.dtype)
 
 
+#: lane width of the per-head score/statistic slabs inside the paged
+#: kernel — heads pad up to one full vreg row
+_PAGED_HEAD_LANES = 128
+
+
 def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref,
-                         out_ref, m_ref, l_ref, acc_ref, *,
-                         block_size: int, scale: float):
+                         seg_ref, segt_ref, out_ref, m_ref, l_ref,
+                         acc_ref, *, block_size: int, scale: float):
     """Online-softmax accumulation over one sequence's KV blocks.
     Grid (batch, max_blocks), j innermost; the block table picks the
     KV block each j step streams in (scalar-prefetch index map), so
-    only table-listed blocks ever leave HBM."""
+    only table-listed blocks ever leave HBM.
+
+    Everything is a lane-dense 2-D slab: a KV block arrives as
+    ``[block, h*d]`` (the pool's own memory order — heads are
+    contiguous lane segments), and the per-head reductions are
+    matmuls against the 0/1 segment matrix ``seg [h*d, H]`` (column
+    ``i // d`` of row ``i``; ``H`` = heads padded to 128 lanes), its
+    transpose broadcasting per-head scalars back over the head's
+    lanes. That keeps the head axis out of every batch dimension,
+    slice and 1-D vector (the ``[block, h, d]`` form batched
+    ``dot_general`` over a non-leading axis and read ``m_ref[:, 0]``
+    lane slices, which Mosaic does not lower), at the cost of MXU
+    passes over zeros. Padded head lanes carry zeros through
+    (``segt`` has no row for them)."""
     import jax.experimental.pallas as pl
 
     b = pl.program_id(0)
     j = pl.program_id(1)
     n_j = pl.num_programs(1)
+    f32 = jnp.float32
+    exact = jax.lax.Precision.HIGHEST     # 0/1 matrices: keep f32 sums
+
+    def per_lane(x):
+        """Per-head ``[1, H]`` -> per-lane ``[1, h*d]`` (8 sublanes so
+        the matmul tiles; row 0 is the answer)."""
+        x8 = jnp.broadcast_to(x, (8, x.shape[1]))
+        return jnp.dot(x8, segt_ref[...], preferred_element_type=f32,
+                       precision=exact)[0:1, :]
 
     @pl.when(j == 0)
     def _init():                                  # noqa: ANN202
@@ -246,87 +273,103 @@ def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[...].astype(jnp.float32)            # [h, d]
-    k = k_ref[...].astype(jnp.float32)            # [block, h, d]
-    v = v_ref[...].astype(jnp.float32)
-    # per-head scores: contract d, batch over h -> [h, block]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (2,)), ((0,), (1,)))) * scale
+    q = q_ref[...].astype(f32)                    # [1, h*d]
+    k = k_ref[...].astype(f32)                    # [block, h*d]
+    v = v_ref[...].astype(f32)
+    # per-head scores: sum each head's d lanes of q*k -> [block, H]
+    s = jnp.dot(k * q, seg_ref[...], preferred_element_type=f32,
+                precision=exact) * scale
     token_idx = (j * block_size
-                 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
+                 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
     s = jnp.where(token_idx < lens_ref[b], s, _PAGED_NEG_INF)
 
-    m_prev = m_ref[:, 0]                          # [h]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+    m_prev = m_ref[...]                           # [1, H]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
     corr = jnp.exp(m_prev - m_new)
-    p = jnp.where(s <= _PAGED_NEG_INF / 2, 0.0,
-                  jnp.exp(s - m_new[:, None]))    # [h, block]
-    l_new = corr * l_ref[:, 0] + jnp.sum(p, axis=1)
-    # p @ v batched over h: [h, block] x [block, h, d] -> [h, d]
-    pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((0,), (1,))))
-    acc_ref[...] = acc_ref[...] * corr[:, None] + pv
-    m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+    p = jnp.where(s <= _PAGED_NEG_INF / 2, 0.0, jnp.exp(s - m_new))
+    l_ref[...] = corr * l_ref[...] + jnp.sum(p, axis=0, keepdims=True)
+    m_ref[...] = m_new
+    # p @ v per head: broadcast each head's weight over its lanes,
+    # weigh v, fold the block's tokens -> [1, h*d]
+    p_lanes = jnp.dot(p, segt_ref[...], preferred_element_type=f32,
+                      precision=exact)            # [block, h*d]
+    acc_ref[...] = (acc_ref[...] * per_lane(corr)
+                    + jnp.sum(p_lanes * v, axis=0, keepdims=True))
 
     @pl.when(j == n_j - 1)
     def _finish():                                # noqa: ANN202
-        denom = jnp.maximum(l_ref[:, 0], 1e-30)
-        out_ref[...] = (acc_ref[...] / denom[:, None]
-                        ).astype(out_ref.dtype)
+        denom = per_lane(jnp.maximum(l_ref[...], 1e-30))
+        out_ref[...] = (acc_ref[...] / denom).astype(out_ref.dtype)
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
-                           scale: Optional[float] = None,
-                           interpret: Optional[bool] = None):
+                           scale: Optional[float] = None):
     """Pallas paged decode attention — same contract as
     :func:`paged_attention_reference`, but the KV pool stays in HBM
     and only the blocks each sequence's table names are streamed into
     VMEM (scalar-prefetched index map), one online-softmax fold per
-    block. ``interpret=None`` resolves to interpret mode off-TPU so
-    CPU conformance tests run the chip's code path."""
+    block. Compiled by Mosaic on a TPU backend, interpreted everywhere
+    else (``kernel_select.interpret_mode``), so CPU conformance tests
+    run the chip's code path."""
     import functools
 
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    from deeplearning4j_tpu.ops import kernel_select
+
     b, h, d = q.shape
+    hd = h * d
     block = int(k_pool.shape[1])
     max_blocks = int(block_tables.shape[1])
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    lanes = -(-h // _PAGED_HEAD_LANES) * _PAGED_HEAD_LANES
+    # seg[i, i // d] = 1: lane i of the flat [h*d] axis belongs to
+    # head i // d (a compile-time constant; XLA folds it)
+    seg = (jnp.arange(hd, dtype=jnp.int32)[:, None] // d
+           == jnp.arange(lanes, dtype=jnp.int32)[None, :]
+           ).astype(jnp.float32)                  # [h*d, H]
+
+    def row(i, j, tables, lens):                  # one sequence's q/out
+        return (i, 0, 0)
+
+    def kv_block(i, j, tables, lens):             # table-picked block
+        return (tables[i, j], 0, 0)
+
+    def whole(i, j, tables, lens):
+        return (0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,          # block_tables, lengths
         grid=(b, max_blocks),
         in_specs=[
-            pl.BlockSpec((None, h, d),
-                         lambda i, j, tables, lens: (i, 0, 0)),
-            pl.BlockSpec((None, block, h, d),
-                         lambda i, j, tables, lens:
-                         (tables[i, j], 0, 0, 0)),
-            pl.BlockSpec((None, block, h, d),
-                         lambda i, j, tables, lens:
-                         (tables[i, j], 0, 0, 0)),
+            pl.BlockSpec((None, 1, hd), row),
+            pl.BlockSpec((None, block, hd), kv_block),
+            pl.BlockSpec((None, block, hd), kv_block),
+            pl.BlockSpec((hd, lanes), whole),
+            pl.BlockSpec((lanes, hd), whole),
         ],
-        out_specs=pl.BlockSpec((None, h, d),
-                               lambda i, j, tables, lens: (i, 0, 0)),
+        out_specs=pl.BlockSpec((None, 1, hd), row),
         scratch_shapes=[
-            pltpu.VMEM((h, 128), jnp.float32),    # running max
-            pltpu.VMEM((h, 128), jnp.float32),    # running sum
-            pltpu.VMEM((h, d), jnp.float32),      # output accumulator
+            pltpu.VMEM((1, lanes), jnp.float32),  # running max
+            pltpu.VMEM((1, lanes), jnp.float32),  # running sum
+            pltpu.VMEM((1, hd), jnp.float32),     # output accumulator
         ],
     )
     kernel = functools.partial(_paged_decode_kernel,
                                block_size=block, scale=float(scale))
+    nb = int(k_pool.shape[0])
     with jax.named_scope("pallas.paged_decode_attention"):
-        return pl.pallas_call(
+        out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-            interpret=interpret,
+            out_shape=jax.ShapeDtypeStruct((b, 1, hd), q.dtype),
+            interpret=kernel_select.interpret_mode(),
         )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-          q, k_pool, v_pool)
+          q.reshape(b, 1, hd), k_pool.reshape(nb, block, hd),
+          v_pool.reshape(nb, block, hd), seg, seg.T)
+    return out.reshape(b, h, d)
 
 
 def select_paged_backend(batch: int, max_blocks: int, *,
@@ -351,7 +394,7 @@ def select_paged_backend(batch: int, max_blocks: int, *,
     def _auto():
         plat = platform
         if plat is None:
-            plat = jax.devices()[0].platform
+            plat = kernel_select.platform()
         if plat == "tpu":
             return True, "auto: paged kernel on tpu"
         return False, f"auto: platform '{plat}' is not tpu"

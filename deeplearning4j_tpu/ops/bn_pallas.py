@@ -22,8 +22,9 @@ inner loop is two mul-adds per element).
 
 Enabled behind ``DL4J_TPU_FUSED_BN_BWD=1`` (Environment
 ``extra["fused_bn_bwd"]``).  Off-TPU the kernels run in Pallas
-interpret mode, so the f64 gradient checks exercise the SAME code
-path the chip runs.
+interpret mode (``kernel_select.interpret_mode`` — the platform alone
+decides), so the f64 gradient checks exercise the SAME code path the
+chip runs.
 """
 from __future__ import annotations
 
@@ -32,6 +33,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from deeplearning4j_tpu.ops import kernel_select
 
 
 def fused_bn_bwd_enabled() -> bool:
@@ -44,19 +47,13 @@ def fused_bn_bwd_enabled() -> bool:
     decision runs through the shared ``ops/kernel_select.py`` ladder
     (family ``bn_bwd``) and is counted in
     ``dl4j_kernel_select_total``."""
-    from deeplearning4j_tpu.ops import kernel_select
-
     def _auto():
-        platform = jax.devices()[0].platform
+        platform = kernel_select.platform()
         if platform == "tpu":
             return True, "auto: tpu — fused backward pays (r02)"
         return False, f"auto: platform '{platform}' is not tpu"
 
     return kernel_select.select("bn_bwd", auto=_auto).fused
-
-
-def _interpret() -> bool:
-    return jax.devices()[0].platform != "tpu"
 
 
 def _block_rows(M: int, C: int) -> int:
@@ -110,7 +107,7 @@ def _bn_bwd_sums(x2d, dy2d, mean, rstd, acc_t):
                   pl.BlockSpec((2, C), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((2, C), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((2, C), acc_t),
-        interpret=_interpret(),
+        interpret=kernel_select.interpret_mode(),
     )(x2d, dy2d, stat)
     return acc[0], acc[1]
 
@@ -129,7 +126,7 @@ def _bn_bwd_dx(x2d, dy2d, a, d, e, acc_t):
                   pl.BlockSpec((3, C), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((bm, C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((M, C), x2d.dtype),
-        interpret=_interpret(),
+        interpret=kernel_select.interpret_mode(),
     )(x2d, dy2d, coef)
 
 

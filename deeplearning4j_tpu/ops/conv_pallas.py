@@ -49,7 +49,7 @@ from jax.experimental import pallas as pl
 
 from deeplearning4j_tpu.common import telemetry
 from deeplearning4j_tpu.ops import kernel_select
-from deeplearning4j_tpu.ops.bn_pallas import _block_rows, _interpret
+from deeplearning4j_tpu.ops.bn_pallas import _block_rows
 
 #: activations the epilogue kernels stream (relu as a max against the
 #: zero of the accumulator dtype; identity as a pure FMA)
@@ -108,7 +108,7 @@ def select_conv_epilogue(out_shape, dtype, act_name: str, *,
     ``platform``/``override`` exist for tests — they default to the
     live device and the DL4J_TPU_FUSED_CONV tri-state."""
     if platform is None:
-        platform = jax.devices()[0].platform
+        platform = kernel_select.platform()
     if not has_epilogue:
         structural = "no epilogue to fuse (no bias, identity activation)"
     elif act_name not in STREAMABLE_ACTIVATIONS:
@@ -135,7 +135,7 @@ def select_bn_forward(shape, dtype, *, training: bool,
     batch-stats pass — it is an epilogue site — so asking for the
     stats kernel outside training is a structural demotion."""
     if platform is None:
-        platform = jax.devices()[0].platform
+        platform = kernel_select.platform()
     if not training:
         structural = ("inference-mode BN folds into the epilogue "
                       "(no batch-stats pass)")
@@ -245,7 +245,7 @@ def _epilogue_apply_raw(x, scale, shift, act):
                   pl.BlockSpec((2, C), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((bm, C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((M, C), x.dtype),
-        interpret=_interpret(),
+        interpret=kernel_select.interpret_mode(),
     )(x.reshape(M, C), coef)
     return y2d.reshape(x.shape)
 
@@ -267,7 +267,7 @@ def _epilogue_backward(x, dy, scale, shift, act):
                    pl.BlockSpec((2, C), lambda i: (0, 0))],
         out_shape=[jax.ShapeDtypeStruct((M, C), x.dtype),
                    jax.ShapeDtypeStruct((2, C), acc_t)],
-        interpret=_interpret(),
+        interpret=kernel_select.interpret_mode(),
     )(x.reshape(M, C), dy.reshape(M, C), coef)
     # acc[0] = Σ dy·act′ (dshift), acc[1] = Σ dy·act′·x (dscale)
     return dx2d.reshape(x.shape), acc[1], acc[0]
@@ -282,7 +282,7 @@ def _channel_sums(x2d, acc_t):
         in_specs=[pl.BlockSpec((bm, C), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((2, C), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((2, C), acc_t),
-        interpret=_interpret(),
+        interpret=kernel_select.interpret_mode(),
     )(x2d)
 
 
@@ -301,7 +301,7 @@ def _matmul_epilogue(x2d, w2d, bias, act):
                   pl.BlockSpec((1, bn), lambda i, j: (0, j))],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x2d.dtype),
-        interpret=_interpret(),
+        interpret=kernel_select.interpret_mode(),
     )(x2d, w2d, bias2d)
 
 

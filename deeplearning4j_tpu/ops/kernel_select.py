@@ -30,6 +30,8 @@ counts compiled-program dispatch choices, not per-step executions.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import logging
 import os
 from dataclasses import dataclass
@@ -55,6 +57,76 @@ _select_total = telemetry.counter(
     "dl4j_kernel_select_total",
     "kernel-dispatch ladder decisions by kernel family and rung "
     "(structural / forced / killed / auto_fused / auto_dense)")
+
+
+def platform() -> str:
+    """The platform every auto rung and every ``pallas_call`` keys on
+    — jax's default backend, the one idiom for "are we on the chip"."""
+    import jax
+    return jax.default_backend()
+
+
+def interpret_mode() -> bool:
+    """Whether the Pallas kernels run in interpret mode: False on a
+    TPU backend (Mosaic compiles them), True everywhere else (so CPU
+    tests exercise the same kernel code). The platform alone decides
+    — no env var, argument or caught exception can put a kernel into
+    interpret mode on the chip."""
+    return platform() != "tpu"
+
+
+#: devices the program being traced is partitioned over by GSPMD (1 =
+#: a single-device program); set by whoever places a model on a mesh
+_partitions: contextvars.ContextVar = contextvars.ContextVar(
+    "dl4j_kernel_select_partitions", default=1)
+
+
+@contextlib.contextmanager
+def partitioned(n_devices: int):
+    """Mark programs traced inside the block as GSPMD-partitioned over
+    ``n_devices``. jax refuses to lower a Mosaic kernel into such a
+    program ("Mosaic kernels cannot be automatically partitioned.
+    Please wrap the call in a shard_map"), so while ``n_devices > 1``
+    every family's structural gate demotes to the dense lowering —
+    with that reason, counted like any other. The mesh owners
+    (ParallelWrapper, ParallelInference) wrap their jitted calls in
+    this and the model funnels mark themselves
+    (:func:`marks_partitions`); the decision lands at trace time, i.e.
+    on the first call. Fully-manual ``shard_map`` bodies are
+    single-device programs to Mosaic and need no mark."""
+    token = _partitions.set(max(int(n_devices), 1))
+    try:
+        yield
+    finally:
+        _partitions.reset(token)
+
+
+def devices_spanned(tree) -> int:
+    """Devices the arrays of ``tree`` are laid out over — read off the
+    first device-array leaf (a model's params share one placement);
+    1 for host arrays and empty trees."""
+    import jax
+    for leaf in jax.tree_util.tree_leaves(tree):
+        sharding = getattr(leaf, "sharding", None)
+        if sharding is not None:
+            return len(sharding.device_set)
+    return 1
+
+
+def marks_partitions(method):
+    """Decorator for a model's public funnels (``fit``, ``output``,
+    ...): run the method under :func:`partitioned` with the device
+    count ``self.params`` span, so a model that a mesh owner placed on
+    several devices keeps tracing partition-safe programs when the
+    user calls it directly afterwards (``net.output(x)`` after a
+    data-parallel fit)."""
+    import functools
+
+    @functools.wraps(method)
+    def wrapper(self, *args, **kwargs):
+        with partitioned(devices_spanned(self.params)):
+            return method(self, *args, **kwargs)
+    return wrapper
 
 
 @dataclass(frozen=True)
@@ -105,6 +177,11 @@ def select(kernel: str, *,
     live ``gate_override(kernel)`` tri-state is consulted.
     """
     env_var = GATES[kernel][1]
+    n_part = _partitions.get()
+    if structural is None and n_part > 1:
+        structural = (f"program is partitioned over {n_part} devices: "
+                      "Mosaic kernels cannot be automatically "
+                      "partitioned")
     if structural is not None:
         sel = Selection(kernel, False, "structural", structural)
     else:

@@ -120,9 +120,6 @@ class ParallelInference:
             m.states = replicate_tree(self.mesh, m.states)
             self._placed = True
         if self._fwd is None:
-            from deeplearning4j_tpu.common.compilecache import \
-                enable_persistent_cache
-            enable_persistent_cache()
             from deeplearning4j_tpu.nn.graph import ComputationGraph
             is_graph = isinstance(m, ComputationGraph)
 
@@ -140,6 +137,14 @@ class ParallelInference:
             # jitted fn and the last assignment wins — no torn state
             # dl4j-lint: disable=lock-discipline
             self._fwd = jax.jit(fwd)
+
+    def _run_fwd(self, params, states, placed):
+        """The jitted forward, traced under the mesh's partition mark
+        (``kernel_select.partitioned``): on a multi-device mesh the
+        program is GSPMD-partitioned, which Mosaic kernels cannot be."""
+        from deeplearning4j_tpu.ops import kernel_select
+        with kernel_select.partitioned(self.mesh.size):
+            return self._fwd(params, states, placed)
 
     def _place_chunk(self, x):
         """Pad to a shard multiple and device_put sharded over the mesh
@@ -159,7 +164,8 @@ class ParallelInference:
         unlike training — mesh.py note)."""
         self._ensure()
         placed, orig = self._place_chunk(x)
-        out = self._fwd(self.model.params, self.model.states, placed)
+        out = self._run_fwd(self.model.params, self.model.states,
+                            placed)
         return np.asarray(out[:orig])
 
     def output_batched(self, requests: List) -> List[np.ndarray]:
@@ -186,7 +192,8 @@ class ParallelInference:
         for i in range(len(chunks)):
             cur, orig = placed
             # device compute for the current chunk: dispatched async
-            out = self._fwd(self.model.params, self.model.states, cur)
+            out = self._run_fwd(self.model.params, self.model.states,
+                                cur)
             if i + 1 < len(chunks):
                 if overlap:
                     # stage chunk i+1 while chunk i computes/transfers

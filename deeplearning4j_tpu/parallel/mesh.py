@@ -157,33 +157,19 @@ def pad_batch_to_multiple(x, n: int):
     return jnp.concatenate([x, reps], axis=0), b
 
 
-def shard_map(f, mesh: Mesh, *, in_specs, out_specs, check_rep=True):
-    """jax.shard_map across jax versions (experimental alias pre-0.8).
-    The package-public seam every parallel module builds on.
+def shard_map(f, mesh: Mesh, *, in_specs, out_specs, check_vma=True):
+    """``jax.shard_map`` over ``mesh`` — the package-public seam every
+    parallel module builds on.
 
-    ``check_rep=False`` disables the static replication / varying-
-    manual-axes check (the kwarg is ``check_rep`` on older jax,
-    ``check_vma`` on newer) — callers that opt out take over the
-    cross-rank gradient reduction themselves and must say why at the
-    call site."""
-    if hasattr(jax, "shard_map"):
-        _sm = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as _sm
-    kw = {}
-    if not check_rep:
-        import inspect
-        params = inspect.signature(_sm).parameters
-        kw["check_vma" if "check_vma" in params else "check_rep"] = False
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               **kw)
+    ``check_vma=False`` disables the static varying-manual-axes check —
+    callers that opt out take over the cross-rank gradient reduction
+    themselves and must say why at the call site."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def axis_size(axis: str) -> int:
     """Concrete size of a mesh axis from inside shard_map tracing
-    (the mesh is static, so this is a Python int on every jax we
-    support)."""
+    (the mesh is static, so this is a Python int)."""
     from jax import lax
-    if hasattr(lax, "axis_size"):
-        return int(lax.axis_size(axis))
-    return int(lax.psum(1, axis))
+    return int(lax.axis_size(axis))

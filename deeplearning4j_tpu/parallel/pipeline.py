@@ -110,15 +110,11 @@ def _varying(x, axes):
     the scan carry differs per stage even though it starts as zeros;
     with MoE/DP inside the stage fn it also varies over those axes)."""
     axes = (axes,) if isinstance(axes, str) else tuple(axes)
-    have = getattr(getattr(x, "aval", None), "vma", frozenset())
+    have = jax.typeof(x).vma
     axes = tuple(a for a in axes if a not in have)
     if not axes:
         return x
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axes, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, axes)
-    return x
+    return lax.pcast(x, axes, to="varying")
 
 
 from .mesh import axis_size as _axis_size_concrete  # shared helper

@@ -155,10 +155,7 @@ def _sds_like(shape, dtype, like):
     """ShapeDtypeStruct carrying ``like``'s varying-manual-axes, so
     pallas_call outputs type-check under shard_map (ring attention
     runs the kernels inside the ``seq`` manual axis)."""
-    vma = getattr(jax.core.get_aval(like), "vma", None)
-    if vma:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, *rest, n_kb: int, causal: bool,
@@ -247,10 +244,11 @@ def _fit_block(block, t):
 
 
 def _flash_forward(q, k, v, key_mask, causal: bool, block_q: int,
-                   block_k: int, interpret: bool,
-                   want_lse: bool = False):
+                   block_k: int, want_lse: bool = False):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+
+    from deeplearning4j_tpu.ops.kernel_select import interpret_mode
 
     b, h, tq, d = q.shape
     tk = k.shape[2]
@@ -308,7 +306,7 @@ def _flash_forward(q, k, v, key_mask, causal: bool, block_q: int,
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(*inputs)
     if want_lse:
         out, lse = res
@@ -423,8 +421,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 
 def _flash_backward(q, k, v, key_mask, out, lse, g, causal: bool,
-                    block_q: int, block_k: int, interpret: bool,
-                    g_lse=None):
+                    block_q: int, block_k: int, g_lse=None):
     """Pallas flash backward: dq via a (bh, iq, jk) sweep, dk/dv via a
     (bh, jk, iq) sweep, probabilities recomputed from the saved
     log-sum-exp.  Replaces the r3 jax.vjp-through-blockwise backward,
@@ -434,6 +431,9 @@ def _flash_backward(q, k, v, key_mask, out, lse, g, causal: bool,
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    from deeplearning4j_tpu.ops.kernel_select import interpret_mode
+
+    interpret = interpret_mode()
     b, h, tq, d = q.shape
     tk = k.shape[2]
     scale = 1.0 / (d ** 0.5)
@@ -525,10 +525,9 @@ def _flash_backward(q, k, v, key_mask, out, lse, g, causal: bool,
             dv.reshape(b, h, tk, d))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def flash_attention(q, k, v, causal: bool = False, block_q: int = 1024,
-                    block_k: int = 1024,
-                    interpret: Optional[bool] = None, key_mask=None):
+                    block_k: int = 1024, key_mask=None):
     """Fused attention kernel, [b, h, t, d]. Equals dense softmax
     attention; O(block) VMEM. ``key_mask``: [b, tk], 0 = masked.
     Backward = Pallas dq/dk/dv kernels recomputing probabilities from
@@ -545,42 +544,37 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = 1024,
     BENCH_notes_r03.md; the backward caps blocks at 512 (it keeps
     score + dp + ds f32 blocks live). Blocks clamp to the sequence
     length, so short sequences still work; below ~4k prefer plain
-    XLA attention, which wins outright there."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return _flash_forward(q, k, v, key_mask, causal, block_q, block_k,
-                          interpret)
+    XLA attention, which wins outright there.
+
+    Compiled by Mosaic on a TPU backend, interpreted everywhere else
+    (``ops.kernel_select.interpret_mode`` — the platform alone
+    decides, no argument can)."""
+    return _flash_forward(q, k, v, key_mask, causal, block_q, block_k)
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_k, interpret,
-               key_mask=None):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+def _flash_fwd(q, k, v, causal, block_q, block_k, key_mask=None):
     out, lse = _flash_forward(q, k, v, key_mask, causal, block_q,
-                              block_k, interpret, want_lse=True)
+                              block_k, want_lse=True)
     return out, (q, k, v, key_mask, out, lse)
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, res, g):
+def _flash_bwd(causal, block_q, block_k, res, g):
     q, k, v, key_mask, out, lse = res
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     # backward blocks default to 512: the bwd keeps an extra f32
     # score block + dp/ds live, so the fwd's 1024x1024 tuning would
     # overflow VMEM
     dq, dk, dv = _flash_backward(
         q, k, v, key_mask, out, lse, g, causal,
-        min(block_q, 512), min(block_k, 512), interpret)
+        min(block_q, 512), min(block_k, 512))
     return dq, dk, dv, None      # no cotangent for the mask
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def flash_attention_with_lse(q, k, v, causal: bool = False,
                              block_q: int = 1024, block_k: int = 1024,
-                             interpret: Optional[bool] = None,
                              key_mask=None):
     """:func:`flash_attention` that ALSO returns the per-row
     log-sum-exp of the scaled scores, [b, h, t] f32 — the residual
@@ -588,34 +582,26 @@ def flash_attention_with_lse(q, k, v, causal: bool = False,
     exactly (ring attention's per-step form).  Differentiable in the
     lse output too: its cotangent folds into the backward's delta
     term (d lse/d s = p)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     out, lse = _flash_forward(q, k, v, key_mask, causal, block_q,
-                              block_k, interpret, want_lse=True)
+                              block_k, want_lse=True)
     b, h, tq, _ = q.shape
     return out, lse[:, :, 0].reshape(b, h, tq)
 
 
-def _flash_lse_fwd(q, k, v, causal, block_q, block_k, interpret,
-                   key_mask=None):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+def _flash_lse_fwd(q, k, v, causal, block_q, block_k, key_mask=None):
     out, lse = _flash_forward(q, k, v, key_mask, causal, block_q,
-                              block_k, interpret, want_lse=True)
+                              block_k, want_lse=True)
     b, h, tq, _ = q.shape
     return ((out, lse[:, :, 0].reshape(b, h, tq)),
             (q, k, v, key_mask, out, lse))
 
 
-def _flash_lse_bwd(causal, block_q, block_k, interpret, res, g):
+def _flash_lse_bwd(causal, block_q, block_k, res, g):
     q, k, v, key_mask, out, lse = res
     g_out, g_lse = g
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     dq, dk, dv = _flash_backward(
         q, k, v, key_mask, out, lse, g_out, causal,
-        min(block_q, 512), min(block_k, 512), interpret,
-        g_lse=g_lse)
+        min(block_q, 512), min(block_k, 512), g_lse=g_lse)
     return dq, dk, dv, None
 
 
@@ -670,7 +656,7 @@ def ring_attention(q, k, v, axis_name: str, *, causal: bool = False,
     q_pos = my * t_local + jnp.arange(t_local)
 
     # derive the carry from q so it carries q's varying-manual-axes
-    # (jax>=0.8 shard_map type-checks vma through scan carries)
+    # (shard_map type-checks vma through scan carries)
     acc_dt = jnp.promote_types(q.dtype, jnp.float32)
     o0 = (q * 0).astype(acc_dt)
     l0 = o0[..., 0]
@@ -683,14 +669,18 @@ def ring_attention(q, k, v, axis_name: str, *, causal: bool = False,
                 lax.ppermute(vb, axis_name, p))
 
     if use_flash:
-        on_tpu = jax.default_backend() == "tpu"
+        from deeplearning4j_tpu.ops.kernel_select import interpret_mode
+
+        # nothing but the platform takes the reference branch: on a
+        # TPU backend interpret_mode() is False and the kernels run
+        kernels = not interpret_mode()
 
         def partial_fn(causal_local):
             def f(q, kb, vb):
-                if on_tpu:
+                if kernels:
                     o_s, lse_s = flash_attention_with_lse(
                         q, kb, vb, causal_local, flash_block_q,
-                        flash_block_k, None)
+                        flash_block_k)
                 else:
                     # interpret-mode pallas does not propagate
                     # varying-manual-axes through the kernel body, so
@@ -757,7 +747,7 @@ def _seq_sharded_call(local_fn, mesh, q, k, v, seq_axis, causal,
 
     data = "data" if "data" in mesh.axis_names else None
     spec = P(data, None, seq_axis, None)
-    # check_rep=False: the causal ring's lax.switch (fully-visible /
+    # check_vma=False: the causal ring's lax.switch (fully-visible /
     # locally-causal / skipped branches) makes jax's static
     # replication checker raise "branches of cond produced mismatched
     # replication types" (jax suggests exactly this workaround).  It
@@ -769,7 +759,7 @@ def _seq_sharded_call(local_fn, mesh, q, k, v, seq_axis, causal,
         functools.partial(local_fn, axis_name=seq_axis, causal=causal,
                           **kw),
         mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False)
+        check_vma=False)
     return fn(q, k, v)
 
 
@@ -801,7 +791,8 @@ def ulysses_attention(q, k, v, axis_name: str, *, causal: bool = False,
                         tiled=True)
     vh = lax.all_to_all(v, axis_name, split_axis=1, concat_axis=2,
                         tiled=True)
-    if use_flash and jax.default_backend() == "tpu":
+    from deeplearning4j_tpu.ops.kernel_select import interpret_mode
+    if use_flash and not interpret_mode():
         o = flash_attention(qh, kh, vh, causal)
     else:
         o = blockwise_attention(qh, kh, vh, causal=causal,

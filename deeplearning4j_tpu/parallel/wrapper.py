@@ -490,11 +490,16 @@ class ParallelWrapper:
 
     def _fit_model(self, ds):
         """One training batch through whichever engine owns the fit
-        path — the model's own fused step, or the pipeline schedule."""
-        if self._pipeline is not None:
-            self._pipeline.fit_batch(ds)
-        else:
-            self.model.fit(ds)
+        path — the model's own fused step, or the pipeline schedule.
+        Traced under the mesh's partition mark: on a multi-device mesh
+        the step is GSPMD-partitioned, which Mosaic kernels cannot be
+        (``kernel_select.partitioned``)."""
+        from deeplearning4j_tpu.ops import kernel_select
+        with kernel_select.partitioned(self.mesh.size):
+            if self._pipeline is not None:
+                self._pipeline.fit_batch(ds)
+            else:
+                self.model.fit(ds)
 
     def _shard(self, a):
         if a is None or not hasattr(a, "ndim") or getattr(a, "ndim", 0) == 0:

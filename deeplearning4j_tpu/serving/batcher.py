@@ -170,9 +170,6 @@ class ServingBatcher(ParallelInference):
         if self._fwd is None:
             import jax
 
-            from deeplearning4j_tpu.common.compilecache import \
-                enable_persistent_cache
-            enable_persistent_cache()
             from deeplearning4j_tpu.nn.graph import ComputationGraph
             from deeplearning4j_tpu.serving.residency import \
                 serving_param_view
@@ -236,11 +233,11 @@ class ServingBatcher(ParallelInference):
         placed, _ = self._place_chunk(padded)
         self._record(placed)
         if self._serve_params is not None:
-            out = self._fwd(self._serve_params, self._serve_states,
-                            placed)
+            out = self._run_fwd(self._serve_params, self._serve_states,
+                                placed)
         else:
-            out = self._fwd(self.model.params, self.model.states,
-                            placed)
+            out = self._run_fwd(self.model.params, self.model.states,
+                                placed)
         return np.asarray(out)[:orig]
 
     # ------------------------------------------------------------------

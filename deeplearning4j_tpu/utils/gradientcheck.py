@@ -73,10 +73,7 @@ class GradientCheckUtil:
         ``max_params_per_array`` random entries are checked per
         parameter tensor (sampling keeps runtime sane with identical
         detection power for systematic backward bugs)."""
-        x64 = getattr(jax, "enable_x64", None)
-        if x64 is None:                      # older jax spelling
-            from jax.experimental import enable_x64 as x64
-        with x64():
+        with jax.enable_x64():
             return GradientCheckUtil._check_f64(
                 net, ds, epsilon, max_rel_error, min_abs_error,
                 max_params_per_array, seed, print_results)

@@ -21,25 +21,22 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-# the CPU device count is an XLA flag read once at backend init, so it
-# must be in place BEFORE anything touches jax.devices() (backend init
-# is lazy — see tests/conftest.py); jax_num_cpu_devices exists only on
-# newer jax, the flag works everywhere
-if "--xla_force_host_platform_device_count" not in \
-        os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
-                               " --xla_force_host_platform_device_count=8")
-
 import numpy as np
 
 
 def ensure_devices(n):
+    """Fewer than ``n`` real devices: move to an ``n``-device virtual
+    CPU mesh (and say so)."""
     import jax
     if len(jax.devices()) >= n:
         return
+    print(f"{len(jax.devices())} device(s) on "
+          f"{jax.default_backend()}: using a {n}-device virtual CPU "
+          f"mesh")
     import jax.extend.backend as eb
     eb.clear_backends()
     jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_num_cpu_devices", n)
     assert len(jax.devices()) >= n
 
 

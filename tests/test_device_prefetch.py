@@ -224,6 +224,9 @@ _CACHE_CHILD = """
 import sys, jax
 import numpy as np
 jax.config.update("jax_platforms", "cpu")
+import deeplearning4j_tpu
+from deeplearning4j_tpu.common import compilecache
+print("CONFIGURED=%s" % compilecache.configure())
 hits = []
 from jax._src import monitoring
 monitoring.register_event_listener(
@@ -255,14 +258,18 @@ print("CACHE_HITS=%d" %
 
 class TestPersistentCompileCache:
     def test_second_process_hits_cache(self, tmp_path):
-        """The acceptance check: process 1 populates the on-disk cache,
-        process 2 compiling the same network loads from it."""
+        """The acceptance check, with the cache placed from outside:
+        ``JAX_COMPILATION_CACHE_DIR`` names the directory, the package
+        sets none, process 1 populates it and process 2 compiling the
+        same network loads from it."""
         cache_dir = str(tmp_path / "xla-cache")
         env = {**os.environ,
-               "DL4J_TPU_COMPILE_CACHE": "1",
-               "DL4J_TPU_COMPILE_CACHE_DIR": cache_dir,
+               "JAX_COMPILATION_CACHE_DIR": cache_dir,
+               # jax's own gates would skip this sub-second compile
+               "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
+               "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES": "-1",
                "JAX_PLATFORMS": "cpu"}
-        env.pop("PYTHONPATH", None)
+        env.pop("DL4J_TPU_COMPILE_CACHE", None)
         root = os.path.dirname(os.path.dirname(
             os.path.abspath(__file__)))
 
@@ -273,6 +280,7 @@ class TestPersistentCompileCache:
 
         r1 = run()
         assert r1.returncode == 0, r1.stderr[-2000:]
+        assert "CONFIGURED=None" in r1.stdout, r1.stdout
         entries = os.listdir(cache_dir)
         assert any(e.endswith("-cache") for e in entries), entries
         r2 = run()
@@ -280,23 +288,26 @@ class TestPersistentCompileCache:
         hits = int(r2.stdout.strip().rsplit("CACHE_HITS=", 1)[1])
         assert hits > 0, (r2.stdout, r2.stderr[-2000:])
 
-    def test_cache_dir_created_and_flag_off(self, tmp_path, monkeypatch):
-        from deeplearning4j_tpu.common import compilecache
-        monkeypatch.setenv("DL4J_TPU_COMPILE_CACHE_DIR",
-                           str(tmp_path / "cc"))
-        monkeypatch.setenv("DL4J_TPU_COMPILE_CACHE", "1")
-        Environment.reset()
-        compilecache._reset_for_tests()
-        try:
-            d = compilecache.enable_persistent_cache()
-            assert d == str(tmp_path / "cc")
-            assert os.path.isdir(d)
-            # idempotent
-            assert compilecache.enable_persistent_cache() == d
-            monkeypatch.setenv("DL4J_TPU_COMPILE_CACHE", "0")
-            Environment.reset()
-            compilecache._reset_for_tests()
-            assert compilecache.enable_persistent_cache() is None
-        finally:
-            Environment.reset()
-            compilecache._reset_for_tests()
+    def test_where_the_cache_lives(self):
+        """The placement rule (common.compilecache.resolve_cache_dir):
+        jax's variable wins and the package sets nothing; unset, the
+        directory is <checkout>/.jax_cache; a process pinned to cpu
+        gets none unless DL4J_TPU_COMPILE_CACHE=1; =0 opts out."""
+        from deeplearning4j_tpu.common.compilecache import (
+            checkout_cache_dir, resolve_cache_dir)
+        root = os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))
+        here = os.path.join(root, ".jax_cache")
+        assert checkout_cache_dir() == here
+        assert resolve_cache_dir(
+            {"JAX_COMPILATION_CACHE_DIR": "/elsewhere"}, "tpu") is None
+        assert resolve_cache_dir({}, "tpu") == here
+        assert resolve_cache_dir({}, None) == here
+        assert resolve_cache_dir({}, "cpu") is None
+        assert resolve_cache_dir(
+            {"DL4J_TPU_COMPILE_CACHE": "1"}, "cpu") == here
+        assert resolve_cache_dir(
+            {"DL4J_TPU_COMPILE_CACHE": "0"}, "tpu") is None
+        # tier-1 runs pinned to cpu: this process placed no cache
+        assert jax.config.jax_compilation_cache_dir is None or \
+            os.environ.get("JAX_COMPILATION_CACHE_DIR")

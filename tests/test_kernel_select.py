@@ -235,3 +235,51 @@ class TestFusedSitesCounter:
             assert after == before + 1
         finally:
             env.extra.pop("fused_conv", None)
+
+
+class TestPartitionedGate:
+    """A GSPMD-partitioned program cannot hold a Mosaic kernel (jax
+    refuses the lowering), so the mark demotes every family — even a
+    forced one — structurally, and the platform alone decides
+    interpret mode."""
+
+    def test_partitioned_demotes_even_forced(self):
+        with kernel_select.partitioned(4):
+            sel, counts = _delta("bn_bwd", lambda: kernel_select.select(
+                "bn_bwd", auto=(True, "auto"), override=True))
+        assert not sel.fused and sel.decision == "structural"
+        assert "partitioned over 4 devices" in sel.reason
+        assert counts == {"structural": 1}
+        # the mark ends with the block, and one device is no partition
+        with kernel_select.partitioned(1):
+            assert kernel_select.select(
+                "bn_bwd", auto=(True, "auto"), override=True,
+                record=False).fused
+        assert kernel_select.select(
+            "bn_bwd", auto=(True, "auto"), override=True,
+            record=False).fused
+
+    def test_model_funnels_mark_what_their_params_span(self):
+        class M:
+            params = {"w": jnp.ones(2)}
+            seen = None
+
+            @kernel_select.marks_partitions
+            def fit(self):
+                self.seen = kernel_select.select(
+                    "bn_bwd", auto=(True, "auto"), record=False).fused
+        m = M()
+        m.fit()
+        assert m.seen is True                       # one device
+        devs = jax.devices()
+        if len(devs) >= 2:
+            mesh = jax.sharding.Mesh(np.array(devs[:2]), ("data",))
+            m.params = {"w": jax.device_put(
+                jnp.ones(2), jax.sharding.NamedSharding(
+                    mesh, jax.sharding.PartitionSpec()))}
+            m.fit()
+            assert m.seen is False                  # replicated over 2
+
+    def test_interpret_mode_is_the_platform(self):
+        assert kernel_select.interpret_mode() == (
+            jax.default_backend() != "tpu")

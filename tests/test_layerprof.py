@@ -230,10 +230,18 @@ class TestDynamicAttribution:
         total = sum(m["fwd_ms"] + m["bwd_ms"] for m in split.values())
         assert total == pytest.approx(10.0, rel=1e-6)
         assert rep["time_source"] == "static_share_proxy"
+        # the CPU backend is not in diagnostics.DEVICE_PEAKS: the v5e
+        # ridge is a labelled weighting and nothing is a percent of it
+        assert rep["peaks"]["weighting_only"]
         for name in ("layer_0", "layer_1"):
             ent = rep["layers"][name]
             assert ent["fwd_ms"] + ent["bwd_ms"] > 0
-            assert ent["pct_of_roof"] is not None
+            assert ent.get("pct_of_roof") is None
+        # peaks the caller vouches for do give a percent
+        rep_p = net.layer_report(x, y, peak_tflops=1.0,
+                                 peak_hbm_gbps=1.0)
+        layerprof.share_step_time(rep_p, 10.0)
+        assert rep_p["layers"]["layer_0"]["pct_of_roof"] is not None
         # explicit join path: measured ms replace the shares
         rep2 = layerprof.join_dynamic(
             rep, {"layer_0": {"fwd_ms": 1.0, "bwd_ms": 2.0}},

@@ -130,7 +130,7 @@ class TestFlashAttention:
             km = jnp.asarray(kma)
 
         def loss_flash(q, k, v):
-            o = flash_attention(q, k, v, causal, 128, 128, True,
+            o = flash_attention(q, k, v, causal, 128, 128,
                                 key_mask=km)
             return jnp.sum(jnp.sin(o))
 
@@ -153,7 +153,7 @@ class TestFlashAttention:
         """Blocks that don't divide the sequence shrink to a divisor
         instead of erroring (t=48 with 32-blocks runs at 16)."""
         q, k, v = _qkv(t=48)
-        out = flash_attention(q, k, v, False, 32, 32, True)
+        out = flash_attention(q, k, v, False, 32, 32)
         s = np.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
         e = np.exp(s - s.max(-1, keepdims=True))
         want = np.einsum("bhqk,bhkd->bhqd",
@@ -223,8 +223,7 @@ class TestRingAttention:
                 / np.sqrt(q.shape[-1])
             return jax.scipy.special.logsumexp(s, axis=-1)
 
-        o, lse = flash_attention_with_lse(q, k, v, False, 128, 128,
-                                          True)
+        o, lse = flash_attention_with_lse(q, k, v, False, 128, 128)
         np.testing.assert_allclose(np.asarray(o),
                                    np.asarray(_dense(q, k, v)),
                                    atol=2e-5)
@@ -233,8 +232,7 @@ class TestRingAttention:
                                    atol=2e-5)
 
         def loss_f(q, k, v):
-            _, l = flash_attention_with_lse(q, k, v, False, 128, 128,
-                                            True)
+            _, l = flash_attention_with_lse(q, k, v, False, 128, 128)
             return jnp.sum(jnp.cos(l))
 
         def loss_d(q, k, v):
@@ -317,7 +315,7 @@ class TestUlysses:
         km_np[0, 100:] = 0.0
         km_np[1, 64:] = 0.0
         km = jnp.asarray(km_np)
-        out = flash_attention(q, k, v, False, 64, 64, None, km)
+        out = flash_attention(q, k, v, False, 64, 64, km)
         ref = dot_product_attention(q, k, v, km[:, None, None, :])
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5)
@@ -329,7 +327,7 @@ class TestUlysses:
 
         def loss(q, k, v):
             return jnp.sum(flash_attention(q, k, v, False, 64, 64,
-                                           None, km) ** 2)
+                                           km) ** 2)
 
         def loss_dense(q, k, v):
             return jnp.sum(dot_product_attention(
