@@ -17,13 +17,16 @@ Three pieces:
   text-format page (``UIServer`` serves it at ``/metrics``), folds into
   ``ui.stats`` reports via :class:`MetricsReporterListener`, and lands
   in ``bench.py`` JSON via :meth:`MetricsRegistry.summary`.
-- :func:`span` — a context manager recording wall-clock spans into a
-  shared chrome-trace event buffer, in the SAME format
+- :func:`span` — a context manager recording spans into a shared
+  chrome-trace event buffer, in the SAME format
   ``ui.profiling.ProfilingListener`` emits, so host spans, feeder-
   thread spans, and ``jax.profiler`` TPU traces load into one
   chrome://tracing / Perfetto timeline.  :func:`export_chrome_trace`
   writes the buffer; :func:`merge_chrome_traces` folds several trace
-  files (ours or jax.profiler's) into one.
+  files (ours or jax.profiler's) into one.  Every stamp comes from ONE
+  clock, :func:`now_us`; while a ``jax.profiler`` trace is being taken
+  each span also lies in its ``/host:CPU`` plane, above the device
+  lines.
 - ``DL4J_TPU_TELEMETRY`` gate (default on) — when off, every record
   call is a single attribute check and spans don't allocate
   (``benchmarks/bench_telemetry.py`` is the overhead microbench).
@@ -497,25 +500,141 @@ class _TraceBuffer:
 _trace_buffer = _TraceBuffer()
 
 
-@contextmanager
-def span(name: str, **attrs):
-    """Record a wall-clock chrome-trace span ("X" event) for the
-    with-block onto THIS thread's timeline row.  Near-free when
-    telemetry is off.  Attrs land in the event's ``args`` and show in
-    the trace viewer's detail pane."""
-    if not MetricsRegistry.get().enabled:
-        yield
-        return
-    t0 = time.time()
-    try:
-        yield
-    finally:
-        t1 = time.time()
+# ----------------------------------------------------------------------
+# the one clock.  Every span, instant and request phase is stamped in
+# epoch microseconds derived from ``time.perf_counter_ns()`` through a
+# single anchor pair read once, here: the ring's ``ts`` stays on the
+# epoch axis the chrome-trace exporters and the flight recorder expect,
+# never runs backwards when the wall clock is stepped, and converts
+# back to the ``perf_counter`` a caller stamped its own events with.
+# (``time.monotonic`` is the same clock as ``time.perf_counter`` on
+# Linux, CLOCK_MONOTONIC: phase instants taken with either convert.)
+_ANCHOR_WALL_NS = time.time_ns()
+_ANCHOR_PERF_NS = time.perf_counter_ns()
+_EPOCH_LESS_PERF_NS = _ANCHOR_WALL_NS - _ANCHOR_PERF_NS
+
+#: the anchor: (``time.time()``, ``time.perf_counter()``) of one instant
+CLOCK_ANCHOR_S = (_ANCHOR_WALL_NS * 1e-9, _ANCHOR_PERF_NS * 1e-9)
+
+
+_perf_ns = time.perf_counter_ns
+
+
+def now_us() -> int:
+    """Epoch microseconds on the monotonic clock."""
+    return (_perf_ns() + _EPOCH_LESS_PERF_NS) // 1000
+
+
+def us_of(perf_s: float) -> int:
+    """Epoch microseconds of a ``time.perf_counter()`` instant."""
+    return (int(perf_s * 1e9) + _EPOCH_LESS_PERF_NS) // 1000
+
+
+def perf_counter_of(ts_us: float) -> float:
+    """The ``time.perf_counter()`` reading (seconds) of a ring ``ts``
+    — the inverse of :func:`now_us`."""
+    return (ts_us * 1000 - _EPOCH_LESS_PERF_NS) * 1e-9
+
+
+#: ``args`` keys a span hands down to the spans it causes: the
+#: identifier their work shares (an engine or fit iteration, a request)
+SHARED_IDS = ("iter", "seq", "trace")
+
+#: this process's id, for the ring's records.  Read once and again in a
+#: forked child, not in every span: ``os.getpid()`` is a system call,
+#: 5 us in a loop on the sealed machines the chip is reached through
+#: and ten times that after the thread has slept, and one in each of an
+#: engine iteration's seven spans was most of what the spans cost there
+#: (PERF.md section 6, PR 26)
+_PID = os.getpid()
+
+
+def _reread_pid() -> None:
+    global _PID
+    _PID = os.getpid()
+
+
+os.register_at_fork(after_in_child=_reread_pid)
+
+#: ``.stack``: (name, args) of the spans open on THIS thread, outermost
+#: first — what names a new span's ``parent``
+_open_spans = threading.local()
+
+
+def _import_annotation():
+    global _TraceAnnotation
+    from jax.profiler import TraceAnnotation
+    _TraceAnnotation = TraceAnnotation
+    return TraceAnnotation
+
+
+#: ``jax.profiler.TraceAnnotation``, imported lazily and once: a span's
+#: twin on the profiler's clock (one flag check while no profile is
+#: being taken)
+_TraceAnnotation = None
+
+
+class _Span:
+    """One with-block's span: a ring record and its twin in a running
+    ``jax.profiler`` trace.  Hand-rolled (slots, no generator) because
+    the decode engine opens seven of these an iteration."""
+
+    __slots__ = ("name", "args", "t0", "t1", "_twin")
+
+    def __init__(self, name: str, attrs: dict):
+        self.name = name
+        self.args = attrs
+
+    def __enter__(self) -> dict:
+        if not MetricsRegistry.get()._state["on"]:
+            self._twin = None
+            return self.args
+        try:
+            stack = _open_spans.stack
+        except AttributeError:
+            stack = _open_spans.stack = []
+        args = self.args
+        if stack:
+            # caused by the span open round it: its name, and the
+            # identifiers it shares where this span gives none
+            parent, held = stack[-1]
+            args = {"parent": parent}
+            for k in SHARED_IDS:
+                if k in held:
+                    args[k] = held[k]
+            args.update(self.args)
+            self.args = args
+        stack.append((self.name, args))
+        # now_us(), inlined: seven spans an engine iteration
+        self.t0 = (_perf_ns() + _EPOCH_LESS_PERF_NS) // 1000
+        self._twin = (_TraceAnnotation or _import_annotation())(
+            self.name, **args)
+        return args
+
+    def __exit__(self, *exc):
+        twin = self._twin
+        if twin is None:
+            return False
+        self.t1 = t1 = (_perf_ns() + _EPOCH_LESS_PERF_NS) // 1000
+        twin.__exit__(None, None, None)
+        _open_spans.stack.pop()
         _trace_buffer.append({
-            "name": name, "ph": "X", "pid": os.getpid(),
+            "name": self.name, "ph": "X", "pid": _PID,
             "tid": threading.get_ident() & 0xFFFF,
-            "ts": int(t0 * 1e6), "dur": int((t1 - t0) * 1e6),
-            "args": attrs})
+            "ts": self.t0, "dur": t1 - self.t0, "args": self.args})
+        return False
+
+
+def span(name: str, **attrs) -> _Span:
+    """Record a chrome-trace span ("X" event) for the with-block onto
+    THIS thread's timeline row, and mirror it into a running
+    ``jax.profiler`` trace.  Near-free when telemetry is off.  Attrs
+    land in the event's ``args`` beside ``parent`` (the span open round
+    this one on this thread) and the identifiers inherited from it
+    (:data:`SHARED_IDS`).  ``with span(...) as args`` gives the
+    ``args`` dict: what is only known when the block ends (a count of
+    what it did) may be added to it."""
+    return _Span(name, attrs)
 
 
 def instant(name: str, **attrs) -> None:
@@ -524,22 +643,23 @@ def instant(name: str, **attrs) -> None:
     if not MetricsRegistry.get().enabled:
         return
     _trace_buffer.append({
-        "name": name, "ph": "i", "s": "p", "pid": os.getpid(),
+        "name": name, "ph": "i", "s": "p", "pid": _PID,
         "tid": threading.get_ident() & 0xFFFF,
-        "ts": int(time.time() * 1e6), "args": attrs})
+        "ts": now_us(), "args": attrs})
 
 
 def span_at(name: str, t_wall: float, dur_s: float, **attrs) -> None:
     """Record a chrome-trace span with EXPLICIT start/duration — for
     phases measured by another thread (a batcher flush attributing
     queue wait back to each request) where a with-block cannot wrap
-    the interval. ``t_wall`` is a unix timestamp (seconds)."""
+    the interval. ``t_wall`` is epoch seconds on the one clock:
+    ``now_us() * 1e-6``, or ``us_of(perf_counter instant) * 1e-6``."""
     if not MetricsRegistry.get().enabled:
         return
     _trace_buffer.append({
-        "name": name, "ph": "X", "pid": os.getpid(),
+        "name": name, "ph": "X", "pid": _PID,
         "tid": threading.get_ident() & 0xFFFF,
-        "ts": int(t_wall * 1e6), "dur": max(0, int(dur_s * 1e6)),
+        "ts": int(round(t_wall * 1e6)), "dur": max(0, int(dur_s * 1e6)),
         "args": attrs})
 
 
@@ -653,53 +773,46 @@ _STEP_HELP = ("host-observed train-step wall time: dispatch plus "
               "whatever sync the funnel performs (seconds)")
 
 
-class _StepSpan:
+class _StepSpan(_Span):
     """The fit-funnel instrumentation point: times the with-block into
     the ``dl4j_train_step_seconds`` histogram (labeled by model class)
-    AND records a ``train_step`` chrome-trace span — one call site per
-    funnel keeps MLN/graph/SameDiff step timing comparable.
-
-    Hand-rolled (slots, cached bound histogram per model name) rather
-    than @contextmanager: this runs once per train step, and the <1%
+    AND records a ``train_step`` span — one call site per funnel keeps
+    MLN/graph/SameDiff step timing comparable.  The bound histogram is
+    cached per model name: this runs once per train step, and the <1%
     overhead budget is measured against millisecond steps."""
 
-    __slots__ = ("model", "attrs", "_bound", "t0", "p0", "duration")
+    __slots__ = ("duration",)
 
     def __init__(self, model: str, attrs: dict):
-        self.model = model
-        self.attrs = attrs
+        self.name = "train_step"
+        attrs["model"] = model
+        self.args = attrs
 
     def __enter__(self):
-        # the clock always runs (two perf_counter calls even when
-        # telemetry is off): the flight recorder reads ``duration``
-        # after the with-block, independent of the metrics gate
+        # the clock always runs (even when telemetry is off): the
+        # flight recorder reads ``duration`` after the with-block,
+        # independent of the metrics gate
         self.duration = 0.0
-        self.p0 = time.perf_counter()
-        reg = MetricsRegistry.get()
-        if not reg._state["on"]:
-            self._bound = None
-            return self
-        cache = reg.__dict__.setdefault("_step_bound", {})
-        b = cache.get(self.model)
-        if b is None:
-            b = cache[self.model] = histogram(
-                "dl4j_train_step_seconds",
-                _STEP_HELP).bind(model=self.model)
-        self._bound = b
-        self.t0 = time.time()
+        _Span.__enter__(self)
+        if self._twin is None:
+            self.t0 = now_us()
         return self
 
     def __exit__(self, *exc):
-        dt = time.perf_counter() - self.p0
-        self.duration = dt
-        if self._bound is None:
+        if self._twin is None:
+            self.duration = (now_us() - self.t0) * 1e-6
             return False
-        self._bound.observe(dt)
-        _trace_buffer.append({
-            "name": "train_step", "ph": "X", "pid": os.getpid(),
-            "tid": threading.get_ident() & 0xFFFF,
-            "ts": int(self.t0 * 1e6), "dur": int(dt * 1e6),
-            "args": {"model": self.model, **self.attrs}})
+        _Span.__exit__(self, *exc)
+        self.duration = dt = (self.t1 - self.t0) * 1e-6
+        reg = MetricsRegistry.get()
+        cache = reg.__dict__.setdefault("_step_bound", {})
+        model = self.args["model"]
+        b = cache.get(model)
+        if b is None:
+            b = cache[model] = histogram(
+                "dl4j_train_step_seconds",
+                _STEP_HELP).bind(model=model)
+        b.observe(dt)
         return False
 
 

@@ -25,10 +25,12 @@ Two propagation mechanisms, on purpose:
   pending tuple) and use :meth:`TraceContext.phase_at` to attribute
   intervals they measured back onto the request's timeline.
 
-Clocks: phase intervals are measured on ``time.monotonic`` and mapped
-onto the unix-epoch microsecond axis chrome-trace uses via the
-context's own (wall, mono) anchor pair, so spans from different
-threads of one request line up without per-thread clock reads.
+Clocks: phase intervals are measured on the process's monotonic clock
+(``time.perf_counter``; ``time.monotonic`` is the same clock on Linux)
+and put on the unix-epoch microsecond axis chrome-trace uses through
+the telemetry spine's ONE anchor (``telemetry.us_of``), so a request's
+phases line up with every other span of the process, whichever thread
+measured them.
 
 Gate: ``DL4J_TPU_REQUEST_TRACE`` (default ON, and also off whenever
 the telemetry spine is off). When off, :func:`start` returns the
@@ -112,16 +114,15 @@ class TraceContext:
     returns the falsy :data:`NULL` instead), thread-safe for the
     cross-thread ``phase_at``/``note`` calls."""
 
-    __slots__ = ("trace_id", "model", "kind", "t0_wall", "t0_mono",
-                 "phases", "attrs", "verdict", "closed", "_lock")
+    __slots__ = ("trace_id", "model", "kind", "t0", "phases", "attrs",
+                 "verdict", "closed", "_lock")
 
     def __init__(self, model: str, kind: str,
                  trace_id: Optional[str] = None):
         self.trace_id = trace_id or mint_trace_id()
         self.model = model
         self.kind = kind                    # "predict" | "generate"
-        self.t0_wall = time.time()
-        self.t0_mono = time.monotonic()
+        self.t0 = time.perf_counter()       # ingress, monotonic
         #: (phase, start_mono, dur_s) — the recorder's phase breakdown
         self.phases: List[Tuple[str, float, float]] = []
         self.attrs: dict = {}
@@ -134,19 +135,19 @@ class TraceContext:
 
     # -- clock mapping -------------------------------------------------
     def wall(self, mono_t: float) -> float:
-        """A ``time.monotonic`` instant on this request's wall-clock
-        axis (the anchor pair was read together at ingress)."""
-        return self.t0_wall + (mono_t - self.t0_mono)
+        """A monotonic-clock instant in epoch seconds, through the
+        telemetry spine's one anchor."""
+        return telemetry.us_of(mono_t) * 1e-6
 
     # -- phases --------------------------------------------------------
     @contextmanager
     def phase(self, name: str):
         """Time the with-block as phase ``name`` of this request."""
-        t0 = time.monotonic()
+        t0 = time.perf_counter()
         try:
             yield
         finally:
-            self.phase_at(name, t0, time.monotonic())
+            self.phase_at(name, t0, time.perf_counter())
 
     def phase_at(self, name: str, mono_t0: float,
                  mono_t1: float) -> None:
@@ -172,7 +173,7 @@ class TraceContext:
 
     # -- completion ----------------------------------------------------
     def elapsed_s(self) -> float:
-        return time.monotonic() - self.t0_mono
+        return time.perf_counter() - self.t0
 
     def finish(self, verdict) -> float:
         """Close the request: emit the ``request`` root span covering
@@ -186,7 +187,7 @@ class TraceContext:
             self.verdict = str(verdict)
             attrs = dict(self.attrs)
         dur = self.elapsed_s()
-        telemetry.span_at("request", self.t0_wall, dur,
+        telemetry.span_at("request", self.wall(self.t0), dur,
                           trace=self.trace_id, model=self.model,
                           kind=self.kind, verdict=self.verdict,
                           **attrs)

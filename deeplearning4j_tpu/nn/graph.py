@@ -975,6 +975,13 @@ class ComputationGraph:
         if lmasks is not None:
             lmasks = [(_as_jnp(m) if m is not None else None)
                       for m in lmasks]
+        # the host's whole turn for one step: ``train_step`` (the
+        # jitted call) is its child, the rest is its self time
+        from deeplearning4j_tpu.common import telemetry
+        with telemetry.span("fit.batch", iter=self.iteration_count):
+            self._fit_step(inputs, labels, fmask, lmasks)
+
+    def _fit_step(self, inputs: list, labels: list, fmask, lmasks):
         if self._retrace_guard is None:
             from deeplearning4j_tpu.common.compilecache import RetraceGuard
             self._retrace_guard = RetraceGuard(

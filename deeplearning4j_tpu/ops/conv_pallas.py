@@ -276,14 +276,18 @@ def _epilogue_backward(x, dy, scale, shift, act):
 def _channel_sums(x2d, acc_t):
     M, C = x2d.shape
     bm = _block_rows(M, C)
-    return pl.pallas_call(
-        partial(_stats_kernel, M=M, bm=bm, acc_t=acc_t),
-        grid=(pl.cdiv(M, bm),),
-        in_specs=[pl.BlockSpec((bm, C), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((2, C), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((2, C), acc_t),
-        interpret=kernel_select.interpret_mode(),
-    )(x2d)
+    # kernel-site annotation, as on its neighbours pallas.conv_epilogue
+    # and pallas.bn_bwd: the forward's statistics pass is one name in a
+    # trace, not one per enclosing layer
+    with jax.named_scope("pallas.bn_stats"):
+        return pl.pallas_call(
+            partial(_stats_kernel, M=M, bm=bm, acc_t=acc_t),
+            grid=(pl.cdiv(M, bm),),
+            in_specs=[pl.BlockSpec((bm, C), lambda i: (i, 0))],
+            out_specs=pl.BlockSpec((2, C), lambda i: (0, 0)),
+            out_shape=jax.ShapeDtypeStruct((2, C), acc_t),
+            interpret=kernel_select.interpret_mode(),
+        )(x2d)
 
 
 def _matmul_epilogue(x2d, w2d, bias, act):

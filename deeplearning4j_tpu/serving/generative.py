@@ -29,6 +29,17 @@ iteration.
 Dispatch signatures are recorded into the batcher's ``RetraceGuard``,
 so ``retraces_since_warmup() == 0`` covers the generative path with
 the same proof obligation as predict.
+
+Spans (``telemetry.span``, so also in a running ``jax.profiler`` trace):
+one pass of the loop that admitted or stepped is one
+``generate.iteration``, and its children split the host's turn by
+cause — ``generate.admit`` (with a ``generate.prefill`` a request),
+``generate.build``, ``generate.decode_step`` (``generate.dispatch``
+then ``generate.pull``) and ``generate.emit``. They inherit the
+iteration's ``iter``; what no child covers is the iteration's self
+time. ``generate.decode_step`` carries the counts of the step it
+dispatched: ``live`` rows of ``bucket``, ``pool_live`` of
+``pool_usable`` blocks.
 """
 from __future__ import annotations
 
@@ -43,7 +54,7 @@ import numpy as np
 from deeplearning4j_tpu.common import telemetry
 from deeplearning4j_tpu.common.compilecache import RetraceGuard
 from deeplearning4j_tpu.serving.admission import DeadlineExceeded
-from deeplearning4j_tpu.serving.kvcache import KVBlockPool
+from deeplearning4j_tpu.serving.kvcache import KVBlockPool, PoolExhausted
 
 #: terminal reasons a TokenStream closes with
 END_REASONS = ("eos", "max_tokens", "cancelled", "deadline", "kv_pool",
@@ -64,6 +75,24 @@ def _intertoken_hist() -> telemetry.Histogram:
         "gap between consecutive streamed tokens of one sequence — "
         "the decode-iteration latency a streaming client experiences "
         "(seconds)")
+
+
+def _decode_step_hist() -> telemetry.Histogram:
+    return telemetry.histogram(
+        "dl4j_generate_decode_step_seconds",
+        "wall time of one fused decode iteration over the live batch "
+        "(gather + paged attention + sample + append), per model "
+        "(seconds)")
+
+
+def _occupancy_hist() -> telemetry.Histogram:
+    return telemetry.histogram(
+        "dl4j_serving_batch_occupancy",
+        "live rows / bucket-padded rows per serving flush — "
+        "how full the warm buckets actually run (1.0 = no "
+        "padding waste; continuous batching should push this "
+        "up under load)",
+        buckets=telemetry.RATIO_BUCKETS)
 
 
 def _tokens_counter() -> telemetry.Counter:
@@ -237,6 +266,9 @@ class DecodeEngine:
         self._work = threading.Event()
         self._shutdown = False
         self._step = 0
+        self._iter = 0              # engine iterations, the spans' id
+        self._meters_of = None      # the registry _meters() bound to
+        self._bound = None          # ... and what it bound there
         self._warmed = False
         self.warm_signatures = 0
         self._jits: dict = {}
@@ -398,7 +430,7 @@ class DecodeEngine:
             self._ensure_worker()
             self._pending.put((seq_id, prompt, max_tokens,
                                float(temperature), int(top_k),
-                               deadline, stream, time.monotonic(),
+                               deadline, stream, time.perf_counter(),
                                ctx))
         self._work.set()
         return stream
@@ -439,33 +471,39 @@ class DecodeEngine:
     def _loop(self):
         me = threading.current_thread()
         while True:
-            # Clear BEFORE draining: a submit that lands after the
-            # drain re-sets the event, so the wait below returns
+            # Clear BEFORE looking: a submit that lands after the
+            # look re-sets the event, so the wait below returns
             # immediately instead of losing the wake-up.
             self._work.clear()
-            admitted = self._admit_pending()
-            stepped = self._decode_iteration()
-            if admitted or stepped:
+            if self._pending.empty() and not self._live:
+                # Idle — and only exit on shutdown/supersession while
+                # idle: every pending request was admitted and every
+                # admitted sequence retired, so no stream is stranded.
+                if self._shutdown or self._worker is not me:
+                    return
+                # Block until a submit wakes us (bounded so queued
+                # deadline/cancel checks still tick over).
+                self._work.wait(0.05)
                 continue
-            # Idle — and only exit on shutdown/supersession while
-            # idle: every pending request was admitted and every
-            # admitted sequence retired, so no stream is stranded.
-            if self._shutdown or self._worker is not me:
-                return
-            # Block until a submit wakes us (bounded so queued
-            # deadline/cancel checks still tick over).
-            self._work.wait(0.05)
+            self._iter += 1
+            with telemetry.span("generate.iteration", model=self.name,
+                                iter=self._iter):
+                if not self._pending.empty():
+                    with telemetry.span("generate.admit") as args:
+                        args["admitted"] = self._admit_pending()
+                self._decode_iteration()
 
-    def _admit_pending(self) -> bool:
+    def _admit_pending(self) -> int:
         """Prefill every queued request (each its own bucket-padded
-        pass), then join it to the decode batch."""
-        admitted = False
+        pass), then join it to the decode batch. Returns how many
+        left the queue."""
+        admitted = 0
         while True:
             try:
                 item = self._pending.get_nowait()
             except _queue.Empty:
                 return admitted
-            admitted = True
+            admitted += 1
             (seq_id, prompt, max_tokens, temperature, top_k, deadline,
              stream, t_submit, ctx) = item
             if stream.cancelled or (deadline is not None
@@ -473,7 +511,7 @@ class DecodeEngine:
                 reason = "cancelled" if stream.cancelled else "deadline"
                 self.pool.free(seq_id)
                 if ctx is not None:
-                    ctx.phase_at("queue", t_submit, time.monotonic())
+                    ctx.phase_at("queue", t_submit, time.perf_counter())
                 self._finish(stream, reason)
                 continue
             try:
@@ -493,19 +531,22 @@ class DecodeEngine:
     def _prefill_one(self, seq_id, prompt, max_tokens, temperature,
                      top_k, deadline, stream, t_submit, ctx=None):
         import jax
-
-        from deeplearning4j_tpu.ops.sampling import sample_logits
-        t_prefill = time.monotonic()
-        if ctx is not None:
+        t_prefill = time.perf_counter()
+        whose = {"seq": seq_id}
+        if ctx:
             # engine-side queue phase: submit -> prefill start
             ctx.phase_at("queue", t_submit, t_prefill)
+            whose["trace"] = ctx.trace_id
         t = self._prompt_bucket(prompt.size)
-        tokens = np.zeros((1, t), np.int32)
-        tokens[0, :prompt.size] = prompt
-        length = np.asarray([prompt.size], np.int32)
-        self._record(tokens, length)
-        with telemetry.span("generate.prefill", model=self.name,
-                            tokens=int(prompt.size)):
+        with telemetry.span(
+                "generate.prefill", model=self.name,
+                tokens=int(prompt.size), bucket=t,
+                queue_ms=round((t_prefill - t_submit) * 1e3, 3),
+                **whose):
+            tokens = np.zeros((1, t), np.int32)
+            tokens[0, :prompt.size] = prompt
+            length = np.asarray([prompt.size], np.int32)
+            self._record(tokens, length)
             last, k, v = self._prefill_jit()(self.params, tokens,
                                              length)
             # scatter the prompt's K/V into its pool blocks (padded
@@ -529,7 +570,7 @@ class DecodeEngine:
                 last, key,
                 np.asarray([temperature], np.float32),
                 np.asarray([top_k], np.int32)))[0])
-        now = time.monotonic()
+        now = time.perf_counter()
         _ttft_hist().observe(now - t_submit, model=self.name)
         if ctx is not None:
             # the prefill forward + commit + first-token sample is
@@ -560,8 +601,7 @@ class DecodeEngine:
     def _retire(self, seq: _Sequence, reason: str,
                 error: Optional[BaseException] = None) -> None:
         self._live.pop(seq.seq_id, None)
-        freed = self.pool.free(seq.seq_id)
-        del freed
+        self.pool.free(seq.seq_id)
         self._finish(seq.stream, reason, error)
         _live_gauge().set(len(self._live), model=self.name)
 
@@ -572,12 +612,53 @@ class DecodeEngine:
             _disconnects_counter().inc(model=self.name)
         _requests_counter().inc(model=self.name, outcome=reason)
 
-    def _decode_iteration(self) -> bool:
+    def _meters(self):
+        """The per-step and per-token meters with this engine's labels
+        resolved, bound once (again only if the registry is replaced):
+        (decode-step seconds, occupancy, tokens, inter-token gap)."""
+        reg = telemetry.MetricsRegistry.get()
+        if self._meters_of is not reg:
+            self._meters_of = reg
+            self._bound = (
+                _decode_step_hist().bind(model=self.name),
+                _occupancy_hist().bind(model=self.name,
+                                       policy="decode"),
+                _tokens_counter().bind(model=self.name),
+                _intertoken_hist().bind(model=self.name))
+        return self._bound
+
+    def _decode_iteration(self) -> None:
         """ONE fused step over all live sequences (the iteration of
-        iteration-level scheduling). Returns False when idle."""
-        import jax
+        iteration-level scheduling)."""
         if not self._live:
-            return False
+            return
+        with telemetry.span("generate.build"):
+            step = self._build_step()
+        if step is None:
+            return
+        seqs, b, inputs = step
+        pool = self.pool
+        t0 = time.perf_counter()
+        with telemetry.span(
+                "generate.decode_step", model=self.name, live=len(seqs),
+                bucket=b, pool_usable=pool.usable_blocks,
+                pool_live=pool.usable_blocks - pool.free_blocks):
+            with telemetry.span("generate.dispatch",
+                                program="decode_step"):
+                ids, kp, vp = self._decode_jit()(
+                    self.params, pool.k, pool.v, *inputs)
+            with telemetry.span("generate.pull"):
+                ids = np.asarray(ids)
+        step_s = time.perf_counter() - t0
+        with telemetry.span("generate.emit", tokens=len(seqs)) as args:
+            pool.update_arrays(kp, vp)
+            args["retired"] = self._emit(seqs, b, ids, step_s)
+
+    def _build_step(self):
+        """Everything the host does before a step can be dispatched:
+        pre-step retirement, one more token slot for every row, the
+        padded inputs and the step's key. None when no row is left."""
+        import jax
         now = time.monotonic()
         # pre-step retirement: cancelled / deadline sequences leave
         # and their blocks free before we spend device time
@@ -586,18 +667,15 @@ class DecodeEngine:
                 self._retire(seq, "cancelled")
             elif seq.deadline is not None and now >= seq.deadline:
                 self._retire(seq, "deadline")
-        if not self._live:
-            return True
         # grow every sequence by one token slot; a pool with no free
         # block sheds THAT sequence mid-batch, the rest keep decoding
-        from deeplearning4j_tpu.serving.kvcache import PoolExhausted
         for seq in list(self._live.values()):
             try:
                 self.pool.extend(seq.seq_id, 1)
             except PoolExhausted as e:
                 self._retire(seq, "kv_pool", e)
         if not self._live:
-            return True
+            return None
         seqs = list(self._live.values())[:self.decode_buckets[-1]]
         b = self._decode_bucket(len(seqs))
         tokens = np.zeros((b,), np.int32)
@@ -615,51 +693,35 @@ class DecodeEngine:
         self._record(tokens, positions, tables, temps, topks)
         self._step += 1
         key = jax.random.fold_in(self._rng, self._step)
-        t0 = time.perf_counter()
-        with telemetry.span("generate.decode_step", model=self.name,
-                            live=len(seqs), bucket=b):
-            ids, kp, vp = self._decode_jit()(
-                self.params, self.pool.k, self.pool.v, tokens,
-                positions, tables, key, temps, topks)
-            ids = np.asarray(ids)
-        self.pool.update_arrays(kp, vp)
-        if telemetry.enabled():
-            telemetry.histogram(
-                "dl4j_generate_decode_step_seconds",
-                "wall time of one fused decode iteration over the "
-                "live batch (gather + paged attention + sample + "
-                "append), per model (seconds)").observe(
-                    time.perf_counter() - t0, model=self.name)
-            telemetry.histogram(
-                "dl4j_serving_batch_occupancy",
-                "live rows / bucket-padded rows per serving flush — "
-                "how full the warm buckets actually run (1.0 = no "
-                "padding waste; continuous batching should push this "
-                "up under load)",
-                buckets=telemetry.RATIO_BUCKETS).observe(
-                    len(seqs) / max(1, b), model=self.name,
-                    policy="decode")
-        now = time.monotonic()
+        return seqs, b, (tokens, positions, tables, key, temps, topks)
+
+    def _emit(self, seqs, b, ids, step_s) -> int:
+        """Hand every row its token: meters, the stream's queue, the
+        request's ``inter_token`` instant, and retirement on EOS or
+        ``max_tokens``. Returns how many rows retired."""
+        step_hist, occupancy, tokens, gap_hist = self._meters()
+        step_hist.observe(step_s)
+        occupancy.observe(len(seqs) / max(1, b))
+        now = time.perf_counter()
         eos = self.model.conf.eos_id
+        retired = 0
         for i, seq in enumerate(seqs):
             tok = int(ids[i])
             seq.stream._put(tok)
-            _tokens_counter().inc(model=self.name)
+            tokens.inc()
             if seq.ctx is not None:
                 seq.ctx.instant(
                     "inter_token", index=seq.generated,
                     gap_ms=round((now - seq.t_last) * 1e3, 3))
-            _intertoken_hist().observe(now - seq.t_last,
-                                       model=self.name)
+            gap_hist.observe(now - seq.t_last)
             seq.t_last = now
             seq.position += 1
             seq.next_token = tok
             seq.generated += 1
-            if tok == eos:
-                self._retire(seq, "eos")
-            elif seq.generated >= seq.max_tokens:
-                self._retire(seq, "max_tokens")
-        return True
+            if tok == eos or seq.generated >= seq.max_tokens:
+                self._retire(seq, "eos" if tok == eos else "max_tokens")
+                retired += 1
+        return retired
 
     def _record(self, *arrays) -> None:
         hit = self.guard.record(*arrays)

@@ -167,8 +167,10 @@ class KVBlockPool:
         if not telemetry.enabled():
             return
         g = _blocks_gauge()
+        # a block is free or in a table: live is one subtraction, not
+        # a sum over every table (this runs on every alloc/extend/free)
         g.set(len(self._free), pool=self.name, state="free")
-        g.set(sum(len(t) for t in self._tables.values()),
+        g.set(self.usable_blocks - len(self._free),
               pool=self.name, state="live")
 
     # -- lifecycle ------------------------------------------------------

@@ -314,13 +314,13 @@ class ServingRouter:
         if tid is None and tracectx.request_trace_enabled():
             tid = tracectx.mint_trace_id()
         handler._trace_id = tid
-        t0_wall, t0_mono = time.time(), time.monotonic()
+        t0 = time.perf_counter()
 
         def route_span(replica: str, status) -> None:
             if tid:
                 telemetry.span_at(
-                    "req.route", t0_wall,
-                    time.monotonic() - t0_mono, trace=tid,
+                    "req.route", telemetry.us_of(t0) * 1e-6,
+                    time.perf_counter() - t0, trace=tid,
                     replica=replica, status=str(status))
 
         body = handler.read_body()

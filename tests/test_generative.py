@@ -246,6 +246,202 @@ class TestDecodeEngine:
         assert pool.live_blocks == 0
 
 
+def _run_three(eng):
+    """Three requests, the third joining while the first two decode:
+    admits, steps, mid-batch retirement and a last lone row."""
+    s1 = eng.submit(np.array([5, 9, 2, 7]), 6)
+    s2 = eng.submit(np.array([8, 3]), 10)
+    first = s1.next(timeout=30)
+    s3 = eng.submit(np.array([4, 4, 1]), 5)
+    out = [[first] + list(s1), list(s2), list(s3)]
+    eng.shutdown()
+    return out
+
+
+def _spans(prefix="generate."):
+    from deeplearning4j_tpu.common import telemetry
+    return [e for e in telemetry.trace_events()
+            if e["ph"] == "X" and e["name"].startswith(prefix)]
+
+
+class TestEngineSpans:
+    """ISSUE 26: the loop split where the work happens. One pass that
+    admitted or stepped is one ``generate.iteration``; its children
+    carry its ``iter``, lie inside it and do not overlap."""
+
+    @pytest.fixture(autouse=True)
+    def _clean_ring(self):
+        from deeplearning4j_tpu.common.telemetry import MetricsRegistry
+        MetricsRegistry._reset_for_tests()
+        yield
+        MetricsRegistry._reset_for_tests()
+
+    def test_every_iteration_is_partitioned_by_its_children(self):
+        model, pool, eng = _engine()
+        _spans()                            # warm-up records no span
+        assert not _spans()
+        tokens = _run_three(eng)
+        assert [len(t) for t in tokens][:2] == [6, 10]
+        ev = _spans()
+        its = [e for e in ev if e["name"] == "generate.iteration"]
+        assert [e["args"]["iter"] for e in its] == \
+            list(range(1, len(its) + 1))    # exactly one an iteration
+        assert len(its) >= 9               # the ten tokens of s2
+        for it in its:
+            i = it["args"]["iter"]
+            mine = [e for e in ev if e is not it
+                    and e["args"].get("iter") == i]
+            kids = sorted((e for e in mine if e["args"]["parent"]
+                           == "generate.iteration"),
+                          key=lambda e: e["ts"])
+            assert kids, i
+            assert {e["name"] for e in kids} <= {
+                "generate.admit", "generate.build",
+                "generate.decode_step", "generate.emit"}
+            edge = it["ts"]
+            for k in kids:                  # inside, in order, disjoint
+                assert k["ts"] >= edge
+                edge = k["ts"] + k["dur"]
+            assert edge <= it["ts"] + it["dur"]
+            for step in (k for k in kids
+                         if k["name"] == "generate.decode_step"):
+                inner = sorted((e for e in mine if e["args"]["parent"]
+                                == "generate.decode_step"),
+                               key=lambda e: e["ts"])
+                assert [e["name"] for e in inner] == [
+                    "generate.dispatch", "generate.pull"]
+                assert inner[0]["args"]["program"] == "decode_step"
+                assert step["ts"] <= inner[0]["ts"]
+                assert inner[0]["ts"] + inner[0]["dur"] <= inner[1]["ts"]
+                assert inner[1]["ts"] + inner[1]["dur"] \
+                    <= step["ts"] + step["dur"]
+        # every span of the family belongs to some iteration
+        assert all(e["args"].get("iter") for e in ev)
+
+    def test_the_counts_ride_on_the_spans(self):
+        model, pool, eng = _engine()
+        served = sum(len(t) for t in _run_three(eng))
+        ev = _spans()
+        steps = [e["args"] for e in ev
+                 if e["name"] == "generate.decode_step"]
+        assert steps and all(
+            1 <= a["live"] <= a["bucket"] == 4
+            and 1 <= a["pool_live"] <= a["pool_usable"] == 63
+            for a in steps)
+        assert max(a["live"] for a in steps) == 3
+        # a row holds at least one block: the pool's count follows
+        assert all(a["pool_live"] >= a["live"] for a in steps)
+        prefills = [e["args"] for e in ev
+                    if e["name"] == "generate.prefill"]
+        assert [a["seq"] for a in prefills] == [1, 2, 3]
+        assert [a["tokens"] for a in prefills] == [4, 2, 3]
+        for a in prefills:                  # no ctx was passed
+            assert a["parent"] == "generate.admit" and "trace" not in a
+            assert a["bucket"] == 16 and a["queue_ms"] >= 0
+        admits = [e["args"] for e in ev if e["name"] == "generate.admit"]
+        assert sum(a["admitted"] for a in admits) == 3
+        emits = [e["args"] for e in ev if e["name"] == "generate.emit"]
+        # every token but each request's first, which its prefill gave
+        assert sum(a["tokens"] for a in emits) == served - 3
+        assert sum(a["retired"] for a in emits) == 3
+
+    def test_a_request_context_puts_its_trace_id_on_the_prefill(self):
+        from deeplearning4j_tpu.common import tracectx
+        model, pool, eng = _engine()
+        ctx = tracectx.start("t-gen", "generate")
+        list(eng.submit(np.array([5, 9, 2, 7]), 3, ctx=ctx))
+        eng.shutdown()
+        (prefill,) = [e for e in _spans()
+                      if e["name"] == "generate.prefill"]
+        assert prefill["args"]["trace"] == ctx.trace_id
+        queue = [e for e in _spans("req.") if e["name"] == "req.queue"]
+        assert len(queue) == 1
+        # the request's phases and the engine's spans share the clock
+        assert abs(queue[0]["ts"] + queue[0]["dur"]
+                   - prefill["ts"]) < 1000
+
+    def test_an_idle_engine_records_nothing(self):
+        model, pool, eng = _engine()
+        list(eng.submit(np.array([5, 9]), 2))
+        time.sleep(0.2)     # the last iteration's span closes after
+        n = len(_spans())   # the consumer has its last token
+        time.sleep(0.3)                     # six idle polls
+        assert len(_spans()) == n
+        eng.shutdown()
+
+    def test_telemetry_off_same_tokens_and_an_empty_ring(
+            self, monkeypatch):
+        from deeplearning4j_tpu.common import telemetry
+        from deeplearning4j_tpu.common.environment import Environment
+        on = _run_three(_engine()[2])
+        assert _spans()
+        monkeypatch.setenv("DL4J_TPU_TELEMETRY", "0")
+        Environment.reset()
+        telemetry.MetricsRegistry._reset_for_tests()
+        try:
+            assert not telemetry.enabled()
+            off = _run_three(_engine()[2])
+            assert telemetry.trace_events() == []
+        finally:
+            monkeypatch.delenv("DL4J_TPU_TELEMETRY")
+            Environment.reset()
+        assert on == off
+
+    def test_the_spans_have_twins_in_a_profile(self, tmp_path):
+        """Where ``jax.profiler`` can trace this machine: every
+        ``generate.*`` ring span lies in the ``/host:CPU`` plane too,
+        within 100 us of the ring's stamp mapped as the benchmark maps
+        it (``lo + (perf_counter_of(ts) - ta)`` off a ``cb.window``
+        annotation)."""
+        import glob
+        import jax
+        from deeplearning4j_tpu.common import telemetry
+        model, pool, eng = _engine()
+        try:
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            opts.host_tracer_level = 2
+            jax.profiler.start_trace(str(tmp_path),
+                                     profiler_options=opts)
+        except Exception as e:              # noqa: BLE001
+            eng.shutdown()
+            pytest.skip(f"jax.profiler cannot trace here: {e}")
+        try:
+            with jax.profiler.TraceAnnotation("cb.window"):
+                ta = time.perf_counter()
+                _run_three(eng)
+        finally:
+            jax.profiler.stop_trace()
+        files = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                          recursive=True)
+        data = jax.profiler.ProfileData.from_file(sorted(files)[-1])
+        host = [p for p in data.planes if p.name == "/host:CPU"]
+        assert len(host) == 1
+        twins = {}
+        for line in host[0].lines:
+            for e in line.events:
+                if e.name.startswith(("generate.", "cb.")):
+                    twins.setdefault(e.name, []).append(
+                        (e.start_ns * 1e-9, e.duration_ns * 1e-9))
+        (lo, _), = twins["cb.window"]
+        ring = _spans()
+        assert len(ring) > 40
+        off = []
+        for name in {e["name"] for e in ring}:
+            mine = sorted((lo + telemetry.perf_counter_of(e["ts"]) - ta,
+                           e["dur"] * 1e-6)
+                          for e in ring if e["name"] == name)
+            theirs = sorted(twins[name])
+            assert len(mine) == len(theirs), name
+            off += [abs(a[0] - b[0]) for a, b in zip(mine, theirs)]
+            off += [abs(a[0] + a[1] - b[0] - b[1])
+                    for a, b in zip(mine, theirs)]
+        # a stall of this shared CPU between the two stamps of one
+        # span is not the clock's: the typical span must agree
+        assert np.percentile(off, 90) < 100e-6, np.percentile(
+            off, [50, 90, 100])
+
+
 def _mesh_1d():
     import jax
 

@@ -8,6 +8,7 @@ import math
 import subprocess
 import sys
 import threading
+import time
 import urllib.request
 from pathlib import Path
 
@@ -509,3 +510,171 @@ class TestSatellites:
         # storage stays appendable after a dirty resume
         again.put_report({"iteration": 3, "time": 4.0, "score": 0.5})
         assert FileStatsStorage(str(p)).latest()["iteration"] == 3
+
+
+def _stamp(kind):
+    """Record one event of ``kind`` and return it."""
+    from deeplearning4j_tpu.common import tracectx
+    n = len(telemetry.trace_events())
+    if kind == "span":
+        with telemetry.span("clock.span"):
+            pass
+    elif kind == "instant":
+        telemetry.instant("clock.instant")
+    elif kind == "span_at":
+        telemetry.span_at("clock.span_at",
+                          telemetry.us_of(time.perf_counter()) * 1e-6,
+                          0.0)
+    elif kind == "step_span":
+        with telemetry.step_span("Clock"):
+            pass
+    elif kind == "phase_at":
+        t = time.perf_counter()
+        tracectx.TraceContext("m", "predict").phase_at("queue", t, t)
+    elif kind == "phase":
+        with tracectx.TraceContext("m", "predict").phase("device"):
+            pass
+    else:
+        t = tracectx.TraceContext("m", "predict")
+        t.finish(200)
+    ev = telemetry.trace_events()[n:]
+    assert len(ev) == 1
+    return ev[0]
+
+
+class TestOneClock:
+    """ISSUE 26: one anchor, read once; every span, instant and
+    request phase is stamped in epoch microseconds derived from
+    ``perf_counter`` through it."""
+
+    @pytest.mark.parametrize("kind", [
+        "span", "instant", "span_at", "step_span", "phase_at", "phase",
+        "request"])
+    def test_stamps_through_now_us_and_ignores_the_wall_clock(
+            self, kind, monkeypatch):
+        # a wall clock that is stepped back an hour at every read: a
+        # stamp taken from it would fall outside the bracket, and two
+        # in a row would run backwards
+        wall = iter(range(10**9, 0, -3600))
+        monkeypatch.setattr(time, "time", lambda: float(next(wall)))
+        lo = telemetry.now_us()
+        first = _stamp(kind)
+        second = _stamp(kind)
+        hi = telemetry.now_us()
+        assert lo <= first["ts"] <= second["ts"] <= hi
+        assert first.get("dur", 0) >= 0
+
+    def test_perf_counter_of_inverts_now_us(self):
+        for _ in range(200):
+            p0 = time.perf_counter()
+            ts = telemetry.now_us()
+            p1 = time.perf_counter()
+            assert p0 - 2e-6 <= telemetry.perf_counter_of(ts) <= p1 + 2e-6
+            assert abs(telemetry.us_of(telemetry.perf_counter_of(ts))
+                       - ts) <= 1
+        wall_s, perf_s = telemetry.CLOCK_ANCHOR_S
+        assert abs(telemetry.us_of(perf_s) - wall_s * 1e6) <= 2
+        # the epoch axis the exporters expect: the anchor is "now"
+        assert abs(telemetry.now_us() * 1e-6 - time.time()) < 3600
+
+    def test_monotonic_is_the_same_clock_as_perf_counter(self):
+        # phase instants callers took with time.monotonic() convert
+        # through the same anchor (Linux: both CLOCK_MONOTONIC)
+        assert abs(time.monotonic() - time.perf_counter()) < 1e-3
+
+    def test_no_wall_or_monotonic_clock_stamps_in_the_two_modules(self):
+        import re
+        for mod in ("telemetry", "tracectx"):
+            src = (_ROOT / "deeplearning4j_tpu" / "common"
+                   / f"{mod}.py").read_text()
+            assert not re.search(r"time\.monotonic\(\)", src), mod
+            # what is left of time.time() stamps no span: a histogram's
+            # exemplar and a stats report's own "time" field
+            left = [ln.strip() for ln in src.splitlines()
+                    if "time.time()" in ln and not ln.lstrip()
+                    .startswith("#")]
+            assert all("exemplar" in ln or '"time"' in ln
+                       for ln in left), left
+
+    def test_span_names_its_parent_and_hands_down_the_shared_id(self):
+        with telemetry.span("outer", iter=7, model="m") as outer:
+            with telemetry.span("inner", rows=3) as inner:
+                inner["late"] = 1           # known when the block ends
+                with telemetry.step_span("Net"):
+                    pass
+            with telemetry.span("own", iter=8):
+                pass
+        with telemetry.span("after"):
+            pass
+        ev = {e["name"]: e for e in telemetry.trace_events()}
+        assert ev["outer"]["args"] == {"iter": 7, "model": "m"} == outer
+        assert ev["inner"]["args"] == {"parent": "outer", "iter": 7,
+                                       "rows": 3, "late": 1}
+        assert ev["train_step"]["args"] == {"parent": "inner", "iter": 7,
+                                            "model": "Net"}
+        assert ev["own"]["args"] == {"parent": "outer", "iter": 8}
+        assert ev["after"]["args"] == {}    # nothing open: no parent
+        for child, parent in (("inner", "outer"), ("train_step", "inner"),
+                              ("own", "outer")):
+            c, p = ev[child], ev[parent]
+            assert p["ts"] <= c["ts"]
+            assert c["ts"] + c["dur"] <= p["ts"] + p["dur"]
+
+    def test_parent_is_per_thread(self):
+        seen = {}
+
+        def other():
+            with telemetry.span("elsewhere") as args:
+                seen.update(args)
+        with telemetry.span("here", iter=1):
+            t = threading.Thread(target=other)
+            t.start()
+            t.join(10)
+        assert not t.is_alive() and seen == {}
+
+    def test_a_raising_block_still_closes_its_span(self):
+        with pytest.raises(ValueError):
+            with telemetry.span("outer"):
+                with telemetry.span("boom"):
+                    raise ValueError
+        with telemetry.span("next"):
+            pass
+        ev = {e["name"]: e["args"] for e in telemetry.trace_events()}
+        assert ev["boom"] == {"parent": "outer"} and ev["next"] == {}
+
+    def test_fit_batch_is_the_parent_of_train_step(self):
+        from deeplearning4j_tpu.activations import Activation
+        from deeplearning4j_tpu.datasets.dataset import DataSet
+        from deeplearning4j_tpu.learning import Adam
+        from deeplearning4j_tpu.lossfunctions import LossFunction
+        from deeplearning4j_tpu.nn import (InputType,
+                                           NeuralNetConfiguration)
+        from deeplearning4j_tpu.nn.conf.layers import (DenseLayer,
+                                                       OutputLayer)
+        from deeplearning4j_tpu.nn.graph import ComputationGraph
+        g = (NeuralNetConfiguration.Builder().seed(0)
+             .updater(Adam(1e-2)).graph_builder().add_inputs("in"))
+        g.add_layer("d", DenseLayer(n_out=4,
+                                    activation=Activation.RELU), "in")
+        g.add_layer("out", OutputLayer(
+            n_out=2, loss_function=LossFunction.MCXENT,
+            activation=Activation.SOFTMAX), "d")
+        g.set_outputs("out")
+        g.set_input_types(InputType.feed_forward(4))
+        net = ComputationGraph(g.build()).init()
+        rng = np.random.RandomState(0)
+        ds = DataSet(rng.randn(8, 4).astype(np.float32),
+                     np.eye(2, dtype=np.float32)[rng.randint(0, 2, 8)])
+        for _ in range(3):
+            net.fit(ds)
+        ev = telemetry.trace_events()
+        batches = [e for e in ev if e["name"] == "fit.batch"]
+        steps = [e for e in ev if e["name"] == "train_step"]
+        assert [e["args"]["iter"] for e in batches] == [0, 1, 2]
+        assert len(steps) == 3
+        for b, s in zip(batches, steps):
+            assert s["args"]["parent"] == "fit.batch"
+            assert s["args"]["iter"] == b["args"]["iter"]
+            assert s["args"]["model"] == "ComputationGraph"
+            assert b["ts"] <= s["ts"]
+            assert s["ts"] + s["dur"] <= b["ts"] + b["dur"]
