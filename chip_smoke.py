@@ -820,12 +820,15 @@ def phase_kernels(*, epilogue_rows=128 * 56 * 56, epilogue_k=64,
                    f"({time.perf_counter() - t0:.1f}s)")
 
     # -- paged decode attention, standalone -----------------------------
+    # full rows, a row of length 1 (what a dead row of the bucket is)
+    # and a ragged one; the float32 pool and the bf16 pool that the
+    # benchmark's engine holds
     pb, ph, pd, nb, bs, width = paged
     kq2, kk2, kv2 = jax.random.split(kq, 3)
     pq = jax.random.normal(kq2, (pb, ph, pd), jnp.float32)
-    k_pool = jax.random.normal(kk2, (nb, bs, ph, pd), jnp.float32)
-    v_pool = jax.random.normal(kv2, (nb, bs, ph, pd), jnp.float32)
-    lens = np.array([1 + (7 * (i + 1)) % (width * bs)
+    full = width * bs
+    lens = np.array([full if i % 2 == 0 else 1 if i == 1
+                     else 1 + (7 * (i + 1)) % full
                      for i in range(pb)], np.int32)
     tables = np.zeros((pb, width), np.int32)
     nxt = 1
@@ -834,18 +837,21 @@ def phase_kernels(*, epilogue_rows=128 * 56 * 56, epilogue_k=64,
             tables[i, j] = nxt
             nxt += 1
     require(nxt <= nb, "pool too small for the paged check")
-    t0 = time.perf_counter()
-    got = jax.jit(paged_decode_attention)(pq, k_pool, v_pool,
-                                          jnp.asarray(tables),
-                                          jnp.asarray(lens))
-    ref = jax.jit(paged_attention_reference)(pq, k_pool, v_pool,
-                                             jnp.asarray(tables),
-                                             jnp.asarray(lens))
-    out["paged_decode"] = rel_err(got, ref)
-    say("kernels", f"paged_decode_attention b={pb} h={ph} d={pd} "
-                   f"lengths {lens.tolist()} vs dense gather: rel "
-                   f"{out['paged_decode']:.2e} "
-                   f"({time.perf_counter() - t0:.1f}s)")
+    k_f32 = jax.random.normal(kk2, (nb, bs, ph, pd), jnp.float32)
+    v_f32 = jax.random.normal(kv2, (nb, bs, ph, pd), jnp.float32)
+    for name, dtype in (("paged_decode", jnp.float32),
+                        ("paged_decode_bf16", bf)):
+        args = (pq, k_f32.astype(dtype), v_f32.astype(dtype),
+                jnp.asarray(tables), jnp.asarray(lens))
+        t0 = time.perf_counter()
+        got = jax.jit(paged_decode_attention)(*args)
+        ref = jax.jit(paged_attention_reference)(*args)
+        out[name] = rel_err(got, ref)
+        say("kernels", f"paged_decode_attention b={pb} h={ph} d={pd} "
+                       f"{jnp.dtype(dtype).name} pool, lengths "
+                       f"{lens.tolist()} vs dense gather: rel "
+                       f"{out[name]:.2e} "
+                       f"({time.perf_counter() - t0:.1f}s)")
 
     bad = {k_: v_ for k_, v_ in out.items()
            if not (np.isfinite(v_) and v_ <= KERNEL_REL_TOL)}

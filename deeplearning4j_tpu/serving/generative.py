@@ -39,7 +39,8 @@ then ``generate.pull``) and ``generate.emit``. They inherit the
 iteration's ``iter``; what no child covers is the iteration's self
 time. ``generate.decode_step`` carries the counts of the step it
 dispatched: ``live`` rows of ``bucket``, ``pool_live`` of
-``pool_usable`` blocks.
+``pool_usable`` blocks, and ``grid_blocks``, the ``bucket x
+max_blocks`` table positions of which ``pool_live`` name live KV.
 """
 from __future__ import annotations
 
@@ -641,7 +642,8 @@ class DecodeEngine:
         t0 = time.perf_counter()
         with telemetry.span(
                 "generate.decode_step", model=self.name, live=len(seqs),
-                bucket=b, pool_usable=pool.usable_blocks,
+                bucket=b, grid_blocks=b * self.max_blocks,
+                pool_usable=pool.usable_blocks,
                 pool_live=pool.usable_blocks - pool.free_blocks):
             with telemetry.span("generate.dispatch",
                                 program="decode_step"):

@@ -327,6 +327,7 @@ class TestEngineSpans:
         assert steps and all(
             1 <= a["live"] <= a["bucket"] == 4
             and 1 <= a["pool_live"] <= a["pool_usable"] == 63
+            and a["grid_blocks"] == 4 * eng.max_blocks == 32
             for a in steps)
         assert max(a["live"] for a in steps) == 3
         # a row holds at least one block: the pool's count follows

@@ -1,0 +1,184 @@
+"""Conformance of the paged decode attention kernel
+(``ops.attention_pallas.paged_decode_attention``, interpret mode here)
+against the dense gather ``paged_attention_reference``: every length
+round a block's and a step's edge, dead rows of the bucket between
+live ones, tables out of pool order, both head shapes the chip runs,
+float32 and bf16 pools, and KV poisoned wherever ``lengths`` says it
+is not live. Then the engine: the same greedy tokens through the
+kernel as through the gather."""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import chip_smoke
+from deeplearning4j_tpu.ops import attention_pallas as ap
+
+BLOCK = 8
+MAX_BLOCKS = 7                    # 56 tokens; not a multiple of 3 blocks
+FULL = BLOCK * MAX_BLOCKS
+
+
+def _pools(h, d, n_blocks, block, dtype, seed):
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
+    k = jax.random.normal(kk, (n_blocks, block, h, d), jnp.float32)
+    v = jax.random.normal(kv, (n_blocks, block, h, d), jnp.float32)
+    return kq, np.array(k.astype(dtype)), np.array(v.astype(dtype))
+
+
+def _tables(lens, block, max_blocks, n_blocks, seed):
+    """Each row's blocks drawn from a shuffled pool (block 0 is the
+    scratch block: dead rows and the padding name it)."""
+    order = np.random.default_rng(seed).permutation(
+        np.arange(1, n_blocks))
+    tables = np.zeros((len(lens), max_blocks), np.int32)
+    nxt = 0
+    for i, n in enumerate(lens):
+        if n == 1 and i % 2:            # a dead row: all scratch
+            continue
+        need = -(-n // block)
+        tables[i, :need] = order[nxt:nxt + need]
+        nxt += need
+    assert nxt <= order.size, "pool too small for the case"
+    return tables
+
+
+def _poison(pool, tables, lens):
+    """NaN in every slot that ``lengths`` does not call live: blocks no
+    row names, the slots past a row's length in its last block, the
+    scratch block past the one slot a dead row reads."""
+    block = pool.shape[1]
+    live = np.zeros(pool.shape[:2], bool)
+    for row, n in zip(tables, lens):
+        for t in range(n):
+            live[row[t // block], t % block] = True
+    out = pool.astype(np.float32)
+    out[~live] = np.nan
+    return out.astype(pool.dtype)
+
+
+def _check(lens, *, h, d, block, max_blocks, dtype, seed=0):
+    lens = list(lens)
+    n_blocks = 2 + sum(-(-n // block) for n in lens)
+    kq, k, v = _pools(h, d, n_blocks, block, dtype, seed)
+    q = jax.random.normal(kq, (len(lens), h, d), jnp.float32)
+    tables = _tables(lens, block, max_blocks, n_blocks, seed)
+    lengths = jnp.asarray(lens, jnp.int32)
+    want = ap.paged_attention_reference(q, jnp.asarray(k), jnp.asarray(v),
+                                        jnp.asarray(tables), lengths)
+    got = jax.jit(ap.paged_decode_attention)(
+        q, jnp.asarray(_poison(k, tables, lens)),
+        jnp.asarray(_poison(v, tables, lens)), jnp.asarray(tables),
+        lengths)
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert np.all(np.isfinite(got)), "poisoned KV reached the output"
+    for i in range(len(lens)):            # row by row: none hides
+        assert chip_smoke.rel_err(got[i], want[i]) \
+            <= chip_smoke.KERNEL_REL_TOL, (i, lens[i])
+
+
+@pytest.fixture
+def three_blocks_a_step(monkeypatch):
+    """Steps of 3 blocks, so a 7-block table takes three steps and its
+    last is short: the loop, the prefetch across rows and the slot
+    flip run at a size the interpreter holds."""
+    monkeypatch.setattr(ap, "_PAGED_STEP_TOKENS", 3 * BLOCK)
+    assert ap._paged_blocks_per_step(BLOCK, 128, 4, MAX_BLOCKS) == 3
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("length", [
+    1, BLOCK - 1, BLOCK, BLOCK + 1, 3 * BLOCK, 3 * BLOCK + 1, FULL - 1,
+    FULL], ids=lambda n: f"len{n}")
+def test_one_row_at_every_edge(three_blocks_a_step, length, dtype):
+    _check([length], h=2, d=64, block=BLOCK, max_blocks=MAX_BLOCKS,
+           dtype=dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("lens", [
+    (1, 1, FULL, 1, 1, BLOCK + 1),        # dead rows round live ones
+    (FULL, 1, 3 * BLOCK, 1),              # a full step, then a dead row
+    (1, 1, 1),                            # nothing but dead rows
+    (5, 30, 55, 17, 9, 41, 2, 24),        # a full bucket
+], ids=["dead-between", "full-then-dead", "all-dead", "all-live"])
+def test_bucket_of_rows(three_blocks_a_step, lens, dtype):
+    _check(lens, h=2, d=64, block=BLOCK, max_blocks=MAX_BLOCKS,
+           dtype=dtype, seed=1)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("h,d", [(20, 64), (2, 64)],
+                         ids=["h20d64", "h2d64"])
+def test_the_cells_shapes(h, d, dtype):
+    """The engine's block of 16 at the constant the chip runs: 16
+    blocks a step over a table of 21, so a 330-token row takes a full
+    step and a short one."""
+    assert ap._paged_blocks_per_step(16, h * d, 2, 21) == 16
+    _check((330, 1, 17, 256, 1), h=h, d=d, block=16, max_blocks=21,
+           dtype=dtype, seed=2)
+
+
+def test_blocks_per_step_follows_the_shapes():
+    per = ap._paged_blocks_per_step
+    assert per(16, 1280, 2, 64) == 16         # the cell: 256 tokens
+    assert per(16, 1280, 2, 8) == 8           # never past the table
+    assert per(16, 16384, 4, 64) == 2         # four slabs inside VMEM
+    assert per(512, 1280, 2, 64) == 1         # a block wider than a step
+
+
+def test_selector_rungs():
+    pick = ap.select_paged_backend
+    assert pick(32, 64, platform="tpu", use_env_override=False)[0] \
+        == "paged"
+    assert pick(32, 64, platform="cpu", use_env_override=False)[0] \
+        == "dense"
+    assert pick(32, 64, platform="tpu", override=False)[0] == "dense"
+    assert pick(32, 64, platform="cpu", override=True)[0] == "paged"
+    assert pick(0, 64, platform="tpu", override=True)[0] == "dense"
+
+
+class TestEngine:
+    """Greedy decoding through the kernel serves the tokens the dense
+    gather serves, with rows joining and leaving mid-batch."""
+
+    @staticmethod
+    def _serve(paged):
+        from deeplearning4j_tpu.models.decoder import (DecoderConfig,
+                                                       DecoderLM)
+        from deeplearning4j_tpu.serving.generative import DecodeEngine
+        from deeplearning4j_tpu.serving.kvcache import KVBlockPool
+        conf = DecoderConfig(vocab_size=64, n_layers=2, n_heads=2,
+                             d_model=128, d_ff=128, max_len=64,
+                             eos_id=64)
+        model = DecoderLM(conf)
+        # weights wide enough that attention moves the logits, narrow
+        # enough that bf16 products do not turn a near-tie
+        params = jax.tree_util.tree_map(
+            lambda w: w * 4.0 if w.ndim == 2 else w, model.init())
+        pool = KVBlockPool(conf.n_layers, 24, 8, conf.n_heads,
+                           conf.head_dim, name=f"t-paged-{paged}")
+        eng = DecodeEngine(model, params, pool, name=f"t-paged-{paged}",
+                           prompt_buckets=(16,), decode_buckets=(4,),
+                           max_seq_len=64, paged=paged)
+        eng.warmup()
+        rng = np.random.default_rng(7)
+        first = [eng.submit(rng.integers(2, 64, n), m)
+                 for n, m in ((5, 40), (9, 12), (3, 25))]
+        head = first[1].next(timeout=60)        # all three are decoding
+        late = [eng.submit(rng.integers(2, 64, n), m)
+                for n, m in ((12, 20), (2, 30))]  # one waits for a row
+        out = [list(s) for s in first + late]
+        out[1].insert(0, head)
+        eng.shutdown()
+        return out
+
+    def test_same_greedy_tokens_as_the_dense_gather(self):
+        dense, paged = self._serve(False), self._serve(True)
+        assert [len(t) for t in dense] == [40, 12, 25, 20, 30]
+        assert paged == dense
