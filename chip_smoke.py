@@ -19,6 +19,12 @@ weights, and checks what comes out by the repo's own means:
             ``:generate``: four concurrent streams to their end, the
             paged kernel in the compiled decode step, and one decode
             step's logits against the dense-gather lowering;
+  hybrid    the Falcon-H1 block (Mamba-2 beside grouped-query
+            attention): the paged kernel with five query heads a KV
+            head and ``pallas.ssm_state_update`` at the published head
+            shapes, each against its ``jax.numpy`` form, then one
+            prefill and eight decode steps of ``FalconH1LM`` through
+            K/V blocks and a state slot against the plain reference;
   kernels   every TPU-default Pallas kernel the model phases do not
             reach, un-interpreted, against its dense lowering;
   four-chip (``len(jax.devices()) >= 4``) ResNet-50 through
@@ -50,7 +56,7 @@ from importlib import metadata
 import numpy as np
 
 FAMILIES = ("conv_epilogue", "bn_fwd", "bn_bwd", "attention",
-            "paged_attention")
+            "paged_attention", "ssm_state")
 #: the Mosaic entry point in compiled HLO / lowered StableHLO text
 MOSAIC_TARGET = "tpu_custom_call"
 
@@ -606,7 +612,7 @@ def phase_generate(conf: dict = GPT2_SMALL, *, n_requests=4,
         # the compiled decode step holds one paged kernel per layer
         eng = ver.batcher.engine
         b = n_requests
-        args = (eng.params, eng.pool.k, eng.pool.v,
+        args = (eng.params, eng.pool.arrays,
                 np.zeros((b,), np.int32), np.zeros((b,), np.int32),
                 np.zeros((b, eng.max_blocks), np.int32),
                 jax.random.PRNGKey(0), np.zeros((b,), np.float32),
@@ -944,6 +950,139 @@ def phase_four_chip(*, batch=256, hw=224, classes=1000, stages=None,
 
 
 # ----------------------------------------------------------------------
+#: a Falcon-H1 block at a small width: every published ratio (5 query
+#: heads a KV head, 2 state groups, convolution 4) and a head state of
+#: whole float32 tiles, so the state-update kernel is on the path
+_H1_PUBLISHED = __file__.replace("chip_smoke.py",
+                                 "chipbench/configs/falcon-h1-34b.json")
+H1_SMALL = dict(vocab_size=1024, num_hidden_layers=2, hidden_size=256,
+                num_attention_heads=10, num_key_value_heads=2,
+                head_dim=64, intermediate_size=512, mamba_d_ssm=256,
+                mamba_n_heads=4, mamba_d_head=64, mamba_d_state=128,
+                init_std=0.05)
+
+
+def phase_hybrid(conf: dict = H1_SMALL, *, gqa=(8, 20, 4, 128, 80, 16, 6),
+                 state=(2, 6, 32, 128, 256, 2, 4), prompt_len=37,
+                 prompt_bucket=128, steps=8, block=16) -> dict:
+    """The hybrid block's two decode kernels against their
+    ``jax.numpy`` forms, then ``FalconH1LM`` (prefill, commit into K/V
+    blocks and a state slot, ``steps`` decode steps) against the plain
+    reference's full forward. ``gqa`` = (rows, query heads, KV heads,
+    head dim, blocks, block, table width); ``state`` = (layers, slots,
+    heads, p, n, groups, rows)."""
+    import jax
+    import jax.numpy as jnp
+
+    from chipbench.models.falcon_h1 import program_layout
+    from chipbench.reference import falcon_h1 as ref
+    from deeplearning4j_tpu.models.falcon_h1 import (FalconH1Config,
+                                                     FalconH1LM)
+    from deeplearning4j_tpu.ops.attention_pallas import (
+        paged_attention_reference, paged_decode_attention)
+    from deeplearning4j_tpu.ops.ssm_pallas import (
+        ssm_state_update_pallas, ssm_state_update_reference)
+    from deeplearning4j_tpu.serving.kvcache import KVBlockPool
+
+    out = {}
+    key = jax.random.PRNGKey(23)
+    # -- grouped-query paged attention ----------------------------------
+    pb, hq, hkv, pd, nb, bs, width = gqa
+    kq, kk, kv, key = jax.random.split(key, 4)
+    lens = np.array([width * bs if i % 2 == 0 else 1 + (11 * i) % (width * bs)
+                     for i in range(pb)], np.int32)
+    tables = np.zeros((pb, width), np.int32)
+    nxt = 1
+    for i in range(pb):
+        for j in range(-(-int(lens[i]) // bs)):
+            tables[i, j] = nxt
+            nxt += 1
+    require(nxt <= nb, "pool too small for the grouped-query check")
+    args = (jax.random.normal(kq, (pb, hq, pd), jnp.float32),
+            jax.random.normal(kk, (nb, bs, hkv, pd)).astype(jnp.bfloat16),
+            jax.random.normal(kv, (nb, bs, hkv, pd)).astype(jnp.bfloat16),
+            jnp.asarray(tables), jnp.asarray(lens))
+    out["paged_gqa"] = rel_err(jax.jit(paged_decode_attention)(*args),
+                               jax.jit(paged_attention_reference)(*args))
+    say("hybrid", f"paged_decode_attention {hq} query heads on {hkv} KV "
+                  f"heads of {pd}, bf16 pool, lengths {lens.tolist()} vs "
+                  f"dense gather: rel {out['paged_gqa']:.2e}")
+
+    # -- the state update, in place ---------------------------------------
+    nl, ns, nh, p, n, g, rows = state
+    ks = jax.random.split(key, 7)
+    pool = jax.random.normal(ks[0], (nl, ns, nh, p, n), jnp.float32)
+    slots = jnp.asarray([ns - 1, 1] + [0] * (rows - 2), jnp.int32)
+    dt = jax.random.uniform(ks[1], (rows, nh), jnp.float32, 1e-3, 0.5)
+    ops = (jax.random.normal(ks[2], (rows, nh, p), jnp.float32), dt,
+           jnp.exp(-4.0 * dt),
+           jax.random.normal(ks[3], (rows, g, n), jnp.float32),
+           jax.random.normal(ks[4], (rows, g, n), jnp.float32))
+    want_s, want_y = jax.jit(ssm_state_update_reference,
+                             static_argnums=1)(pool, nl - 1, slots, *ops)
+    got_s, got_y = jax.jit(ssm_state_update_pallas,
+                           static_argnums=1)(pool, nl - 1, slots, *ops)
+    live = np.asarray([ns - 1, 1])
+    out["ssm_state_y"] = rel_err(got_y[:2], want_y[:2])
+    out["ssm_state_s"] = rel_err(got_s[nl - 1, live], want_s[nl - 1, live])
+    rest = [i for i in range(1, ns) if i not in live]
+    require(bool(jnp.all(got_s[:nl - 1] == pool[:nl - 1]))
+            and bool(jnp.all(got_s[nl - 1, rest] == pool[nl - 1, rest])),
+            "the state update touched a slot no live row named")
+    say("hybrid", f"pallas.ssm_state_update {nh} heads of [{p}, {n}], "
+                  f"{rows} rows of which 2 live, vs its jax.numpy form: "
+                  f"y rel {out['ssm_state_y']:.2e}, state rel "
+                  f"{out['ssm_state_s']:.2e}; other slots untouched")
+
+    # -- the model through blocks and a slot, against the reference --------
+    cfg = dict(json.load(open(_H1_PUBLISHED)), **conf)
+    weights = ref.make_params(cfg, 29)
+    params = program_layout(weights)
+    model = FalconH1LM(FalconH1Config.from_published(
+        cfg, max_len=prompt_bucket + steps))
+    total = prompt_len + steps
+    tokens = np.asarray(jax.random.randint(
+        jax.random.PRNGKey(31), (total,), 0, cfg["vocab_size"]), np.int32)
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(ref.forward(cfg, weights, jnp.asarray(tokens)))
+    padded = np.zeros((1, prompt_bucket), np.int32)
+    padded[0, :prompt_len] = tokens[:prompt_len]
+    last, k, v, ssm, conv = jax.jit(model.prefill)(
+        params, padded, np.asarray([prompt_len], np.int32))
+    n_blocks = -(-total // block)
+    cache_pool = KVBlockPool(
+        cfg["num_hidden_layers"], n_blocks + 1, block,
+        cfg["num_key_value_heads"], cfg["head_dim"], dtype=jnp.bfloat16,
+        name="smoke-h1", state=model.state_shapes(), state_slots=2)
+    idx = np.arange(prompt_bucket)
+    at = np.where(idx < prompt_len, block + idx, 0)     # blocks 1.., in order
+    kp, vp, s_, c_ = cache_pool.arrays
+    flat = (kp.shape[0], -1) + kp.shape[3:]
+    cache = (kp.reshape(flat).at[:, at].set(k[:, 0].astype(kp.dtype)
+                                            ).reshape(kp.shape),
+             vp.reshape(flat).at[:, at].set(v[:, 0].astype(vp.dtype)
+                                            ).reshape(vp.shape),
+             s_.at[:, 1].set(ssm[:, 0]), c_.at[:, 1].set(conv[:, 0]))
+    table = np.arange(1, n_blocks + 1, dtype=np.int32)[None]
+    step = jax.jit(lambda *a: model.decode_step(*a, paged=True))
+    got = [np.asarray(last[0])]
+    for pos in range(prompt_len, total - 1):
+        logits, *cache = step(params, tokens[pos:pos + 1],
+                              np.asarray([pos], np.int32), *cache, table,
+                              np.asarray([1], np.int32))
+        got.append(np.asarray(logits[0]))
+    out["model"] = rel_err(np.stack(got), want[prompt_len - 1:total - 1])
+    say("hybrid", f"FalconH1LM at width {cfg['hidden_size']}: prefill of "
+                  f"{prompt_len} in a bucket of {prompt_bucket} + "
+                  f"{len(got) - 1} decode steps through the cache vs the "
+                  f"plain reference's full forward: rel {out['model']:.2e}")
+    bad = {k_: v_ for k_, v_ in out.items()
+           if not (np.isfinite(v_) and v_ <= KERNEL_REL_TOL)}
+    require(not bad, f"hybrid checks outside tol {KERNEL_REL_TOL}: {bad}")
+    return {"ok": True, **out}
+
+
+# ----------------------------------------------------------------------
 def main() -> int:
     t_start = time.perf_counter()
     dev = require_tpu()
@@ -967,6 +1106,8 @@ def main() -> int:
     del train
     gc.collect()
     timed("generate", phase_generate)
+    gc.collect()
+    timed("hybrid", phase_hybrid)
     gc.collect()
     timed("kernels", phase_kernels)
     gc.collect()

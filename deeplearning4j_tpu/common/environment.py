@@ -130,7 +130,9 @@ class Environment:
       conv/BN/ReLU epilogue family — conv-bias-act, BN statistics +
       normalize, matmul+epilogue for aligned 1x1 convs),
       DL4J_TPU_PAGED_ATTENTION (tri-state: the paged decode-attention
-      Pallas kernel for the serving KV pool; all four gates resolve
+      Pallas kernel for the serving KV pool), DL4J_TPU_SSM_STATE
+      (tri-state: the in-place recurrent-state update kernel for the
+      serving state pool; all five gates resolve
       through the ops/kernel_select.py ladder: structural gate, then
       force/kill, then auto heuristic, every decision counted in
       dl4j_kernel_select_total),

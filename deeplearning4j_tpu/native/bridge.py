@@ -99,7 +99,6 @@ def ensure_built(force: bool = False) -> bool:
     :func:`status` (``chip_smoke.py`` does)."""
     global _lib, _build_attempted, _status
     if os.environ.get("DL4J_TPU_DISABLE_NATIVE"):
-        _status = ("absent", "DL4J_TPU_DISABLE_NATIVE is set")
         return False
     if _lib is not None and not force:
         # lock-free fast path: every native entry point calls this,
@@ -157,7 +156,12 @@ def status() -> Tuple[str, str]:
     """``(state, detail)`` of the native library in this process:
     ``built`` (make compiled it just now), ``loaded`` (make found it
     current), ``absent`` (Python fallbacks in use; detail says why) or
-    ``unattempted`` (nothing has asked for it yet)."""
+    ``unattempted`` (nothing has asked for it yet). The kill switch
+    is read here and not remembered: a process that set it for a while
+    (a test of the fallbacks) reports the loaded library again once it
+    is unset."""
+    if os.environ.get("DL4J_TPU_DISABLE_NATIVE"):
+        return ("absent", "DL4J_TPU_DISABLE_NATIVE is set")
     return _status
 
 

@@ -215,8 +215,11 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables,
     everywhere and defines the semantics the kernel must match
     bit-for-tolerance."""
     b, h, d = q.shape
-    block = k_pool.shape[1]
+    block, h_kv = k_pool.shape[1:3]
     t = block_tables.shape[1] * block
+    if h != h_kv:
+        return _paged_reference_grouped(q, k_pool, v_pool, block_tables,
+                                        lengths, scale)
     k = jnp.reshape(k_pool[block_tables], (b, t, h, d))
     v = jnp.reshape(v_pool[block_tables], (b, t, h, d))
     if scale is None:
@@ -231,6 +234,30 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables,
     w = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
     out = jnp.einsum("bht,bthd->bhd", w, v.astype(jnp.float32))
     return out.astype(q.dtype)
+
+
+def _paged_reference_grouped(q, k_pool, v_pool, block_tables, lengths,
+                             scale):
+    """:func:`paged_attention_reference` where the pool holds fewer KV
+    heads than ``q`` has query heads (grouped-query attention): query
+    head ``i`` reads KV head ``i // (h / h_kv)``."""
+    f32 = jnp.float32
+    b, h, d = q.shape
+    block, h_kv = k_pool.shape[1:3]
+    t = block_tables.shape[1] * block
+    k = jnp.reshape(k_pool[block_tables], (b, t, h_kv, d)).astype(f32)
+    v = jnp.reshape(v_pool[block_tables], (b, t, h_kv, d)).astype(f32)
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
+    qg = q.astype(f32).reshape(b, h_kv, h // h_kv, d)
+    s = jnp.einsum("bkgd,btkd->bkgt", qg, k) * scale
+    valid = jnp.arange(t, dtype=jnp.int32)[None, :] < lengths[:, None]
+    s = jnp.where(valid[:, None, None, :], s, _PAGED_NEG_INF)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.where(s <= _PAGED_NEG_INF / 2, 0.0, jnp.exp(s - m))
+    w = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+    out = jnp.einsum("bkgt,btkd->bkgd", w, v)
+    return out.reshape(b, h, d).astype(q.dtype)
 
 
 #: KV tokens one inner step of the paged kernel multiplies at once:
@@ -256,7 +283,8 @@ def _paged_blocks_per_step(block: int, hd: int, itemsize: int,
 def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm,
                          out_ref, kbuf, vbuf, sem, slot_ref, m_ref,
                          l_ref, acc_ref, *, head_dim: int, block: int,
-                         blocks_per_step: int, scale: float):
+                         blocks_per_step: int, scale: float,
+                         group: int = 1):
     """One sequence a grid step; inside it, a loop over the sequence's
     own ``ceil(length / block)`` blocks, ``blocks_per_step`` at a time.
 
@@ -321,9 +349,21 @@ def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm,
     n_steps = pl.cdiv(n_blocks(i), blocks_per_step)
     # head r owns lanes [r * d, (r + 1) * d) of the flat h*d axis
     lane = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 1)
-    lo = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 0) * head_dim
-    own = (lane >= lo) & (lane < lo + head_dim)
-    qbd = jnp.where(own, q_ref[...].astype(f32), 0.0).astype(bf16)
+    row = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 0)
+    if group == 1:
+        lo = row * head_dim
+        own = (lane >= lo) & (lane < lo + head_dim)
+        owns = [own]
+        qbd = jnp.where(own, q_ref[...].astype(f32), 0.0).astype(bf16)
+    else:
+        # grouped queries: row r is query head r and owns the lanes of
+        # KV head r // group; q_ref / out_ref row j hold, on KV head k's
+        # lanes, query head k * group + j
+        lo = (row // group) * head_dim
+        own = (lane >= lo) & (lane < lo + head_dim)
+        owns = [own & (row % group == j) for j in range(group)]
+        qbd = sum(jnp.where(o, q_ref[j:j + 1, :].astype(f32), 0.0)
+                  for j, o in enumerate(owns)).astype(bf16)
     m_ref[...] = jnp.full_like(m_ref, _PAGED_NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
@@ -366,9 +406,11 @@ def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm,
         return carry
 
     jax.lax.fori_loop(0, n_steps, step, 0)
-    w = jnp.where(own, 1.0 / jnp.maximum(l_ref[...], 1e-30), 0.0)
-    out_ref[...] = jnp.sum(acc_ref[...] * w, axis=0,
-                           keepdims=True).astype(out_ref.dtype)
+    inv_l = 1.0 / jnp.maximum(l_ref[...], 1e-30)
+    for j, o in enumerate(owns):
+        out_ref[j:j + 1, :] = jnp.sum(
+            acc_ref[...] * jnp.where(o, inv_l, 0.0), axis=0,
+            keepdims=True).astype(out_ref.dtype)
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
@@ -385,12 +427,12 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
     the chip's code path."""
     from deeplearning4j_tpu.ops import kernel_select
 
-    h, d = q.shape[1:]
+    d = q.shape[2]
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     per_step = _paged_blocks_per_step(
-        int(k_pool.shape[1]), h * d, k_pool.dtype.itemsize,
-        int(block_tables.shape[1]))
+        int(k_pool.shape[1]), int(k_pool.shape[2]) * d,
+        k_pool.dtype.itemsize, int(block_tables.shape[1]))
     return _paged_call(q, k_pool, v_pool, block_tables, lengths,
                        scale=float(scale), per_step=per_step,
                        interpret=kernel_select.interpret_mode())
@@ -407,8 +449,9 @@ def _paged_call(q, k_pool, v_pool, block_tables, lengths, *, scale,
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, d = q.shape
-    hd = h * d
-    nb, block = k_pool.shape[:2]
+    nb, block, h_kv = k_pool.shape[:3]
+    hd = h_kv * d                         # the pool's lanes a token
+    g = h // h_kv                         # query heads a KV head
     hp = -(-h // 16) * 16                 # heads, a bf16 sublane tile up
 
     def row(i, tables, lens):                     # one sequence's q/out
@@ -418,11 +461,11 @@ def _paged_call(q, k_pool, v_pool, block_tables, lengths, *, scale,
         num_scalar_prefetch=2,          # block_tables, lengths
         grid=(b,),
         in_specs=[
-            pl.BlockSpec((None, 1, hd), row),
+            pl.BlockSpec((None, g, hd), row),
             pl.BlockSpec(memory_space=pl.ANY),    # k pool: stays in HBM
             pl.BlockSpec(memory_space=pl.ANY),    # v pool
         ],
-        out_specs=pl.BlockSpec((None, 1, hd), row),
+        out_specs=pl.BlockSpec((None, g, hd), row),
         scratch_shapes=[
             pltpu.VMEM((2, per_step * block, hd), k_pool.dtype),
             pltpu.VMEM((2, per_step * block, hd), v_pool.dtype),
@@ -435,19 +478,27 @@ def _paged_call(q, k_pool, v_pool, block_tables, lengths, *, scale,
     )
     kernel = functools.partial(_paged_decode_kernel, head_dim=d,
                                block=block, blocks_per_step=per_step,
-                               scale=scale)
+                               scale=scale, group=g)
+
+    def by_kv_head(a):        # [b, h, d] -> [b, g, h_kv * d]
+        if g == 1:
+            return a.reshape(b, 1, hd)
+        return jnp.swapaxes(a.reshape(b, h_kv, g, d), 1, 2).reshape(b, g, hd)
+
     with jax.named_scope("pallas.paged_decode_attention"):
         out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, 1, hd), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((b, g, hd), q.dtype),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
         )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-          q.reshape(b, 1, hd), k_pool.reshape(nb, block, hd),
+          by_kv_head(q), k_pool.reshape(nb, block, hd),
           v_pool.reshape(nb, block, hd))
-    return out.reshape(b, h, d)
+    if g == 1:
+        return out.reshape(b, h, d)
+    return jnp.swapaxes(out.reshape(b, g, h_kv, d), 1, 2).reshape(b, h, d)
 
 
 def select_paged_backend(batch: int, max_blocks: int, *,

@@ -51,6 +51,7 @@ GATES = {
     "bn_bwd": ("fused_bn_bwd", "DL4J_TPU_FUSED_BN_BWD"),
     "attention": ("flash_attention", "DL4J_TPU_FLASH_ATTENTION"),
     "paged_attention": ("paged_attention", "DL4J_TPU_PAGED_ATTENTION"),
+    "ssm_state": ("ssm_state", "DL4J_TPU_SSM_STATE"),
 }
 
 _select_total = telemetry.counter(

@@ -302,12 +302,24 @@ class ServingBatcher(ParallelInference):
             kv_dtype = to_jnp_dtype(
                 "bfloat16" if kv_dtype in ("bf16", "bfloat16")
                 else kv_dtype)
+        decode_buckets = cfg.get("decode_buckets", (4, 8))
+        # a model with recurrent state (state-space layers) names its
+        # per-sequence state arrays; every row of the largest decode
+        # bucket needs a slot of its own beside the scratch slot
+        state = m.state_shapes() if hasattr(m, "state_shapes") else None
+        need = max(decode_buckets) + 1
+        state_slots = int(cfg.get("state_slots", need)) if state else 0
+        if state and state_slots < need:
+            raise ValueError(
+                f"state_slots {state_slots} < largest decode bucket + 1 "
+                f"= {need} (slot 0 is scratch)")
         pool = KVBlockPool(
             c.n_layers,
             int(cfg.get("kv_blocks", 64)),
             int(cfg.get("kv_block_size", 16)),
-            c.n_heads, c.head_dim,
-            dtype=kv_dtype, name=self.name)
+            getattr(c, "n_kv_heads", c.n_heads), c.head_dim,
+            dtype=kv_dtype, name=self.name,
+            state=state, state_slots=state_slots)
         params, view_fn = m.params, None
         if self.mode != "dense":
             from deeplearning4j_tpu.serving.residency import (
@@ -326,7 +338,7 @@ class ServingBatcher(ParallelInference):
         self.engine = DecodeEngine(
             m, params, pool, view_fn=view_fn, name=self.name,
             prompt_buckets=cfg.get("prompt_buckets", (16, 64)),
-            decode_buckets=cfg.get("decode_buckets", (4, 8)),
+            decode_buckets=decode_buckets,
             max_seq_len=cfg.get("max_seq_len"),
             paged=cfg.get("paged"), guard=self.guard,
             rng_seed=int(cfg.get("rng_seed", 0)))

@@ -41,9 +41,43 @@ time. ``generate.decode_step`` carries the counts of the step it
 dispatched: ``live`` rows of ``bucket``, ``pool_live`` of
 ``pool_usable`` blocks, and ``grid_blocks``, the ``bucket x
 max_blocks`` table positions of which ``pool_live`` name live KV.
+
+**The loop runs ahead of the device.** A dispatched step's ids are
+not pulled at once: the next pass builds the following step on the
+rows of the last one dispatched (their positions one further, its
+ids, still on the device, as the tokens) and dispatches it, until
+``RUN_AHEAD`` steps are queued behind the one whose ids the host then
+pulls and emits (``generate.pull`` and ``generate.emit`` are of the
+oldest step in flight, not of the one ``generate.dispatch`` sent).
+The device goes from step to step without waiting for the host's
+turn, and a stall of the host as long as the queued steps (a
+collector's or a hypervisor's pause: 105-125 ms now and then on the
+chip's machines) does not starve it. Rows keep their places from step
+to step; a row whose last token a step in flight brings, or that has
+retired, is a hole (scratch block, scratch slot) in the next, and
+what a step computed for a row that retired meanwhile (EOS seen some
+steps late, a disconnect) is dropped. An admission lands every step
+in flight first (a ``generate.pull`` and ``generate.emit`` each,
+directly under the iteration), so a prefill never runs behind tokens
+that no client has yet, and the step after it is built afresh, its
+rows packed: a freed row is refilled ``RUN_AHEAD`` steps later than
+it could be.
+
+A model with recurrent state (state-space layers; it has
+``state_shapes()``) gets a **state slot** a sequence from the same
+pool: admission takes one before the prefill (a request that finds
+none waits at the head of the queue for a retirement), the commit
+program writes the prefill's last-position state into it, the decode
+step takes the rows' slots beside their block tables (0, the scratch
+slot, for a dead row), and retirement frees it with the blocks. The
+compiled programs take the cache's arrays as one pytree
+(``pool.arrays``), so a K/V-only pool lowers as it always did. The
+spans then carry ``state_live`` of ``state_slots``
+(``generate.decode_step``) and ``state_slot`` (``generate.prefill``).
 """
 from __future__ import annotations
 
+import collections
 import itertools
 import queue as _queue
 import threading
@@ -56,6 +90,13 @@ from deeplearning4j_tpu.common import telemetry
 from deeplearning4j_tpu.common.compilecache import RetraceGuard
 from deeplearning4j_tpu.serving.admission import DeadlineExceeded
 from deeplearning4j_tpu.serving.kvcache import KVBlockPool, PoolExhausted
+
+#: decode steps queued on the device behind the one whose ids the
+#: host waits for: 120 ms of cover at steps of 30 ms, a little more
+#: than the 105-125 ms for which the chip's machines now and then
+#: stop a whole process. A freed row is refilled, and an EOS seen,
+#: this many steps later than it could be.
+RUN_AHEAD = 4
 
 #: terminal reasons a TokenStream closes with
 END_REASONS = ("eos", "max_tokens", "cancelled", "deadline", "kv_pool",
@@ -197,8 +238,8 @@ class _Sequence:
     """Engine-internal live-sequence state."""
 
     __slots__ = ("seq_id", "stream", "next_token", "position",
-                 "generated", "max_tokens", "temperature", "top_k",
-                 "deadline", "t_last", "ctx")
+                 "generated", "flying", "max_tokens", "temperature",
+                 "top_k", "deadline", "t_last", "ctx")
 
     def __init__(self, seq_id, stream, next_token, position,
                  max_tokens, temperature, top_k, deadline, t_last,
@@ -208,12 +249,24 @@ class _Sequence:
         self.next_token = int(next_token)   # fed to the next step
         self.position = int(position)       # its index in the sequence
         self.generated = 1                  # the prefill-sampled token
+        self.flying = 0                     # steps in flight with it
         self.max_tokens = int(max_tokens)
         self.temperature = float(temperature)
         self.top_k = int(top_k)
         self.deadline = deadline
         self.t_last = t_last                # last token emit instant
         self.ctx = ctx                      # request TraceContext
+
+
+class _Step:
+    """A dispatched decode step whose ids are still on the device:
+    the sequence of each row (None: a dead row or a hole), its bucket,
+    the ids, and when it was dispatched."""
+
+    __slots__ = ("rows", "bucket", "ids", "t0")
+
+    def __init__(self, rows, bucket, ids, t0):
+        self.rows, self.bucket, self.ids, self.t0 = rows, bucket, ids, t0
 
 
 class DecodeEngine:
@@ -257,7 +310,12 @@ class DecodeEngine:
         self._paged = paged
         self._seq_ids = itertools.count(1)
         self._pending: "_queue.Queue" = _queue.Queue()
+        #: the head of the queue while it waits for a state slot
+        self._held = None
         self._live: Dict[int, _Sequence] = {}
+        #: the dispatched steps whose ids were not pulled yet
+        self._inflight: "collections.deque[_Step]" = collections.deque()
+        self._t_landed = 0.0
         self._lock = threading.Lock()
         self._worker: Optional[threading.Thread] = None
         #: the worker shutdown() swapped out, until it finishes its
@@ -300,10 +358,18 @@ class DecodeEngine:
             self._jits["prefill"] = jax.jit(fn)
         return self._jits["prefill"]
 
+    def _donate(self, argnum: int) -> tuple:
+        """The cache argument, donated where the model asks for it
+        (``donates_cache``): its programs then update the pool's
+        arrays in place instead of writing a second copy of them."""
+        return (argnum,) if getattr(self.model, "donates_cache",
+                                    False) else ()
+
     def _commit_jit(self):
         import jax
         if "commit" not in self._jits:
-            def fn(kp, vp, k, v, slots):
+            def fn(cache, new, slots, *state_slot):
+                (kp, vp, *state), (k, v, *new_state) = cache, new
                 nl, nb, bs = kp.shape[0], kp.shape[1], kp.shape[2]
                 tail = kp.shape[3:]
                 kf = kp.reshape((nl, nb * bs) + tail)
@@ -312,8 +378,14 @@ class DecodeEngine:
                 # in the pool's own dtype
                 kf = kf.at[:, slots].set(k[:, 0].astype(kp.dtype))
                 vf = vf.at[:, slots].set(v[:, 0].astype(vp.dtype))
-                return (kf.reshape(kp.shape), vf.reshape(vp.shape))
-            self._jits["commit"] = jax.jit(fn)
+                # recurrent state: the prompt's last-token state into
+                # the sequence's slot, every layer at once
+                state = [a.at[:, state_slot[0]].set(n[:, 0].astype(a.dtype))
+                         for a, n in zip(state, new_state)]
+                return (kf.reshape(kp.shape), vf.reshape(vp.shape),
+                        *state)
+            self._jits["commit"] = jax.jit(
+                fn, donate_argnums=self._donate(0))
         return self._jits["commit"]
 
     def _sample_jit(self):
@@ -330,14 +402,15 @@ class DecodeEngine:
         if "decode" not in self._jits:
             paged = self._paged_now()
 
-            def fn(params, kp, vp, tokens, positions, tables, key,
-                   temps, topks):
-                logits, kp, vp = self.model.decode_step(
-                    self._view(params), tokens, positions, kp, vp,
-                    tables, paged=paged)
+            def fn(params, cache, tokens, positions, tables, key,
+                   temps, topks, *state_slots):
+                logits, *cache = self.model.decode_step(
+                    self._view(params), tokens, positions, *cache,
+                    tables, *state_slots, paged=paged)
                 ids = sample_logits(logits, key, temps, topks)
-                return ids, kp, vp
-            self._jits["decode"] = jax.jit(fn)
+                return ids, tuple(cache)
+            self._jits["decode"] = jax.jit(
+                fn, donate_argnums=self._donate(1))
         return self._jits["decode"]
 
     # -- warmup --------------------------------------------------------
@@ -352,21 +425,22 @@ class DecodeEngine:
             tokens = np.zeros((1, t), np.int32)
             length = np.asarray([1], np.int32)
             self.guard.record(tokens, length)
-            last, k, v = self._prefill_jit()(self.params, tokens,
+            last, *new = self._prefill_jit()(self.params, tokens,
                                              length)
             slots = np.zeros((t,), np.int32)
-            self.guard.record(k, slots)
-            kp, vp = self._commit_jit()(self.pool.k, self.pool.v, k, v,
-                                        slots)
+            self.guard.record(new[0], slots)
+            cache = self._commit_jit()(
+                self.pool.arrays, tuple(new), slots,
+                *self._state_arg(np.int32(0)))
             # the first-token sampler compiles once here (its [1,
             # vocab] signature never varies with the prompt bucket)
             first = self._sample_jit()(
                 last, jax.random.fold_in(self._rng, 0),
                 np.zeros((1,), np.float32), np.zeros((1,), np.int32))
-            jax.block_until_ready((last, kp, vp, first))
+            jax.block_until_ready((last, cache, first))
             # scratch-block writes only: pool arrays unchanged where
             # it matters, but keep the functional update discipline
-            self.pool.update_arrays(kp, vp)
+            self.pool.update_arrays(*cache)
         for b in self.decode_buckets:
             tokens = np.zeros((b,), np.int32)
             positions = np.zeros((b,), np.int32)
@@ -376,14 +450,26 @@ class DecodeEngine:
             self.guard.record(tokens, positions, tables, temps, topks)
             import jax as _jax
             key = _jax.random.fold_in(self._rng, 0)
-            ids, kp, vp = self._decode_jit()(
-                self.params, self.pool.k, self.pool.v, tokens,
-                positions, tables, key, temps, topks)
+            rest = (positions, tables, key, temps, topks,
+                    *self._state_arg(np.zeros((b,), np.int32)))
+            ids, cache = self._decode_jit()(
+                self.params, self.pool.arrays, tokens, *rest)
+            self.pool.update_arrays(*cache)
+            # and with its tokens as every step but the first after an
+            # admission gets them: the ids of the step before, still
+            # on the device
+            ids, cache = self._decode_jit()(
+                self.params, self.pool.arrays, ids, *rest)
+            self.pool.update_arrays(*cache)
             jax.block_until_ready(ids)
-            self.pool.update_arrays(kp, vp)
         self._warmed = True
         self.warm_signatures = self.guard.n_signatures
         return time.perf_counter() - t0
+
+    def _state_arg(self, slots) -> tuple:
+        """The state-slot argument of the commit and decode programs:
+        there only for a pool that holds recurrent state."""
+        return (slots,) if self.pool.state else ()
 
     def retraces_since_warmup(self) -> int:
         """Distinct signatures compiled after warmup — must stay 0 in
@@ -476,7 +562,9 @@ class DecodeEngine:
             # look re-sets the event, so the wait below returns
             # immediately instead of losing the wake-up.
             self._work.clear()
-            if self._pending.empty() and not self._live:
+            if self._pending.empty() and not self._live \
+                    and self._held is None:
+                self._inflight.clear()  # nothing but holes is in them
                 # Idle — and only exit on shutdown/supersession while
                 # idle: every pending request was admitted and every
                 # admitted sequence retired, so no stream is stranded.
@@ -489,7 +577,11 @@ class DecodeEngine:
             self._iter += 1
             with telemetry.span("generate.iteration", model=self.name,
                                 iter=self._iter):
-                if not self._pending.empty():
+                if not self._pending.empty() or self._held is not None:
+                    if self._held is None or self.pool.free_slots:
+                        # a prefill is coming: it does not run behind
+                        # tokens that no client has yet
+                        self._land()
                     with telemetry.span("generate.admit") as args:
                         args["admitted"] = self._admit_pending()
                 self._decode_iteration()
@@ -500,21 +592,29 @@ class DecodeEngine:
         left the queue."""
         admitted = 0
         while True:
-            try:
-                item = self._pending.get_nowait()
-            except _queue.Empty:
-                return admitted
-            admitted += 1
+            item, self._held = self._held, None
+            if item is None:
+                try:
+                    item = self._pending.get_nowait()
+                except _queue.Empty:
+                    return admitted
             (seq_id, prompt, max_tokens, temperature, top_k, deadline,
              stream, t_submit, ctx) = item
             if stream.cancelled or (deadline is not None
                                     and time.monotonic() >= deadline):
+                admitted += 1
                 reason = "cancelled" if stream.cancelled else "deadline"
                 self.pool.free(seq_id)
                 if ctx is not None:
                     ctx.phase_at("queue", t_submit, time.perf_counter())
                 self._finish(stream, reason)
                 continue
+            if self.pool.state and self.pool.alloc_slot(seq_id) is None:
+                # every state slot is live: the head of the queue
+                # waits for a retirement, like one that finds no row
+                self._held = item
+                return admitted
+            admitted += 1
             try:
                 self._prefill_one(seq_id, prompt, max_tokens,
                                   temperature, top_k, deadline, stream,
@@ -539,6 +639,8 @@ class DecodeEngine:
             ctx.phase_at("queue", t_submit, t_prefill)
             whose["trace"] = ctx.trace_id
         t = self._prompt_bucket(prompt.size)
+        if self.pool.state:
+            whose["state_slot"] = self.pool.slot(seq_id)
         with telemetry.span(
                 "generate.prefill", model=self.name,
                 tokens=int(prompt.size), bucket=t,
@@ -548,7 +650,7 @@ class DecodeEngine:
             tokens[0, :prompt.size] = prompt
             length = np.asarray([prompt.size], np.int32)
             self._record(tokens, length)
-            last, k, v = self._prefill_jit()(self.params, tokens,
+            last, *new = self._prefill_jit()(self.params, tokens,
                                              length)
             # scatter the prompt's K/V into its pool blocks (padded
             # positions land in scratch block 0)
@@ -561,10 +663,10 @@ class DecodeEngine:
                                len(table) - 1)]
                 * self.pool.block_size + idx % self.pool.block_size,
                 0).astype(np.int32)
-            self._record(k, slots)
-            kp, vp = self._commit_jit()(self.pool.k, self.pool.v, k, v,
-                                        slots)
-            self.pool.update_arrays(kp, vp)
+            self._record(new[0], slots)
+            self.pool.update_arrays(*self._commit_jit()(
+                self.pool.arrays, tuple(new), slots,
+                *self._state_arg(np.int32(self.pool.slot(seq_id)))))
             self._step += 1
             key = jax.random.fold_in(self._rng, self._step)
             first = int(np.asarray(self._sample_jit()(
@@ -630,36 +732,100 @@ class DecodeEngine:
 
     def _decode_iteration(self) -> None:
         """ONE fused step over all live sequences (the iteration of
-        iteration-level scheduling)."""
+        iteration-level scheduling), dispatched behind the steps in
+        flight; the oldest of those is pulled and emitted once
+        ``RUN_AHEAD`` are queued behind it."""
+        flying = self._inflight
         if not self._live:
+            flying.clear()
             return
-        with telemetry.span("generate.build"):
-            step = self._build_step()
+        step = None
+        if flying:
+            with telemetry.span("generate.build"):
+                step = self._build_step(flying[-1])
+            if step is None:
+                # the rows of the steps in flight will not do for
+                # another (a smaller bucket, a sequence without a
+                # row): land them, then pack the rows afresh
+                self._land()
         if step is None:
-            return
-        seqs, b, inputs = step
+            if not self._live:
+                return
+            with telemetry.span("generate.build"):
+                step = self._build_step()
+            if step is None:
+                return
+        rows, b, inputs = step
         pool = self.pool
-        t0 = time.perf_counter()
+        counts = {}
+        if pool.state:
+            counts = {"state_live": pool.usable_slots - pool.free_slots,
+                      "state_slots": pool.usable_slots}
+        got = None
         with telemetry.span(
-                "generate.decode_step", model=self.name, live=len(seqs),
+                "generate.decode_step", model=self.name,
+                live=sum(seq is not None for seq in rows),
                 bucket=b, grid_blocks=b * self.max_blocks,
                 pool_usable=pool.usable_blocks,
-                pool_live=pool.usable_blocks - pool.free_blocks):
+                pool_live=pool.usable_blocks - pool.free_blocks,
+                **counts):
+            t0 = time.perf_counter()
             with telemetry.span("generate.dispatch",
                                 program="decode_step"):
-                ids, kp, vp = self._decode_jit()(
-                    self.params, pool.k, pool.v, *inputs)
-            with telemetry.span("generate.pull"):
-                ids = np.asarray(ids)
-        step_s = time.perf_counter() - t0
-        with telemetry.span("generate.emit", tokens=len(seqs)) as args:
-            pool.update_arrays(kp, vp)
-            args["retired"] = self._emit(seqs, b, ids, step_s)
+                ids, cache = self._decode_jit()(
+                    self.params, pool.arrays, *inputs)
+                # a donated cache is gone once dispatched: the pool
+                # holds the step's own arrays from here on
+                pool.update_arrays(*cache)
+            for seq in rows:
+                if seq is not None:
+                    seq.flying += 1
+            flying.append(_Step(rows, b, ids, t0))
+            if len(flying) > RUN_AHEAD:
+                got = self._pull(flying.popleft())
+        if got is not None:
+            self._emit_step(*got)
 
-    def _build_step(self):
+    def _pull(self, step: _Step):
+        """A dispatched step, its ids on the host, and the seconds the
+        device had for it (since it was dispatched or the step before
+        it landed, whichever came later)."""
+        with telemetry.span("generate.pull"):
+            ids = np.asarray(step.ids)
+        now = time.perf_counter()
+        step_s = now - max(step.t0, self._t_landed)
+        self._t_landed = now
+        return step, ids, step_s
+
+    def _still_live(self, rows) -> list:
+        """``rows`` with None for every sequence that has retired."""
+        return [seq if seq is not None
+                and self._live.get(seq.seq_id) is seq else None
+                for seq in rows]
+
+    def _emit_step(self, step: _Step, ids, step_s) -> None:
+        # what the step computed for a row that retired meanwhile
+        # (EOS seen some steps late, a disconnect) is dropped
+        rows = self._still_live(step.rows)
+        with telemetry.span(
+                "generate.emit",
+                tokens=sum(seq is not None for seq in rows)) as args:
+            args["retired"] = self._emit(rows, step.bucket, ids, step_s)
+
+    def _land(self) -> None:
+        """Pull and emit every step in flight, oldest first, without
+        dispatching another."""
+        while self._inflight:
+            self._emit_step(*self._pull(self._inflight.popleft()))
+
+    def _build_step(self, prev: Optional[_Step] = None):
         """Everything the host does before a step can be dispatched:
         pre-step retirement, one more token slot for every row, the
-        padded inputs and the step's key. None when no row is left."""
+        padded inputs and the step's key. With ``prev``, the last
+        step dispatched and still in flight, the rows keep their
+        places, stand as many positions further as steps in flight
+        carry them, and take its ids as their tokens. None when no
+        row is left, or when the rows of ``prev`` will not do."""
         import jax
         now = time.monotonic()
         # pre-step retirement: cancelled / deadline sequences leave
@@ -669,45 +835,75 @@ class DecodeEngine:
                 self._retire(seq, "cancelled")
             elif seq.deadline is not None and now >= seq.deadline:
                 self._retire(seq, "deadline")
-        # grow every sequence by one token slot; a pool with no free
-        # block sheds THAT sequence mid-batch, the rest keep decoding
-        for seq in list(self._live.values()):
-            try:
-                self.pool.extend(seq.seq_id, 1)
-            except PoolExhausted as e:
-                self._retire(seq, "kv_pool", e)
-        if not self._live:
+        if prev is None:
+            # grow every sequence by one token slot; a pool with no
+            # free block sheds THAT sequence mid-batch, the rest keep
+            # decoding
+            for seq in list(self._live.values()):
+                try:
+                    self.pool.extend(seq.seq_id, 1)
+                except PoolExhausted as e:
+                    self._retire(seq, "kv_pool", e)
+            rows = list(self._live.values())[:self.decode_buckets[-1]]
+            b = self._decode_bucket(len(rows))
+        else:
+            # a row whose last token a step in flight brings, or
+            # that has retired, is a hole in this one
+            rows = [seq if seq is not None and seq.generated
+                    + seq.flying < seq.max_tokens else None
+                    for seq in self._still_live(prev.rows)]
+            n = sum(seq is not None for seq in rows)
+            b = prev.bucket
+            if n != sum(seq.generated + seq.flying < seq.max_tokens
+                        for seq in self._live.values()) \
+                    or self._decode_bucket(n) != b:
+                return None             # a sequence without a row
+            for i, seq in enumerate(rows):
+                if seq is not None:
+                    try:
+                        self.pool.extend(seq.seq_id, 1)
+                    except PoolExhausted as e:
+                        self._retire(seq, "kv_pool", e)
+                        rows[i] = None
+        if not any(seq is not None for seq in rows):
             return None
-        seqs = list(self._live.values())[:self.decode_buckets[-1]]
-        b = self._decode_bucket(len(seqs))
         tokens = np.zeros((b,), np.int32)
         positions = np.zeros((b,), np.int32)
         tables = np.zeros((b, self.max_blocks), np.int32)
         temps = np.zeros((b,), np.float32)
         topks = np.zeros((b,), np.int32)
-        for i, seq in enumerate(seqs):
+        state_slots = np.zeros((b,), np.int32)      # dead rows: scratch
+        for i, seq in enumerate(rows):
+            if seq is None:
+                continue
             tokens[i] = seq.next_token
-            positions[i] = seq.position
+            positions[i] = seq.position + seq.flying
             tables[i] = self.pool.padded_table(seq.seq_id,
                                                self.max_blocks)
             temps[i] = seq.temperature
             topks[i] = seq.top_k
+            state_slots[i] = self.pool.slot(seq.seq_id)
         self._record(tokens, positions, tables, temps, topks)
         self._step += 1
         key = jax.random.fold_in(self._rng, self._step)
-        return seqs, b, (tokens, positions, tables, key, temps, topks)
+        return rows, b, (tokens if prev is None else prev.ids,
+                         positions, tables, key, temps, topks,
+                         *self._state_arg(state_slots))
 
-    def _emit(self, seqs, b, ids, step_s) -> int:
+    def _emit(self, rows, b, ids, step_s) -> int:
         """Hand every row its token: meters, the stream's queue, the
         request's ``inter_token`` instant, and retirement on EOS or
         ``max_tokens``. Returns how many rows retired."""
         step_hist, occupancy, tokens, gap_hist = self._meters()
         step_hist.observe(step_s)
-        occupancy.observe(len(seqs) / max(1, b))
+        occupancy.observe(sum(seq is not None for seq in rows)
+                          / max(1, b))
         now = time.perf_counter()
         eos = self.model.conf.eos_id
         retired = 0
-        for i, seq in enumerate(seqs):
+        for i, seq in enumerate(rows):
+            if seq is None:
+                continue
             tok = int(ids[i])
             seq.stream._put(tok)
             tokens.inc()
@@ -720,6 +916,7 @@ class DecodeEngine:
             seq.position += 1
             seq.next_token = tok
             seq.generated += 1
+            seq.flying -= 1
             if tok == eos or seq.generated >= seq.max_tokens:
                 self._retire(seq, "eos" if tok == eos else "max_tokens")
                 retired += 1

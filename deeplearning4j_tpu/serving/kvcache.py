@@ -28,6 +28,18 @@ that allocator for the TPU stack:
   :class:`PoolExhausted` (a :class:`ShedError` — HTTP 429 with a
   drain-rate-measured ``Retry-After`` upstream).
 
+A model whose blocks also carry **recurrent state** (a state-space
+mixer beside attention) gets a second kind of residency from the same
+manager: ``state={name: (shape, dtype)}`` adds one device array a kind
+``[n_layers, state_slots, *shape]``, a **slot** a live sequence. A
+slot's content is the running summary of the whole sequence: it cannot
+be re-gathered from blocks, so it is written at admission (the
+prefill's state at the prompt's last token) and released at
+retirement. **Slot 0 is scratch** as block 0 is (dead decode rows
+write there). ``n_heads`` is the KV head count: a grouped-query model
+passes fewer KV heads than it has query heads. Gauges
+``dl4j_state_pool_slots{state=free|live}`` / ``dl4j_state_pool_bytes``.
+
 The pool's device bytes are a first-class **resident class** in
 ``diagnostics.memory_report`` (next to params / updater state), looked
 up lazily via ``sys.modules`` so diagnostics keeps zero import edges
@@ -75,6 +87,21 @@ def _bytes_gauge() -> telemetry.Gauge:
         "occupancy moves, allocation does not)")
 
 
+def _slots_gauge() -> telemetry.Gauge:
+    return telemetry.gauge(
+        "dl4j_state_pool_slots",
+        "recurrent-state slots by state (free | live) per pool — one "
+        "slot a live sequence of a model with state-space layers; "
+        "slot 0 is reserved scratch and counted in neither state")
+
+
+def _state_bytes_gauge() -> telemetry.Gauge:
+    return telemetry.gauge(
+        "dl4j_state_pool_bytes",
+        "preallocated device bytes of a pool's recurrent-state arrays "
+        "(all kinds, all slots; constant for the pool's lifetime)")
+
+
 def _shed_counter() -> telemetry.Counter:
     return telemetry.counter(
         "dl4j_kv_pool_shed_total",
@@ -99,9 +126,13 @@ class KVBlockPool:
     def __init__(self, n_layers: int, num_blocks: int,
                  block_size: int, n_heads: int, head_dim: int, *,
                  dtype=np.float32, name: str = "model",
-                 device_arrays: bool = True):
+                 device_arrays: bool = True,
+                 state: Optional[dict] = None, state_slots: int = 0):
         if num_blocks < 2:
             raise ValueError("num_blocks must be >= 2 (block 0 is "
+                             "reserved scratch)")
+        if state and state_slots < 2:
+            raise ValueError("state_slots must be >= 2 (slot 0 is "
                              "reserved scratch)")
         self.n_layers = int(n_layers)
         self.num_blocks = int(num_blocks)
@@ -118,7 +149,18 @@ class KVBlockPool:
         else:               # allocator-only pool (tests, sizing math)
             self.k = np.zeros(shape, dtype=dtype)
             self.v = np.zeros(shape, dtype=dtype)
+        #: recurrent-state arrays by kind, [n_layers, slots, *shape]
+        self.state_slots = int(state_slots) if state else 0
+        xp = jnp if device_arrays else np
+        self.state = {
+            kind: xp.zeros((self.n_layers, self.state_slots)
+                           + tuple(shp), dtype=dt)
+            for kind, (shp, dt) in (state or {}).items()}
         self._lock = threading.RLock()
+        #: free state slots, LIFO (slot 0 reserved)
+        self._free_slots: List[int] = list(
+            range(self.state_slots - 1, 0, -1))
+        self._slots: Dict[object, int] = {}
         #: free block ids, LIFO (block 0 reserved — see module doc)
         self._free: List[int] = list(range(self.num_blocks - 1, 0, -1))
         self._tables: Dict[object, List[int]] = {}
@@ -127,12 +169,27 @@ class KVBlockPool:
         if telemetry.enabled():
             _bytes_gauge().set(self.pool_bytes, pool=self.name)
             self._export_occupancy()
+            if self.state:
+                _state_bytes_gauge().set(self.state_bytes, pool=self.name)
+                self._export_slots()
 
     # -- sizing ---------------------------------------------------------
     @property
     def pool_bytes(self) -> int:
         """Preallocated device bytes (k + v) — the resident class."""
         return int(self.k.nbytes) + int(self.v.nbytes)
+
+    @property
+    def state_bytes(self) -> int:
+        """Preallocated device bytes of the recurrent-state arrays."""
+        return sum(int(a.nbytes) for a in self.state.values())
+
+    @property
+    def arrays(self) -> tuple:
+        """Every device array of the cache as one pytree, in the order
+        the model's ``decode_step`` takes and returns them: ``(k, v)``,
+        then the state arrays in the order of their kinds."""
+        return (self.k, self.v) + tuple(self.state.values())
 
     @property
     def usable_blocks(self) -> int:
@@ -172,6 +229,42 @@ class KVBlockPool:
         g.set(len(self._free), pool=self.name, state="free")
         g.set(self.usable_blocks - len(self._free),
               pool=self.name, state="live")
+
+    # -- state slots ----------------------------------------------------
+    @property
+    def usable_slots(self) -> int:
+        return max(0, self.state_slots - 1)     # minus the scratch slot
+
+    @property
+    def free_slots(self) -> int:
+        with self._lock:
+            return len(self._free_slots)
+
+    def _export_slots(self) -> None:
+        if not telemetry.enabled():
+            return
+        g = _slots_gauge()
+        g.set(len(self._free_slots), pool=self.name, state="free")
+        g.set(len(self._slots), pool=self.name, state="live")
+
+    def alloc_slot(self, seq_id) -> Optional[int]:
+        """A state slot for a sequence that is being admitted, or None
+        when every slot is live: the caller keeps the request queued
+        (a slot frees at the next retirement), it is not shed."""
+        with self._lock:
+            if seq_id in self._slots:
+                raise ValueError(f"sequence {seq_id!r} already has a "
+                                 f"state slot")
+            if not self._free_slots:
+                return None
+            self._slots[seq_id] = self._free_slots.pop()
+            self._export_slots()
+            return self._slots[seq_id]
+
+    def slot(self, seq_id) -> int:
+        """The sequence's state slot (0, the scratch slot, if none)."""
+        with self._lock:
+            return self._slots.get(seq_id, 0)
 
     # -- lifecycle ------------------------------------------------------
     def alloc(self, seq_id, n_tokens: int) -> List[int]:
@@ -214,10 +307,15 @@ class KVBlockPool:
             return list(self._tables[seq_id])
 
     def free(self, seq_id) -> int:
-        """Return a sequence's blocks to the pool (EOS / max_tokens /
-        client disconnect — all mid-batch paths). Idempotent; returns
-        the number of blocks released."""
+        """Return a sequence's blocks, and its state slot if it holds
+        one, to the pool (EOS / max_tokens / client disconnect — all
+        mid-batch paths). Idempotent; returns the number of blocks
+        released."""
         with self._lock:
+            slot = self._slots.pop(seq_id, None)
+            if slot is not None:
+                self._free_slots.append(slot)
+                self._export_slots()
             blocks = self._tables.pop(seq_id, None)
             self._lengths.pop(seq_id, None)
             if not blocks:
@@ -244,14 +342,27 @@ class KVBlockPool:
                              f"blocks > table width {max_blocks}")
         return np.asarray(t + [0] * (max_blocks - len(t)), np.int32)
 
-    def update_arrays(self, k, v) -> None:
+    def update_arrays(self, k, v, *state) -> None:
         """Store the decode step's updated pool arrays (functional
-        update: jit returns new values for the same buffers)."""
+        update: jit returns new values for the same buffers), in the
+        order of :attr:`arrays`."""
         self.k, self.v = k, v
+        if state:
+            self.state = dict(zip(self.state, state))
 
     def report(self) -> dict:
         """The memory_report join row for this pool."""
+        state = {}
+        if self.state:
+            state = {"state": {
+                "bytes": self.state_bytes,
+                "slots": {"free": self.free_slots,
+                          "live": self.usable_slots - self.free_slots,
+                          "reserved": 1, "total": self.state_slots},
+                "layout": {k: list(a.shape)
+                           for k, a in self.state.items()}}}
         return {
+            **state,
             "pool": self.name,
             "bytes": self.pool_bytes,
             "blocks": {"free": self.free_blocks,

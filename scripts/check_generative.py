@@ -15,11 +15,16 @@ accelerator needed):
    decode batch mid-flight; the engine's RetraceGuard must record
    ZERO new signatures after warmup (continuous batching never
    recompiles in steady state).
-3. **Pool accounting reconciles** — every block allocated during the
-   churn must be back on the free list afterwards, and
+3. **Pool accounting reconciles** — every block (and every
+   recurrent-state slot) allocated during the churn must be back on
+   the free list afterwards, and
    ``diagnostics.memory_report()`` must carry the pool as its own
    resident class with bytes equal to the ``dl4j_kv_pool_bytes``
    gauge.
+
+The gate runs once for each model class with the serving contract:
+``DecoderLM`` and ``FalconH1LM`` (grouped-query attention beside a
+state-space mixer: KV blocks and a state slot a sequence).
 
 Usage: JAX_PLATFORMS=cpu python scripts/check_generative.py
 Exit 0 = gate holds, 1 = a clause failed.
@@ -38,20 +43,57 @@ os.environ["DL4J_TPU_PAGED_ATTENTION"] = "1"
 import numpy as np  # noqa: E402
 
 
-def main() -> int:
-    from deeplearning4j_tpu.common import diagnostics
+def _decoder():
     from deeplearning4j_tpu.models.decoder import (DecoderConfig,
                                                    DecoderLM)
-    from deeplearning4j_tpu.serving.generative import DecodeEngine
-    from deeplearning4j_tpu.serving.kvcache import (KVBlockPool,
-                                                    _bytes_gauge)
-
-    failures = []
+    from deeplearning4j_tpu.serving.kvcache import KVBlockPool
     conf = DecoderConfig.tiny()
     model = DecoderLM(conf)
+    return model, KVBlockPool(conf.n_layers, 64, 8, conf.n_heads,
+                              conf.head_dim, name="gate")
+
+
+def _falcon_h1():
+    """The hybrid model class: fewer KV heads than query heads, and a
+    recurrent-state slot a sequence beside its blocks."""
+    from deeplearning4j_tpu.models.falcon_h1 import (FalconH1Config,
+                                                     FalconH1LM)
+    from deeplearning4j_tpu.serving.kvcache import KVBlockPool
+    conf = FalconH1Config()
+    model = FalconH1LM(conf)
+    return model, KVBlockPool(conf.n_layers, 64, 8, conf.n_kv_heads,
+                              conf.head_dim, name="gate",
+                              state=model.state_shapes(), state_slots=9)
+
+
+def main() -> int:
+    import gc
+    failures = []
+    for build in (_decoder, _falcon_h1):
+        model, pool = build()
+        label = type(model).__name__
+        print(f"== {label}")
+        failures += [f"{label}: {f}" for f in gate(model, pool)]
+        del model, pool
+        gc.collect()            # the next pool reconciles alone
+    if failures:
+        for f in failures:
+            print(f"FAIL: {f}")
+        return 1
+    print("OK: paged decode is token-equal to the dense reference, "
+          "churn never retraced, and the pool reconciles")
+    return 0
+
+
+def gate(model, pool) -> list:
+    """The three clauses for one model class; returns the failures."""
+    from deeplearning4j_tpu.common import diagnostics
+    from deeplearning4j_tpu.serving.generative import DecodeEngine
+    from deeplearning4j_tpu.serving.kvcache import _bytes_gauge
+
+    failures = []
+    conf = model.conf
     params = model.init()
-    pool = KVBlockPool(conf.n_layers, 64, 8, conf.n_heads,
-                       conf.head_dim, name="gate")
     eng = DecodeEngine(model, params, pool, name="gate",
                        prompt_buckets=(16,), decode_buckets=(4, 8),
                        max_seq_len=64, paged=True)
@@ -106,6 +148,10 @@ def main() -> int:
         failures.append(
             f"pool leak after churn: {pool.live_blocks} blocks / "
             f"{pool.live_sequences} sequences still live")
+    if pool.free_slots != pool.usable_slots:
+        failures.append(
+            f"state-slot leak after churn: {pool.free_slots} of "
+            f"{pool.usable_slots} slots free")
     report = diagnostics.memory_report()
     pools = report.get("kv_pools", [])
     if not pools:
@@ -121,14 +167,7 @@ def main() -> int:
     print(f"clause 3: pool fully freed, {report['kv_pool_bytes']} "
           f"bytes reconciled with the gauge")
     eng.shutdown()
-
-    if failures:
-        for f in failures:
-            print(f"FAIL: {f}")
-        return 1
-    print("OK: paged decode is token-equal to the dense reference, "
-          "churn never retraced, and the pool reconciles")
-    return 0
+    return failures
 
 
 if __name__ == "__main__":

@@ -84,6 +84,15 @@ class TestPhasesTiny:
             prompt_bucket=8, paged=True)
         assert r["ok"] and all(1 <= n <= 6 for n in r["tokens"])
 
+    def test_hybrid(self):
+        r = chip_smoke.phase_hybrid(
+            dict(chip_smoke.H1_SMALL, vocab_size=96, hidden_size=40,
+                 head_dim=8, intermediate_size=64, mamba_d_ssm=32,
+                 mamba_d_head=8, mamba_d_state=16, init_std=0.3),
+            gqa=(3, 10, 2, 16, 12, 8, 3), state=(2, 4, 4, 8, 16, 2, 3),
+            prompt_len=11, prompt_bucket=16, steps=4, block=8)
+        assert r["ok"] and r["model"] < 2e-2
+
     def test_kernels(self):
         r = chip_smoke.phase_kernels(
             epilogue_rows=256, epilogue_k=16, epilogue_n=128,

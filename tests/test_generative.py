@@ -295,8 +295,10 @@ class TestEngineSpans:
                            == "generate.iteration"),
                           key=lambda e: e["ts"])
             assert kids, i
+            # a pull directly under the iteration lands the step in
+            # flight before an admission, or when its rows will not do
             assert {e["name"] for e in kids} <= {
-                "generate.admit", "generate.build",
+                "generate.admit", "generate.build", "generate.pull",
                 "generate.decode_step", "generate.emit"}
             edge = it["ts"]
             for k in kids:                  # inside, in order, disjoint
@@ -308,13 +310,25 @@ class TestEngineSpans:
                 inner = sorted((e for e in mine if e["args"]["parent"]
                                 == "generate.decode_step"),
                                key=lambda e: e["ts"])
-                assert [e["name"] for e in inner] == [
-                    "generate.dispatch", "generate.pull"]
+                # the pull is of the step before: a step dispatched
+                # with nothing in flight has none
+                assert [e["name"] for e in inner] in (
+                    ["generate.dispatch", "generate.pull"],
+                    ["generate.dispatch"])
                 assert inner[0]["args"]["program"] == "decode_step"
                 assert step["ts"] <= inner[0]["ts"]
-                assert inner[0]["ts"] + inner[0]["dur"] <= inner[1]["ts"]
-                assert inner[1]["ts"] + inner[1]["dur"] \
-                    <= step["ts"] + step["dur"]
+                edge_in = inner[0]["ts"] + inner[0]["dur"]
+                for e in inner[1:]:
+                    assert edge_in <= e["ts"]
+                    edge_in = e["ts"] + e["dur"]
+                assert edge_in <= step["ts"] + step["dur"]
+        steps = [e for e in ev if e["name"] == "generate.decode_step"]
+        pulls = [e for e in ev if e["name"] == "generate.pull"]
+        # every step is pulled once, some of them after later ones
+        # were dispatched
+        assert len(pulls) == len(steps)
+        assert any(e["args"]["parent"] == "generate.decode_step"
+                   for e in pulls)
         # every span of the family belongs to some iteration
         assert all(e["args"].get("iter") for e in ev)
 
@@ -441,6 +455,174 @@ class TestEngineSpans:
         # span is not the clock's: the typical span must agree
         assert np.percentile(off, 90) < 100e-6, np.percentile(
             off, [50, 90, 100])
+
+
+class TestStepInFlight:
+    """Dispatched steps stay in flight: the next is built on the rows
+    of the last and dispatched before the oldest is pulled, an
+    admission lands them first, and what they computed for a row that
+    retired is dropped."""
+
+    @pytest.fixture(autouse=True)
+    def _clean_ring(self):
+        from deeplearning4j_tpu.common.telemetry import MetricsRegistry
+        MetricsRegistry._reset_for_tests()
+        yield
+        MetricsRegistry._reset_for_tests()
+
+    @staticmethod
+    def _alone(model, eng, prompt, n):
+        return list(model.reference_decode(
+            eng.params, np.asarray(prompt), n,
+            eos_id=model.conf.eos_id))
+
+    def test_the_next_step_is_dispatched_before_the_last_is_pulled(self):
+        model, pool, eng = _engine()
+        got = list(eng.submit(np.array([5, 9, 2, 7]), 12))
+        eng.shutdown()
+        assert got == self._alone(model, eng, [5, 9, 2, 7], 12)
+        ev = _spans()
+        steps = sorted((e for e in ev
+                        if e["name"] == "generate.decode_step"),
+                       key=lambda e: e["ts"])
+        assert len(steps) == 11             # the first token: prefill
+        inner = [sorted((e for e in ev if e["args"].get("parent")
+                         == "generate.decode_step"
+                         and e["args"]["iter"] == s["args"]["iter"]),
+                        key=lambda e: e["ts"]) for s in steps]
+        from deeplearning4j_tpu.serving.generative import RUN_AHEAD
+        for alone in inner[:RUN_AHEAD]:
+            assert [e["name"] for e in alone] == ["generate.dispatch"]
+        for pair in inner[RUN_AHEAD:]:
+            assert [e["name"] for e in pair] == [
+                "generate.dispatch", "generate.pull"]
+        # the last steps land under the iteration that finds no row
+        # left for another
+        last = [e for e in ev if e["name"] == "generate.pull"
+                and e["args"].get("parent") == "generate.iteration"]
+        assert len(last) == RUN_AHEAD
+        assert all(e["ts"] > steps[-1]["ts"] for e in last)
+
+    @pytest.mark.parametrize("lengths", [(3, 9, 6), (9, 3, 6),
+                                         (2, 2, 12), (12, 5, 5)])
+    def test_rows_keep_their_places_and_leave_holes(self, lengths):
+        model, pool, eng = _engine(decode_buckets=(4,))
+        prompts = [[5, 9, 2, 7], [8, 3], [4, 4, 1]]
+        streams = [eng.submit(np.array(p), n)
+                   for p, n in zip(prompts, lengths)]
+        got = [list(s) for s in streams]
+        eng.shutdown()
+        for p, n, g in zip(prompts, lengths, got):
+            assert g == self._alone(model, eng, p, n)
+        assert pool.live_blocks == 0
+        assert eng.retraces_since_warmup() == 0
+        steps = sorted((e for e in _spans()
+                        if e["name"] == "generate.decode_step"),
+                       key=lambda e: e["ts"])
+        # a step a row needs, and some more where its last token was
+        # an EOS, which the host sees that many steps late
+        from deeplearning4j_tpu.serving.generative import RUN_AHEAD
+        longest = max(len(g) for g in got)
+        assert longest - 1 <= len(steps) <= longest - 1 + RUN_AHEAD
+        assert {a["args"]["bucket"] for a in steps} == {4}
+        if all(len(g) == n for g, n in zip(got, lengths)):
+            # a row that ends on max_tokens leaves a hole and the loop
+            # runs on: nothing lands but at the very end
+            landed = [e for e in _spans() if e["name"] == "generate.pull"
+                      and e["args"].get("parent") == "generate.iteration"]
+            assert len(landed) == min(RUN_AHEAD, len(steps))
+            assert all(e["ts"] > steps[-1]["ts"] for e in landed)
+        lives = [a["args"]["live"] for a in steps]
+        assert lives == sorted(lives, reverse=True)
+        assert lives[0] == sum(n > 1 for n in lengths)
+
+    @pytest.mark.parametrize("buckets", [(2,), (2, 4)])
+    def test_more_sequences_than_rows_or_a_smaller_bucket(self, buckets):
+        """Three sequences on two rows: the third steps when a row is
+        free; and with two buckets the rows are packed into the
+        smaller one once they fit. Either way the step in flight is
+        landed and the rows are built afresh."""
+        model, pool, eng = _engine(decode_buckets=buckets)
+        prompts, lengths = [[5, 9, 2, 7], [8, 3], [4, 4, 1]], (4, 9, 7)
+        streams = [eng.submit(np.array(p), n)
+                   for p, n in zip(prompts, lengths)]
+        got = [list(s) for s in streams]
+        eng.shutdown()
+        for p, n, g in zip(prompts, lengths, got):
+            assert g == self._alone(model, eng, p, n)
+        assert pool.live_blocks == 0
+        assert eng.retraces_since_warmup() == 0
+        used = {e["args"]["bucket"] for e in _spans()
+                if e["name"] == "generate.decode_step"}
+        assert used == set(buckets)
+
+    def test_an_admission_lands_the_steps_in_flight_first(self):
+        model, pool, eng = _engine(decode_buckets=(4,))
+        s1 = eng.submit(np.array([5, 9, 2, 7]), 40)
+        assert s1.next(timeout=30) is not None
+        assert s1.next(timeout=30) is not None
+        s2 = eng.submit(np.array([8, 3]), 6)
+        t2 = list(s2)
+        t1 = list(s1)
+        eng.shutdown()
+        assert t2 == self._alone(model, eng, [8, 3], 6)
+        assert len(t1) == 38
+        ev = _spans()
+        admits = [e for e in ev if e["name"] == "generate.admit"
+                  and e["args"]["admitted"]]
+        assert len(admits) == 2
+        second = admits[1]
+        before = [e for e in ev
+                  if e["args"]["iter"] == second["args"]["iter"]
+                  and e["args"].get("parent") == "generate.iteration"
+                  and e["ts"] < second["ts"]]
+        from deeplearning4j_tpu.serving.generative import RUN_AHEAD
+        names = [e["name"] for e in sorted(before,
+                                           key=lambda e: e["ts"])]
+        assert 1 <= len(names) // 2 <= RUN_AHEAD
+        assert names == ["generate.pull", "generate.emit"] \
+            * (len(names) // 2)
+
+    @pytest.mark.parametrize("how", ["eos", "cancel", "deadline"])
+    def test_what_a_step_computed_for_a_retired_row_is_dropped(
+            self, how):
+        conf = DecoderConfig.tiny()
+        probe = DecoderLM(conf)
+        ref = list(probe.reference_decode(
+            probe.init(), np.array([5, 9, 2, 7]), 8))
+        # no EOS but the planted one, and room for 250 tokens, so the
+        # row is still decoding when it is told to leave
+        conf = DecoderConfig(**{
+            **conf.__dict__,
+            "eos_id": ref[3] if how == "eos" else conf.vocab_size})
+        model, pool, eng = _engine(conf, decode_buckets=(4,),
+                                   max_seq_len=256, kv_blocks=80)
+        s2 = eng.submit(np.array([8, 3]), 30)
+        s1 = eng.submit(np.array([5, 9, 2, 7]),
+                        8 if how == "eos" else 2000,
+                        deadline=time.monotonic() + 600)
+        first = []
+        if how != "eos":
+            first = [s1.next(timeout=30)]
+            assert first[0] is not None
+        if how == "cancel":
+            s1.cancel()
+        elif how == "deadline":             # it passes mid-generation
+            seq = eng._live.get(s1.seq_id)
+            if seq is not None:
+                seq.deadline = time.monotonic()
+        t1, t2 = first + list(s1), list(s2)
+        eng.shutdown()
+        assert s1.reason == {"eos": "eos", "cancel": "cancelled",
+                             "deadline": "deadline"}[how]
+        if how == "eos":
+            assert t1 == ref[:4]            # nothing after the EOS
+        else:
+            full = self._alone(model, eng, [5, 9, 2, 7], len(t1))
+            assert t1 == full               # a prefix of its own run
+        assert t2 == self._alone(model, eng, [8, 3], 30)
+        assert pool.live_blocks == 0
+        assert eng.retraces_since_warmup() == 0
 
 
 def _mesh_1d():
