@@ -843,12 +843,13 @@ def phase_kernels(*, epilogue_rows=128 * 56 * 56, epilogue_k=64,
             tables[i, j] = nxt
             nxt += 1
     require(nxt <= nb, "pool too small for the paged check")
-    k_f32 = jax.random.normal(kk2, (nb, bs, ph, pd), jnp.float32)
-    v_f32 = jax.random.normal(kv2, (nb, bs, ph, pd), jnp.float32)
+    # two layers of a pool as KVBlockPool stores it, the second read
+    k_f32 = jax.random.normal(kk2, (2, nb, bs, ph * pd), jnp.float32)
+    v_f32 = jax.random.normal(kv2, (2, nb, bs, ph * pd), jnp.float32)
     for name, dtype in (("paged_decode", jnp.float32),
                         ("paged_decode_bf16", bf)):
         args = (pq, k_f32.astype(dtype), v_f32.astype(dtype),
-                jnp.asarray(tables), jnp.asarray(lens))
+                jnp.asarray(tables), jnp.asarray(lens), 1)
         t0 = time.perf_counter()
         got = jax.jit(paged_decode_attention)(*args)
         ref = jax.jit(paged_attention_reference)(*args)
@@ -999,9 +1000,9 @@ def phase_hybrid(conf: dict = H1_SMALL, *, gqa=(8, 20, 4, 128, 80, 16, 6),
             nxt += 1
     require(nxt <= nb, "pool too small for the grouped-query check")
     args = (jax.random.normal(kq, (pb, hq, pd), jnp.float32),
-            jax.random.normal(kk, (nb, bs, hkv, pd)).astype(jnp.bfloat16),
-            jax.random.normal(kv, (nb, bs, hkv, pd)).astype(jnp.bfloat16),
-            jnp.asarray(tables), jnp.asarray(lens))
+            jax.random.normal(kk, (2, nb, bs, hkv * pd)).astype(jnp.bfloat16),
+            jax.random.normal(kv, (2, nb, bs, hkv * pd)).astype(jnp.bfloat16),
+            jnp.asarray(tables), jnp.asarray(lens), 1)
     out["paged_gqa"] = rel_err(jax.jit(paged_decode_attention)(*args),
                                jax.jit(paged_attention_reference)(*args))
     say("hybrid", f"paged_decode_attention {hq} query heads on {hkv} KV "
@@ -1057,11 +1058,11 @@ def phase_hybrid(conf: dict = H1_SMALL, *, gqa=(8, 20, 4, 128, 80, 16, 6),
     idx = np.arange(prompt_bucket)
     at = np.where(idx < prompt_len, block + idx, 0)     # blocks 1.., in order
     kp, vp, s_, c_ = cache_pool.arrays
-    flat = (kp.shape[0], -1) + kp.shape[3:]
-    cache = (kp.reshape(flat).at[:, at].set(k[:, 0].astype(kp.dtype)
-                                            ).reshape(kp.shape),
-             vp.reshape(flat).at[:, at].set(v[:, 0].astype(vp.dtype)
-                                            ).reshape(vp.shape),
+    flat = (kp.shape[0], -1, kp.shape[3])
+    cache = (kp.reshape(flat).at[:, at].set(
+                 k[:, 0].reshape(flat).astype(kp.dtype)).reshape(kp.shape),
+             vp.reshape(flat).at[:, at].set(
+                 v[:, 0].reshape(flat).astype(vp.dtype)).reshape(vp.shape),
              s_.at[:, 1].set(ssm[:, 0]), c_.at[:, 1].set(conv[:, 0]))
     table = np.arange(1, n_blocks + 1, dtype=np.int32)[None]
     step = jax.jit(lambda *a: model.decode_step(*a, paged=True))

@@ -9,8 +9,9 @@ prefill/decode split explicitly:
   the classifier path's heuristics say), returning the last valid
   position's logits plus every layer's K/V for the KV pool.
 - :meth:`DecoderLM.decode_step` — one token per live sequence: project
-  q/k/v for the new token, scatter its K/V into the paged pool at the
-  block-table slot, then paged attention over the pool (Pallas kernel
+  q/k/v for the new token, write its K/V rows into the paged pool at
+  the block-table slot (in place: the engine donates the pool), then
+  paged attention over the stacked pool as it is stored (Pallas kernel
   or dense-gather fallback via the ``paged_attention`` kernel-select
   family). Everything is shape-stable in (batch, table-width), so one
   compiled step serves the whole continuous batch forever.
@@ -182,48 +183,43 @@ class DecoderLM:
 
         ``tokens``/``positions`` ``[b]`` int32 (position = index of
         this token; the KV valid length becomes ``positions + 1``);
-        ``k_pool``/``v_pool`` ``[n_layers, num_blocks, block, heads,
-        head_dim]``; ``block_tables`` ``[b, max_blocks]`` int32 padded
-        with the scratch block 0 (dead batch slots pass position 0 and
-        an all-zero table — their writes land in scratch). ``paged``
-        picks the Pallas kernel over the dense-gather fallback.
-        Returns ``(logits [b, vocab], k_pool, v_pool)`` — a functional
-        pool update."""
+        ``k_pool``/``v_pool`` ``[n_layers, num_blocks, block, heads *
+        head_dim]``, as ``KVBlockPool`` stores them; ``block_tables``
+        ``[b, max_blocks]`` int32 padded with the scratch block 0 (dead
+        batch slots pass position 0 and an all-zero table — their
+        writes land in scratch). ``paged`` picks the Pallas kernel over
+        the dense-gather fallback. Returns ``(logits [b, vocab],
+        k_pool, v_pool)``: the pools with ``b`` rows a layer written
+        and nothing else moved — no reshape, slice or copy of a pool or
+        of a layer of it, so under donation the update is in place."""
         from deeplearning4j_tpu.ops.attention_pallas import (
             paged_attention_reference, paged_decode_attention)
         c = self.conf
         b = tokens.shape[0]
-        nl, nb, bs = (k_pool.shape[0], k_pool.shape[1],
-                      k_pool.shape[2])
+        bs = k_pool.shape[2]
+        attend = (paged_decode_attention if paged
+                  else paged_attention_reference)
         x = (params["embed"]["tok"][tokens]
              + params["embed"]["pos"][positions])        # [b, d]
-        slot = (block_tables[jnp.arange(b), positions // bs] * bs
-                + positions % bs)                        # [b]
+        blk = block_tables[jnp.arange(b), positions // bs]   # [b]
+        off = positions % bs
         lengths = positions + 1
-        kf = jnp.reshape(k_pool, (nl, nb * bs) + k_pool.shape[3:])
-        vf = jnp.reshape(v_pool, (nl, nb * bs) + v_pool.shape[3:])
         for i in range(c.n_layers):
             p = params[f"layer_{i}"]
             h = _ln(x, p["ln1_g"], p["ln1_b"])
             q, k_new, v_new = self._attn_qkv(p, h, heads_first=False)
             # low-precision pools (kv_dtype=bf16) take writes in the
             # pool's own dtype; attention math re-promotes via q
-            kf = kf.at[i, slot].set(k_new.astype(kf.dtype))
-            vf = vf.at[i, slot].set(v_new.astype(vf.dtype))
-            kp = jnp.reshape(kf[i], (nb, bs, c.n_heads, c.head_dim))
-            vp = jnp.reshape(vf[i], (nb, bs, c.n_heads, c.head_dim))
-            if paged:
-                a = paged_decode_attention(q, kp, vp, block_tables,
-                                           lengths)
-            else:
-                a = paged_attention_reference(q, kp, vp, block_tables,
-                                              lengths)
+            k_pool = k_pool.at[i, blk, off].set(
+                jnp.reshape(k_new, (b, -1)).astype(k_pool.dtype))
+            v_pool = v_pool.at[i, blk, off].set(
+                jnp.reshape(v_new, (b, -1)).astype(v_pool.dtype))
+            a = attend(q, k_pool, v_pool, block_tables, lengths, i)
             x = x + jnp.reshape(a, (b, c.d_model)) @ p["wo"]
             x = self._mlp(p, x)
         hp = params["head"]
         logits = _ln(x, hp["ln_g"], hp["ln_b"]) @ hp["w"]
-        shape = (nl, nb, bs, c.n_heads, c.head_dim)
-        return logits, jnp.reshape(kf, shape), jnp.reshape(vf, shape)
+        return logits, k_pool, v_pool
 
     # -- reference decode (conformance gate) ----------------------------
     def reference_decode(self, params, prompt, max_tokens: int,

@@ -22,17 +22,18 @@ paged K/V:
   stand after position ``length - 1``**: ``dt`` is masked to 0 past
   the length, which leaves the state untouched there, and the tail is
   the last ``d_conv - 1`` valid inputs of the convolution.
-- :meth:`FalconH1LM.decode_step` — one token a row: K/V scattered into
-  the paged pool (``n_kv_heads`` heads) and read by the grouped-query
-  paged kernel; the row's state slot updated in place by
+- :meth:`FalconH1LM.decode_step` — one token a row: its K/V rows
+  written into the paged pool (``n_kv_heads * head_dim`` lanes a
+  token) and the stacked pool read as it is stored by the
+  grouped-query paged kernel; the row's state slot updated in place by
   ``ops.ssm_pallas.ssm_state_update``. Rows of the bucket that hold no
   sequence name block 0 and slot 0, the scratch ones.
 
-``state_shapes()`` tells the cache manager what a slot holds;
-``donates_cache`` asks the engine to donate the cache's arrays to the
-commit and decode programs, so that the in-place state update is in
-place across the program boundary too (the pools are then never held
-twice). Weights are whatever type ``params`` holds (bfloat16 in the
+``state_shapes()`` tells the cache manager what a slot holds. The
+engine donates the cache's arrays to the commit and decode programs
+of every model, so the in-place state update is in place across the
+program boundary too (the pools are never held twice).
+Weights are whatever type ``params`` holds (bfloat16 in the
 benchmark); the residual stream and every activation are float32, the
 products run at the backend's default precision, the recurrent state
 and its decay are float32.
@@ -145,10 +146,6 @@ def _rope(x, positions, theta):
 class FalconH1LM:
     """Parallel Mamba-2 + grouped-query attention decoder over token
     ids."""
-
-    #: the engine donates the cache's arrays to this model's commit and
-    #: decode programs (see the module docstring)
-    donates_cache = True
 
     def __init__(self, conf: Optional[FalconH1Config] = None, **kw):
         self.conf = conf if conf is not None else FalconH1Config(**kw)
@@ -365,7 +362,7 @@ class FalconH1LM:
         """One token for every row of the decode batch.
 
         As :meth:`DecoderLM.decode_step`, with ``k_pool``/``v_pool``
-        ``[n_layers, num_blocks, block, n_kv_heads, head_dim]`` and, for
+        ``[n_layers, num_blocks, block, n_kv_heads * head_dim]`` and, for
         the recurrent state, ``ssm [n_layers, slots, heads, p, n]``,
         ``conv [n_layers, slots, d_conv - 1, conv_dim]`` and
         ``state_slots [b]`` int32 (0, the scratch slot, for a dead
@@ -376,25 +373,23 @@ class FalconH1LM:
         from deeplearning4j_tpu.ops.ssm_pallas import ssm_state_update
         c = self.conf
         b = tokens.shape[0]
-        nl, nb, bs = k_pool.shape[:3]
+        bs = k_pool.shape[2]
         attend = (paged_decode_attention if paged
                   else paged_attention_reference)
         x = (params["embed"]["tok"][tokens].astype(jnp.float32)
              * c.embedding_multiplier)                       # [b, d]
-        slot = (block_tables[jnp.arange(b), positions // bs] * bs
-                + positions % bs)                            # [b]
+        blk = block_tables[jnp.arange(b), positions // bs]   # [b]
+        off = positions % bs
         lengths = positions + 1
-        kf = jnp.reshape(k_pool, (nl, nb * bs) + k_pool.shape[3:])
-        vf = jnp.reshape(v_pool, (nl, nb * bs) + v_pool.shape[3:])
         for i in range(c.n_layers):
             p = params[f"layer_{i}"]
             h = _rms(x, p["norm1"], c.rms_norm_eps)
             q, k_new, v_new = self._qkv(p, h, positions)
-            kf = kf.at[i, slot].set(k_new.astype(kf.dtype))
-            vf = vf.at[i, slot].set(v_new.astype(vf.dtype))
-            a = attend(q, jnp.reshape(kf[i], k_pool.shape[1:]),
-                       jnp.reshape(vf[i], v_pool.shape[1:]),
-                       block_tables, lengths)
+            k_pool = k_pool.at[i, blk, off].set(
+                jnp.reshape(k_new, (b, -1)).astype(k_pool.dtype))
+            v_pool = v_pool.at[i, blk, off].set(
+                jnp.reshape(v_new, (b, -1)).astype(v_pool.dtype))
+            a = attend(q, k_pool, v_pool, block_tables, lengths, i)
             a = _mm(jnp.reshape(a, (b, -1)),
                     p["wo"]) * c.attention_out_multiplier
             # the mixer: advance the row's convolution tail, then its
@@ -411,8 +406,7 @@ class FalconH1LM:
                 jnp.exp(dt * -jnp.exp(p["A_log"])), bs_, cs)
             x = x + self._ssm_out(p, y, xs, z) + a
             x = self._mlp(p, x)
-        return (self._logits(params, x), jnp.reshape(kf, k_pool.shape),
-                jnp.reshape(vf, v_pool.shape), ssm, conv)
+        return self._logits(params, x), k_pool, v_pool, ssm, conv
 
     # -- reference decode (conformance gate) ----------------------------
     def reference_decode(self, params, prompt, max_tokens: int,

@@ -199,31 +199,42 @@ def flash_sdpa(q, k, v, scale: Optional[float] = None, key_mask=None,
 _PAGED_NEG_INF = -1e30
 
 
+def _paged_gather(pool, layer, block_tables, d):
+    """One layer's K or V at every table position, ``[b, t, h_kv, d]``:
+    a gather of the tables' blocks out of the stacked lane-dense pool
+    (no slice of the layer), its lanes split into heads."""
+    b, max_blocks = block_tables.shape
+    block, hd = pool.shape[2:]
+    return jnp.reshape(pool[layer, block_tables],
+                       (b, max_blocks * block, hd // d, d))
+
+
 def paged_attention_reference(q, k_pool, v_pool, block_tables,
-                              lengths, scale: Optional[float] = None):
+                              lengths, layer=0,
+                              scale: Optional[float] = None):
     """Dense-gather fallback AND numerical reference for paged decode
     attention.
 
     ``q`` [b, h, d] (the single new token per sequence); ``k_pool`` /
-    ``v_pool`` [num_blocks, block, h, d] (one layer's paged KV);
-    ``block_tables`` [b, max_blocks] int32 (scratch-block-0 padded);
-    ``lengths`` [b] int32 — valid KV tokens per sequence (>= 1, the
-    current token's KV already written). Returns [b, h, d].
+    ``v_pool`` [layers, num_blocks, block, h_kv * d] (the whole paged
+    KV as ``KVBlockPool`` stores it: a token's heads side by side down
+    the lanes) of which ``layer`` is read; ``block_tables``
+    [b, max_blocks] int32 (scratch-block-0 padded); ``lengths`` [b]
+    int32 — valid KV tokens per sequence (>= 1, the current token's KV
+    already written). Returns [b, h, d].
 
-    The gather materializes [b, max_blocks*block, h, d] whatever the
-    lengths are — the bytes the Pallas kernel does not move — but runs
-    everywhere and defines the semantics the kernel must match
+    The gather materializes [b, max_blocks*block, h_kv, d] whatever
+    the lengths are — the bytes the Pallas kernel does not move — but
+    runs everywhere and defines the semantics the kernel must match
     bit-for-tolerance."""
     b, h, d = q.shape
-    block, h_kv = k_pool.shape[1:3]
-    t = block_tables.shape[1] * block
-    if h != h_kv:
-        return _paged_reference_grouped(q, k_pool, v_pool, block_tables,
-                                        lengths, scale)
-    k = jnp.reshape(k_pool[block_tables], (b, t, h, d))
-    v = jnp.reshape(v_pool[block_tables], (b, t, h, d))
+    k = _paged_gather(k_pool, layer, block_tables, d)
+    v = _paged_gather(v_pool, layer, block_tables, d)
+    t = k.shape[1]
     if scale is None:
         scale = 1.0 / math.sqrt(d)
+    if h != k.shape[2]:
+        return _paged_reference_grouped(q, k, v, lengths, scale)
     s = jnp.einsum("bhd,bthd->bht", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     valid = (jnp.arange(t, dtype=jnp.int32)[None, :]
@@ -236,27 +247,22 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables,
     return out.astype(q.dtype)
 
 
-def _paged_reference_grouped(q, k_pool, v_pool, block_tables, lengths,
-                             scale):
-    """:func:`paged_attention_reference` where the pool holds fewer KV
-    heads than ``q`` has query heads (grouped-query attention): query
-    head ``i`` reads KV head ``i // (h / h_kv)``."""
+def _paged_reference_grouped(q, k, v, lengths, scale):
+    """:func:`paged_attention_reference` where the gathered ``k`` /
+    ``v`` [b, t, h_kv, d] hold fewer KV heads than ``q`` has query
+    heads (grouped-query attention): query head ``i`` reads KV head
+    ``i // (h / h_kv)``."""
     f32 = jnp.float32
     b, h, d = q.shape
-    block, h_kv = k_pool.shape[1:3]
-    t = block_tables.shape[1] * block
-    k = jnp.reshape(k_pool[block_tables], (b, t, h_kv, d)).astype(f32)
-    v = jnp.reshape(v_pool[block_tables], (b, t, h_kv, d)).astype(f32)
-    if scale is None:
-        scale = 1.0 / math.sqrt(d)
+    t, h_kv = k.shape[1:3]
     qg = q.astype(f32).reshape(b, h_kv, h // h_kv, d)
-    s = jnp.einsum("bkgd,btkd->bkgt", qg, k) * scale
+    s = jnp.einsum("bkgd,btkd->bkgt", qg, k.astype(f32)) * scale
     valid = jnp.arange(t, dtype=jnp.int32)[None, :] < lengths[:, None]
     s = jnp.where(valid[:, None, None, :], s, _PAGED_NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.where(s <= _PAGED_NEG_INF / 2, 0.0, jnp.exp(s - m))
     w = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
-    out = jnp.einsum("bkgt,btkd->bkgd", w, v)
+    out = jnp.einsum("bkgt,btkd->bkgd", w, v.astype(f32))
     return out.reshape(b, h, d).astype(q.dtype)
 
 
@@ -280,21 +286,22 @@ def _paged_blocks_per_step(block: int, hd: int, itemsize: int,
     return max(1, min(tokens // block, max_blocks))
 
 
-def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm,
-                         out_ref, kbuf, vbuf, sem, slot_ref, m_ref,
-                         l_ref, acc_ref, *, head_dim: int, block: int,
-                         blocks_per_step: int, scale: float,
+def _paged_decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_hbm,
+                         v_hbm, out_ref, kbuf, vbuf, sem, slot_ref,
+                         m_ref, l_ref, acc_ref, *, head_dim: int,
+                         block: int, blocks_per_step: int, scale: float,
                          group: int = 1):
     """One sequence a grid step; inside it, a loop over the sequence's
     own ``ceil(length / block)`` blocks, ``blocks_per_step`` at a time.
 
-    The pools stay in HBM. A step's blocks are fetched by one DMA each
-    (the table names them) into one slot of a two-slot ``[T, h*d]``
-    slab, ``T = blocks_per_step * block``, and the next step's — the
-    next sequence's first, after a sequence's last — are started
-    before this step's are waited for, so a dead row of the bucket
-    (length 1) costs one block and nothing past a row's length is
-    fetched, multiplied or waited for.
+    The stacked pools ``[layers, blocks, block, h*d]`` stay in HBM
+    whole: no layer is cut out of them. A step's blocks are fetched by
+    one DMA each (``layer_ref`` and the table name them) into one slot
+    of a two-slot ``[T, h*d]`` slab, ``T = blocks_per_step * block``,
+    and the next step's — the next sequence's first, after a
+    sequence's last — are started before this step's are waited for,
+    so a dead row of the bucket (length 1) costs one block and nothing
+    past a row's length is fetched, multiplied or waited for.
 
     The math keeps the pool's own lane-dense order ``[T, h*d]`` and
     has no head axis: with ``Qbd [H, h*d]`` holding q's head ``r`` in
@@ -310,6 +317,7 @@ def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm,
     f32, bf16 = jnp.float32, jnp.bfloat16
     i = pl.program_id(0)
     n_rows = pl.num_programs(0)
+    layer = layer_ref[0]
     max_blocks = tables_ref.shape[1]
     step_tokens = blocks_per_step * block
     hp, hd = acc_ref.shape
@@ -320,10 +328,10 @@ def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm,
     def block_copies(row, first, g, slot):
         blk = tables_ref[row, first + g]
         dst = pl.ds(pl.multiple_of(g * block, block), block)
-        return (pltpu.make_async_copy(k_hbm.at[blk], kbuf.at[slot, dst],
-                                      sem.at[0, slot]),
-                pltpu.make_async_copy(v_hbm.at[blk], vbuf.at[slot, dst],
-                                      sem.at[1, slot]))
+        return (pltpu.make_async_copy(k_hbm.at[layer, blk],
+                                      kbuf.at[slot, dst], sem.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[layer, blk],
+                                      vbuf.at[slot, dst], sem.at[1, slot]))
 
     def fetch(row, step, slot, wait):
         """Start (or wait for) the DMAs of ``row``'s blocks
@@ -414,13 +422,14 @@ def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm,
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
-                           scale: Optional[float] = None):
+                           layer=0, scale: Optional[float] = None):
     """Pallas paged decode attention — same contract as
     :func:`paged_attention_reference`, at the model's default product
     precision (bf16 operands, float32 accumulation and softmax). The
-    pools stay in HBM; the kernel reads ``lengths`` and fetches only
-    the ``ceil(length / block)`` blocks each sequence's table names,
-    so its work goes with the live context and not with the bucket
+    stacked pools stay in HBM as they are stored; the kernel reads
+    ``lengths`` and fetches only the ``ceil(length / block)`` blocks of
+    ``layer`` that each sequence's table names, so its work goes with
+    the live context and not with the bucket
     (:func:`_paged_decode_kernel`). Compiled by Mosaic on a TPU
     backend, interpreted everywhere else
     (``kernel_select.interpret_mode``), so CPU conformance tests run
@@ -431,34 +440,36 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     per_step = _paged_blocks_per_step(
-        int(k_pool.shape[1]), int(k_pool.shape[2]) * d,
+        int(k_pool.shape[2]), int(k_pool.shape[3]),
         k_pool.dtype.itemsize, int(block_tables.shape[1]))
     return _paged_call(q, k_pool, v_pool, block_tables, lengths,
+                       jnp.asarray(layer, jnp.int32).reshape(1),
                        scale=float(scale), per_step=per_step,
                        interpret=kernel_select.interpret_mode())
 
 
 @functools.partial(jax.jit,
                    static_argnames=("scale", "per_step", "interpret"))
-def _paged_call(q, k_pool, v_pool, block_tables, lengths, *, scale,
-                per_step, interpret):
+def _paged_call(q, k_pool, v_pool, block_tables, lengths, layer, *,
+                scale, per_step, interpret):
     """The ``pallas_call``, jitted on its own so that a model's layers
-    share one trace and one lowering of the kernel: traced layer by
-    layer, GPT-2 large's 36 added 3.5 s to a 30 s set-up (PR 27)."""
+    share one trace and one lowering of the kernel (the layer index is
+    an operand): traced layer by layer, GPT-2 large's 36 added 3.5 s
+    to a 30 s set-up (PR 27)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, d = q.shape
-    nb, block, h_kv = k_pool.shape[:3]
-    hd = h_kv * d                         # the pool's lanes a token
+    block, hd = k_pool.shape[2:]          # hd: the pool's lanes a token
+    h_kv = hd // d
     g = h // h_kv                         # query heads a KV head
     hp = -(-h // 16) * 16                 # heads, a bf16 sublane tile up
 
-    def row(i, tables, lens):                     # one sequence's q/out
+    def row(i, tables, lens, layer):              # one sequence's q/out
         return (i, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,          # block_tables, lengths
+        num_scalar_prefetch=3,          # block_tables, lengths, layer
         grid=(b,),
         in_specs=[
             pl.BlockSpec((None, g, hd), row),
@@ -494,8 +505,7 @@ def _paged_call(q, k_pool, v_pool, block_tables, lengths, *, scale,
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
         )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-          by_kv_head(q), k_pool.reshape(nb, block, hd),
-          v_pool.reshape(nb, block, hd))
+          layer, by_kv_head(q), k_pool, v_pool)
     if g == 1:
         return out.reshape(b, h, d)
     return jnp.swapaxes(out.reshape(b, g, h_kv, d), 1, 2).reshape(b, h, d)

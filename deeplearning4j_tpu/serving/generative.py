@@ -71,9 +71,13 @@ program writes the prefill's last-position state into it, the decode
 step takes the rows' slots beside their block tables (0, the scratch
 slot, for a dead row), and retirement frees it with the blocks. The
 compiled programs take the cache's arrays as one pytree
-(``pool.arrays``), so a K/V-only pool lowers as it always did. The
-spans then carry ``state_live`` of ``state_slots``
-(``generate.decode_step``) and ``state_slot`` (``generate.prefill``).
+(``pool.arrays``: K and V ``[layers, blocks, block, kv_heads *
+head_dim]``, then the state arrays), **donated** for every model: the
+commit program writes the prompt's blocks and the decode step one row
+a sequence a layer into the buffers they were given, and the pool
+holds what they return. The spans then carry ``state_live`` of
+``state_slots`` (``generate.decode_step``) and ``state_slot``
+(``generate.prefill``).
 """
 from __future__ import annotations
 
@@ -358,34 +362,44 @@ class DecodeEngine:
             self._jits["prefill"] = jax.jit(fn)
         return self._jits["prefill"]
 
-    def _donate(self, argnum: int) -> tuple:
-        """The cache argument, donated where the model asks for it
-        (``donates_cache``): its programs then update the pool's
-        arrays in place instead of writing a second copy of them."""
-        return (argnum,) if getattr(self.model, "donates_cache",
-                                    False) else ()
-
     def _commit_jit(self):
         import jax
+        import jax.numpy as jnp
         if "commit" not in self._jits:
-            def fn(cache, new, slots, *state_slot):
+            def fn(cache, new, blocks, *state_slot):
                 (kp, vp, *state), (k, v, *new_state) = cache, new
-                nl, nb, bs = kp.shape[0], kp.shape[1], kp.shape[2]
-                tail = kp.shape[3:]
-                kf = kp.reshape((nl, nb * bs) + tail)
-                vf = vp.reshape((nl, nb * bs) + tail)
-                # low-precision pools (kv_dtype=bf16) take the write
-                # in the pool's own dtype
-                kf = kf.at[:, slots].set(k[:, 0].astype(kp.dtype))
-                vf = vf.at[:, slots].set(v[:, 0].astype(vp.dtype))
+                bs = kp.shape[2]
+                # every layer named block by block, not taken whole
+                # with ``[:, blocks]``: a scatter whose window spans
+                # the layers may make XLA transpose the pool into a
+                # layout of its own and back (two copies of each pool)
+                layer = jnp.arange(kp.shape[0])[:, None]
+
+                def tiles(a, pool):
+                    # [layers, 1, t, heads, d] -> [layers, blocks,
+                    # block, heads * d]: a position's heads side by
+                    # side, the bucket cut into the pool's blocks (and
+                    # padded up to whole ones); low-precision pools
+                    # (kv_dtype=bf16) take the write in their own dtype
+                    a = a[:, 0].reshape(a.shape[0], a.shape[2], -1)
+                    a = jnp.pad(a, ((0, 0), (0, blocks.size * bs
+                                             - a.shape[1]), (0, 0)))
+                    return a.reshape(a.shape[0], -1, bs,
+                                     a.shape[-1]).astype(pool.dtype)
+                # the prompt's blocks of every layer into the donated
+                # pools, whole: what lies past the prompt in its last
+                # block is not live until a decode step writes it, and
+                # the bucket's other blocks land in scratch block 0
+                kp = kp.at[layer, blocks].set(tiles(k, kp))
+                vp = vp.at[layer, blocks].set(tiles(v, vp))
                 # recurrent state: the prompt's last-token state into
                 # the sequence's slot, every layer at once
                 state = [a.at[:, state_slot[0]].set(n[:, 0].astype(a.dtype))
                          for a, n in zip(state, new_state)]
-                return (kf.reshape(kp.shape), vf.reshape(vp.shape),
-                        *state)
-            self._jits["commit"] = jax.jit(
-                fn, donate_argnums=self._donate(0))
+                return (kp, vp, *state)
+            # the cache is donated: the pool's arrays are updated in
+            # place, never written a second time
+            self._jits["commit"] = jax.jit(fn, donate_argnums=(0,))
         return self._jits["commit"]
 
     def _sample_jit(self):
@@ -409,8 +423,7 @@ class DecodeEngine:
                     tables, *state_slots, paged=paged)
                 ids = sample_logits(logits, key, temps, topks)
                 return ids, tuple(cache)
-            self._jits["decode"] = jax.jit(
-                fn, donate_argnums=self._donate(1))
+            self._jits["decode"] = jax.jit(fn, donate_argnums=(1,))
         return self._jits["decode"]
 
     # -- warmup --------------------------------------------------------
@@ -427,10 +440,10 @@ class DecodeEngine:
             self.guard.record(tokens, length)
             last, *new = self._prefill_jit()(self.params, tokens,
                                              length)
-            slots = np.zeros((t,), np.int32)
-            self.guard.record(new[0], slots)
+            blocks = np.zeros((self.pool.blocks_for(t),), np.int32)
+            self.guard.record(new[0], blocks)
             cache = self._commit_jit()(
-                self.pool.arrays, tuple(new), slots,
+                self.pool.arrays, tuple(new), blocks,
                 *self._state_arg(np.int32(0)))
             # the first-token sampler compiles once here (its [1,
             # vocab] signature never varies with the prompt bucket)
@@ -652,20 +665,14 @@ class DecodeEngine:
             self._record(tokens, length)
             last, *new = self._prefill_jit()(self.params, tokens,
                                              length)
-            # scatter the prompt's K/V into its pool blocks (padded
-            # positions land in scratch block 0)
-            table = self.pool.table(seq_id)
-            idx = np.arange(t)
-            slots = np.where(
-                idx < prompt.size,
-                np.asarray(table, np.int64)[
-                    np.minimum(idx // self.pool.block_size,
-                               len(table) - 1)]
-                * self.pool.block_size + idx % self.pool.block_size,
-                0).astype(np.int32)
-            self._record(new[0], slots)
+            # the bucket's K/V into the prompt's pool blocks (the
+            # blocks of the bucket past the prompt's last land in
+            # scratch block 0)
+            blocks = self.pool.padded_table(seq_id,
+                                            self.pool.blocks_for(t))
+            self._record(new[0], blocks)
             self.pool.update_arrays(*self._commit_jit()(
-                self.pool.arrays, tuple(new), slots,
+                self.pool.arrays, tuple(new), blocks,
                 *self._state_arg(np.int32(self.pool.slot(seq_id)))))
             self._step += 1
             key = jax.random.fold_in(self._rng, self._step)

@@ -9,15 +9,19 @@ the fragmentation paged attention (vLLM) eliminates. This module is
 that allocator for the TPU stack:
 
 - One preallocated device array pair per pool — ``k`` / ``v`` shaped
-  ``[n_layers, num_blocks, block_size, n_heads, head_dim]`` — carved
-  into fixed-size **blocks** of ``block_size`` token slots. A per-layer
-  view ``pool.k[l]`` is the ``[num_blocks, block, heads, head_dim]``
-  paged layout the decode kernel gathers through.
+  ``[n_layers, num_blocks, block_size, n_heads * head_dim]`` — carved
+  into fixed-size **blocks** of ``block_size`` token slots. That is
+  the form the paged decode kernel reads: a token's heads side by
+  side down the lanes (no ``head_dim``-wide minor axis for the
+  compiler to pad to a lane tile), a block one contiguous
+  ``[block, n_heads * head_dim]`` DMA at ``k[l, table[j]]``. No
+  program slices a layer out of it or relays it.
 - A **block table** per sequence: the ordered list of block ids
   holding its tokens. Block ids are shared across layers (layer ``l``
   of token ``t`` lives at ``k[l, table[t // block_size],
-  t % block_size]``), so the table is one small int array per
-  sequence, not one per layer.
+  t % block_size]``, head ``h`` on lanes ``[h * head_dim, (h + 1) *
+  head_dim)``), so the table is one small int array per sequence, not
+  one per layer.
 - **Block 0 is reserved scratch**: padded decode-batch rows (slots
   with no live sequence) write their dummy KV there, so the fused
   step never branches on liveness for the write. It is never handed
@@ -118,9 +122,10 @@ class KVBlockPool:
     block at each ``block_size`` boundary), ``free(seq_id)`` returns
     every block to the free list — callable mid-batch, which is the
     whole point of iteration-level scheduling. The device arrays are
-    functional values: the jitted decode step consumes ``pool.k`` /
-    ``pool.v`` and the engine stores the updated arrays back with
-    :meth:`update_arrays`.
+    **donated** to every program that writes them: the engine's commit
+    and decode programs take :attr:`arrays`, update them in place, and
+    the engine stores what they return back with :meth:`update_arrays`
+    (the arrays it passed in are gone from then on).
     """
 
     def __init__(self, n_layers: int, num_blocks: int,
@@ -141,7 +146,7 @@ class KVBlockPool:
         self.head_dim = int(head_dim)
         self.name = name
         shape = (self.n_layers, self.num_blocks, self.block_size,
-                 self.n_heads, self.head_dim)
+                 self.n_heads * self.head_dim)
         if device_arrays:
             import jax.numpy as jnp
             self.k = jnp.zeros(shape, dtype=dtype)
@@ -176,7 +181,12 @@ class KVBlockPool:
     # -- sizing ---------------------------------------------------------
     @property
     def pool_bytes(self) -> int:
-        """Preallocated device bytes (k + v) — the resident class."""
+        """Preallocated device bytes (k + v) — the resident class.
+        The arrays' own ``nbytes``: with a token's heads merged down
+        the lanes that is what the device holds (no ``head_dim`` minor
+        axis padded to a lane tile) wherever ``n_heads * head_dim`` is
+        whole 128-lane tiles and a block whole sublane tiles, and the
+        donated programs never hold a second copy."""
         return int(self.k.nbytes) + int(self.v.nbytes)
 
     @property
@@ -343,9 +353,9 @@ class KVBlockPool:
         return np.asarray(t + [0] * (max_blocks - len(t)), np.int32)
 
     def update_arrays(self, k, v, *state) -> None:
-        """Store the decode step's updated pool arrays (functional
-        update: jit returns new values for the same buffers), in the
-        order of :attr:`arrays`."""
+        """Store the arrays a commit or decode program returned (the
+        donated buffers, updated in place), in the order of
+        :attr:`arrays`."""
         self.k, self.v = k, v
         if state:
             self.state = dict(zip(self.state, state))
@@ -373,7 +383,7 @@ class KVBlockPool:
             "live_sequences": self.live_sequences,
             "block_tokens": self.block_size,
             "layout": [self.n_layers, self.num_blocks, self.block_size,
-                       self.n_heads, self.head_dim],
+                       self.n_heads * self.head_dim],
         }
 
 

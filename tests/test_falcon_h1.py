@@ -95,9 +95,11 @@ def _commit(model, pool, k, v, ssm, conv, length, table, slot):
                     np.asarray(table)[np.minimum(idx // bs, len(table) - 1)]
                     * bs + idx % bs, 0)
     kp, vp, s_, c_ = pool.arrays
-    flat = (kp.shape[0], -1) + kp.shape[3:]
-    kp = kp.reshape(flat).at[:, rows].set(k[:, 0]).reshape(kp.shape)
-    vp = vp.reshape(flat).at[:, rows].set(v[:, 0]).reshape(vp.shape)
+    flat = (kp.shape[0], -1, kp.shape[3])
+    kp = kp.reshape(flat).at[:, rows].set(
+        k[:, 0].reshape(flat)).reshape(kp.shape)
+    vp = vp.reshape(flat).at[:, rows].set(
+        v[:, 0].reshape(flat)).reshape(vp.shape)
     return (kp, vp, s_.at[:, slot].set(ssm[:, 0]),
             c_.at[:, slot].set(conv[:, 0]))
 
@@ -211,8 +213,9 @@ def test_state_update_ladder():
 def _paged_case(h_q, h_kv, d=16, seed=3):
     rs = np.random.RandomState(seed)
     q = jnp.asarray(rs.randn(3, h_q, d), jnp.float32)
-    kp = jnp.asarray(rs.randn(12, 8, h_kv, d), jnp.float32)
-    vp = jnp.asarray(rs.randn(12, 8, h_kv, d), jnp.float32)
+    # one layer of a pool: [layers, blocks, block, h_kv * d]
+    kp = jnp.asarray(rs.randn(1, 12, 8, h_kv * d), jnp.float32)
+    vp = jnp.asarray(rs.randn(1, 12, 8, h_kv * d), jnp.float32)
     tables = jnp.asarray([[1, 2, 3, 0], [4, 5, 0, 0], [0, 0, 0, 0]],
                          jnp.int32)
     return q, kp, vp, tables, jnp.asarray([20, 9, 1], jnp.int32)
@@ -223,8 +226,8 @@ def test_paged_attention_with_five_query_heads_a_kv_head():
     want = ap.paged_attention_reference(q, kp, vp, tables, lens)
     # the grouped reference is plain attention of head i on KV head i // 5
     for row in range(2):
-        k = kp[tables[row]].reshape(-1, 2, 16)[:lens[row]]
-        v = vp[tables[row]].reshape(-1, 2, 16)[:lens[row]]
+        k = kp[0, tables[row]].reshape(-1, 2, 16)[:lens[row]]
+        v = vp[0, tables[row]].reshape(-1, 2, 16)[:lens[row]]
         for i in range(10):
             w = jax.nn.softmax((k[:, i // 5] @ q[row, i]) / 4.0)
             np.testing.assert_allclose(want[row, i], w @ v[:, i // 5],

@@ -246,6 +246,70 @@ class TestDecodeEngine:
         assert pool.live_blocks == 0
 
 
+def _cache_engine(kind):
+    """A warmed engine over the model class ``kind`` names, its pool
+    with state slots where the model has recurrent state."""
+    if kind == "decoder":
+        return _engine()[1:]
+    from deeplearning4j_tpu.models.falcon_h1 import FalconH1LM
+    model = FalconH1LM()
+    c = model.conf
+    pool = KVBlockPool(c.n_layers, 64, 8, c.n_kv_heads, c.head_dim,
+                       state=model.state_shapes(), state_slots=5,
+                       name="t-gen-h1")
+    eng = DecodeEngine(model, model.init(), pool, name="t-gen-h1",
+                       prompt_buckets=(16,), decode_buckets=(4,),
+                       max_seq_len=64)
+    eng.warmup()
+    return pool, eng
+
+
+@pytest.mark.parametrize("program", ["commit", "decode"])
+@pytest.mark.parametrize("kind", ["decoder", "falcon-h1"])
+def test_the_cache_is_updated_in_place(kind, program):
+    """One donation rule for every model: the commit and the decode
+    program alias each array of the cache to an output (K, V and the
+    state kinds: no second pool is written), and the arrays the pool
+    held before a call are gone after it."""
+    import jax
+    pool, eng = _cache_engine(kind)
+    try:
+        b, t = eng.decode_buckets[-1], eng.prompt_buckets[-1]
+        if program == "commit":
+            _, *new = eng._prefill_jit()(
+                eng.params, np.zeros((1, t), np.int32),
+                np.asarray([3], np.int32))
+            jit = eng._commit_jit()
+            args = (pool.arrays, tuple(new),
+                    np.zeros((pool.blocks_for(t),), np.int32),
+                    *eng._state_arg(np.int32(0)))
+        else:
+            jit = eng._decode_jit()
+            args = (eng.params, pool.arrays, np.zeros((b,), np.int32),
+                    np.zeros((b,), np.int32),
+                    np.zeros((b, eng.max_blocks), np.int32),
+                    jax.random.PRNGKey(0), np.zeros((b,), np.float32),
+                    np.zeros((b,), np.int32),
+                    *eng._state_arg(np.zeros((b,), np.int32)))
+        n = len(pool.arrays)
+        assert n == (2 if kind == "decoder" else 4)
+        lowered = jit.lower(*args)
+        assert lowered.as_text().count("tf.aliasing_output") == n
+        assert lowered.compile().memory_analysis().alias_size_in_bytes \
+            == pool.pool_bytes + pool.state_bytes
+        # and at run time: what went in is deleted, the pool holds
+        # the program's own arrays
+        before = pool.arrays
+        out = jit(*args)
+        cache = out if program == "commit" else out[1]
+        pool.update_arrays(*cache)
+        assert all(a.is_deleted() for a in before)
+        assert not any(a.is_deleted() for a in pool.arrays)
+        assert [a.shape for a in pool.arrays] == [a.shape for a in before]
+    finally:
+        eng.shutdown()
+
+
 def _run_three(eng):
     """Three requests, the third joining while the first two decode:
     admits, steps, mid-batch retirement and a last lone row."""

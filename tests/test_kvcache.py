@@ -102,6 +102,55 @@ class TestAllocator:
             _pool(num_blocks=1)
 
 
+class TestStorage:
+    """The arrays as the paged kernel reads them: a token's heads side
+    by side down the lanes, no ``head_dim``-wide minor axis."""
+
+    @pytest.mark.parametrize("device_arrays", [False, True],
+                             ids=["numpy", "device"])
+    def test_k_and_v_are_lane_dense(self, device_arrays):
+        p = KVBlockPool(3, 8, 4, 2, 8, name="t-layout",
+                        device_arrays=device_arrays)
+        assert p.k.shape == p.v.shape == (3, 8, 4, 2 * 8)
+        assert p.report()["layout"] == [3, 8, 4, 16]
+        # what the gauge and the memory report say is what is stored
+        assert p.pool_bytes == 2 * 3 * 8 * 4 * 16 * 4 == \
+            p.report()["bytes"]
+
+    def test_arrays_are_k_v_then_the_state_kinds_in_order(self):
+        state = {"ssm": ((4, 8, 16), np.float32),
+                 "conv": ((3, 10), np.float32)}
+        p = _pool(state=state, state_slots=3)
+        k, v, ssm, conv = p.arrays
+        assert k is p.k and v is p.v
+        assert ssm is p.state["ssm"] and conv is p.state["conv"]
+        assert ssm.shape == (2, 3, 4, 8, 16) and conv.shape == (2, 3, 3, 10)
+        assert p.report()["state"]["layout"] == {
+            "ssm": [2, 3, 4, 8, 16], "conv": [2, 3, 3, 10]}
+        # update_arrays takes them back in the same order
+        new = tuple(a + 1 for a in p.arrays)
+        p.update_arrays(*new)
+        assert all(a is b for a, b in zip(p.arrays, new))
+        assert list(p.state) == ["ssm", "conv"]
+
+    def test_k_v_and_state_are_the_owning_attributes(self):
+        """The benchmark frees a pool by ``pool.k = pool.v = None``
+        and ``pool.state = {}``: nothing else may hold the arrays."""
+        import gc
+        import weakref
+
+        import jax.numpy as jnp
+        p = KVBlockPool(2, 4, 4, 2, 8, name="t-free",
+                        state={"ssm": ((2, 2), jnp.float32)},
+                        state_slots=2)
+        held = [weakref.ref(a) for a in p.arrays]
+        p.k = p.v = None
+        p.state = {}
+        gc.collect()
+        assert all(r() is None for r in held)
+        assert p.arrays == (None, None)
+
+
 class TestMemoryReport:
     def test_pool_is_its_own_resident_class(self):
         p = KVBlockPool(2, 4, 4, 2, 8, name="resident-t")
@@ -109,7 +158,7 @@ class TestMemoryReport:
         mine = [e for e in rep["kv_pools"]
                 if e["pool"] == "resident-t"]
         assert len(mine) == 1
-        # [n_layers, blocks, block, heads, head_dim] f32, k + v
+        # [n_layers, blocks, block, heads * head_dim] f32, k + v
         expect = 2 * 4 * 4 * 2 * 8 * 4 * 2
         assert mine[0]["bytes"] == expect
         assert rep["kv_pool_bytes"] >= expect

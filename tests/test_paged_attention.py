@@ -21,9 +21,11 @@ FULL = BLOCK * MAX_BLOCKS
 
 
 def _pools(h, d, n_blocks, block, dtype, seed):
+    """One layer's K and V as the pool stores them: ``[n_blocks, block,
+    h * d]``, a token's heads side by side."""
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
-    k = jax.random.normal(kk, (n_blocks, block, h, d), jnp.float32)
-    v = jax.random.normal(kv, (n_blocks, block, h, d), jnp.float32)
+    k = jax.random.normal(kk, (n_blocks, block, h * d), jnp.float32)
+    v = jax.random.normal(kv, (n_blocks, block, h * d), jnp.float32)
     return kq, np.array(k.astype(dtype)), np.array(v.astype(dtype))
 
 
@@ -65,11 +67,12 @@ def _check(lens, *, h, d, block, max_blocks, dtype, seed=0):
     q = jax.random.normal(kq, (len(lens), h, d), jnp.float32)
     tables = _tables(lens, block, max_blocks, n_blocks, seed)
     lengths = jnp.asarray(lens, jnp.int32)
-    want = ap.paged_attention_reference(q, jnp.asarray(k), jnp.asarray(v),
-                                        jnp.asarray(tables), lengths)
+    want = ap.paged_attention_reference(
+        q, jnp.asarray(k)[None], jnp.asarray(v)[None],
+        jnp.asarray(tables), lengths)
     got = jax.jit(ap.paged_decode_attention)(
-        q, jnp.asarray(_poison(k, tables, lens)),
-        jnp.asarray(_poison(v, tables, lens)), jnp.asarray(tables),
+        q, jnp.asarray(_poison(k, tables, lens))[None],
+        jnp.asarray(_poison(v, tables, lens))[None], jnp.asarray(tables),
         lengths)
     got, want = np.asarray(got), np.asarray(want)
     assert got.shape == want.shape and got.dtype == want.dtype
@@ -122,6 +125,44 @@ def test_the_cells_shapes(h, d, dtype):
     assert ap._paged_blocks_per_step(16, h * d, 2, 21) == 16
     _check((330, 1, 17, 256, 1), h=h, d=d, block=16, max_blocks=21,
            dtype=dtype, seed=2)
+
+
+@pytest.mark.parametrize("layer", [0, 1])
+@pytest.mark.parametrize("h,h_kv", [(4, 4), (10, 2)], ids=["g1", "g5"])
+def test_a_layer_of_the_stacked_pool(three_blocks_a_step, h, h_kv, layer):
+    """The pool as ``KVBlockPool`` stores it, two layers stacked: the
+    kernel takes it whole and the layer as an index, reads that
+    layer's blocks only (the other layer is NaN throughout), ragged
+    lengths with dead rows on the scratch block between, as many KV
+    heads as query heads and five query heads a KV head."""
+    d, lens = 32, [41, 1, FULL, 1, BLOCK + 1, 1, 1]
+    n_blocks = 2 + sum(-(-n // BLOCK) for n in lens)
+    kq, k, v = _pools(h_kv, d, n_blocks, BLOCK, jnp.bfloat16, seed=4)
+    q = jax.random.normal(kq, (len(lens), h, d), jnp.float32)
+    tables = _tables(lens, BLOCK, MAX_BLOCKS, n_blocks, seed=4)
+    assert not tables[[1, 3, 5]].any() and tables[6, 0]   # scratch rows
+    lengths = jnp.asarray(lens, jnp.int32)
+
+    def stacked(pool):
+        both = np.full((2,) + pool.shape, np.nan, np.float32)
+        both[layer] = pool.astype(np.float32)
+        return jnp.asarray(both, jnp.bfloat16)
+    want = ap.paged_attention_reference(
+        q, stacked(k), stacked(v), jnp.asarray(tables), lengths, layer)
+    got = jax.jit(ap.paged_decode_attention)(
+        q, stacked(_poison(k, tables, lens)),
+        stacked(_poison(v, tables, lens)), jnp.asarray(tables), lengths,
+        layer)                      # the layer traced, as a model's is not
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.shape == want.shape == (len(lens), h, d)
+    assert np.all(np.isfinite(got)) and np.all(np.isfinite(want))
+    for i in range(len(lens)):
+        assert chip_smoke.rel_err(got[i], want[i]) \
+            <= chip_smoke.KERNEL_REL_TOL, (i, lens[i])
+    # one trace serves every layer: the index is an operand
+    again = ap.paged_decode_attention(
+        q, stacked(k), stacked(v), jnp.asarray(tables), lengths, layer)
+    np.testing.assert_array_equal(np.asarray(again), got)
 
 
 def test_blocks_per_step_follows_the_shapes():

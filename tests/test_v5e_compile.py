@@ -1,0 +1,132 @@
+"""The engine's commit and decode programs compiled for a described
+TPU v5e, at the benchmark's widths and pool sizes with the depth cut
+to two layers: what the chip's compiler does to the KV pool. No chip
+is attached and nothing runs; the compiler is the one the chip's
+machines have (on-chip-measurement guide, section 2).
+
+The pool is stored as the paged kernel reads it and donated, so the
+compiled programs must alias every cache input to an output and hold
+no ``copy``, ``transpose``, ``slice`` or ``reshape`` of a pool or of a
+layer of it: in ``gpt2-large.decode-offline`` those were 77 % of the
+device's time (PERF.md, PR 29).
+
+Every compile of this kind lives in this one file: only the worker
+that is given it loads the TPU's library.
+"""
+from __future__ import annotations
+
+import json
+import os
+import re
+from unittest import mock
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deeplearning4j_tpu.ops import kernel_select
+from deeplearning4j_tpu.serving.generative import DecodeEngine
+from deeplearning4j_tpu.serving.kvcache import KVBlockPool
+
+LAYERS = 2
+BUCKET, PROMPT = 32, 256
+_H1 = os.path.join(os.path.dirname(__file__), "..", "chipbench", "configs",
+                   "falcon-h1-34b.json")
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                          # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _engine(kind):
+    """An engine at the cell's widths over shapes alone: abstract
+    weights, a pool of numpy zeros that is never placed."""
+    if kind == "gpt2-large":
+        from deeplearning4j_tpu.models.decoder import (DecoderConfig,
+                                                       DecoderLM)
+        model = DecoderLM(DecoderConfig(
+            vocab_size=50257, n_layers=LAYERS, n_heads=20, d_model=1280,
+            d_ff=5120, max_len=1024, eos_id=50257))
+        params = jax.eval_shape(model.init)
+        pool = KVBlockPool(LAYERS, 385, 16, 20, 64, dtype=jnp.bfloat16,
+                           name="t-v5e-gpt2", device_arrays=False)
+    else:
+        from deeplearning4j_tpu.models.falcon_h1 import (FalconH1Config,
+                                                         FalconH1LM)
+        cfg = dict(json.load(open(_H1)), num_hidden_layers=LAYERS)
+        model = FalconH1LM(FalconH1Config.from_published(
+            cfg, max_len=cfg["max_len"], eos_id=cfg["vocab_size"]))
+        params = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, jnp.bfloat16 if a.ndim == 2 else a.dtype),
+            jax.eval_shape(model.init))
+        c = model.conf
+        pool = KVBlockPool(LAYERS, 3073, 16, c.n_kv_heads, c.head_dim,
+                           dtype=jnp.bfloat16, name="t-v5e-h1",
+                           state=model.state_shapes(), state_slots=33,
+                           device_arrays=False)
+    eng = DecodeEngine(model, params, pool, name=pool.name,
+                       prompt_buckets=(PROMPT,), decode_buckets=(BUCKET,),
+                       max_seq_len=1024, paged=True)
+    return model, pool, eng
+
+
+def _program(kind, program, one_chip):
+    """The pool, the jitted commit or decode program and its
+    arguments, every one a shape on the described chip."""
+    model, pool, eng = _engine(kind)
+    i32 = np.int32
+    b = BUCKET
+    if program == "commit":
+        new = jax.eval_shape(
+            model.prefill, eng.params, np.zeros((1, PROMPT), i32),
+            np.ones((1,), i32))[1:]
+        jit = eng._commit_jit()
+        args = (pool.arrays, tuple(new),
+                np.zeros((pool.blocks_for(PROMPT),), i32),
+                *eng._state_arg(i32(0)))
+    else:
+        jit = eng._decode_jit()
+        args = (eng.params, pool.arrays, np.zeros((b,), i32),
+                np.zeros((b,), i32), np.zeros((b, eng.max_blocks), i32),
+                np.zeros((2,), np.uint32), np.zeros((b,), np.float32),
+                np.zeros((b,), i32),
+                *eng._state_arg(np.zeros((b,), i32)))
+    return pool, jit, jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=one_chip), args)
+
+
+@pytest.mark.parametrize("program", ["commit", "decode"])
+@pytest.mark.parametrize("kind", ["gpt2-large", "falcon-h1-34b"])
+def test_the_pool_is_written_in_place_and_never_relaid(one_chip, kind,
+                                                       program):
+    pool, jit, args = _program(kind, program, one_chip)
+    with mock.patch.object(kernel_select, "interpret_mode", lambda: False), \
+            mock.patch.object(kernel_select, "platform", lambda: "tpu"):
+        compiled = jit.lower(*args).compile()
+    # every cache input is an output's buffer
+    assert compiled.memory_analysis().alias_size_in_bytes == \
+        pool.pool_bytes + pool.state_bytes
+    text = compiled.as_text()
+    dims = ",".join(str(n) for n in pool.k.shape)        # the whole pool
+    layer = ",".join(str(n) for n in pool.k.shape[1:])   # one layer of it
+    moved = [m.group(0) for m in re.finditer(
+        r"= bf16\[(?:%s|%s)\]\S* (copy|transpose|slice|reshape|"
+        r"dynamic-slice)\(" % (re.escape(dims), re.escape(layer)), text)]
+    assert not moved, moved[:4]
+    if program == "decode":
+        # the paged kernel a layer on the stacked pool (and the state
+        # kernel a layer where the model has recurrent state)
+        kernels = text.count("custom_call_target=\"tpu_custom_call\"")
+        assert kernels == LAYERS * (2 if pool.state else 1)
