@@ -331,7 +331,7 @@ class SameDiff:
         self.training_config = None
         self._updater_state = None
         #: DpFlatSpec of the fsdp fit_steps window (parallel.zero);
-        #: set by _build_raw_train_step(fsdp=True)
+        #: set by _build_raw_train_step under the fsdp exchange
         self._fsdp_spec = None
         #: updater iteration, persisted across fit()/fit_steps() calls
         #: (Adam bias correction must not restart per call)
@@ -1107,9 +1107,14 @@ class SameDiff:
 
     def _build_raw_train_step(self, ph_names: Tuple[str, ...],
                               mesh=None, axis: str = "data",
-                              fsdp: bool = False, tp_specs=None,
-                              dense_tail: bool = False,
+                              mode="dense", tp_specs=None,
                               encoding=None):
+        """The unjitted train step over one flat tree of variables:
+        loss and gradients here, the update tail for the resolved
+        ``UpdateExchange`` ``mode`` in ``parallel.zero.apply_update``
+        (the whole variable tree is its one entry)."""
+        from deeplearning4j_tpu.parallel import zero
+        mode = zero.UpdateExchange(mode)
         cfg = self.training_config
         fn, var_names = self._build_fn(tuple(self.loss_variables),
                                        ph_names, True)
@@ -1123,9 +1128,7 @@ class SameDiff:
                 # 2D mode: pin tp variables to their compute spec; the
                 # custom-vjp pin sends the cotangent to the resident
                 # spec, so dp grad collectives stay on the data axis
-                from deeplearning4j_tpu.parallel.zero import \
-                    pin_tp_entry
-                tv = pin_tp_entry(tv, mesh, tp_specs)
+                tv = zero.pin_tp_entry(tv, mesh, tp_specs)
             outs = fn(tv, ph_vals, rng)
             total = sum(jnp.sum(o) for o in outs)
             if cfg.l2:
@@ -1136,7 +1139,8 @@ class SameDiff:
                     jnp.sum(jnp.abs(v)) for v in tv.values())
             return total
 
-        if fsdp:
+        loss_of = dense_loss
+        if mode is zero.UpdateExchange.FSDP:
             # ZeRO-3: var_vals travel as the single flat shard dict
             # ({FSDP_KEY: {dtype: flat}}, resident 1/N along the data
             # axis); the forward gathers them through the custom-vjp
@@ -1147,91 +1151,31 @@ class SameDiff:
             # resident-sharded over model(×data) via their specs
             from deeplearning4j_tpu.learning.updaters import (
                 FSDP_KEY, TP_KEY, dp_flatten_spec)
-            from deeplearning4j_tpu.parallel.zero import (
-                apply_update_fsdp, apply_update_tp, fsdp_gather,
-                merge_tp_state, split_tp_state)
             spec = dp_flatten_spec(
                 {n: self._arrays[n] for n in trainable
                  if n not in tp_specs},
                 mesh.shape[axis])
             self._fsdp_spec = spec
 
-            def fsdp_step(var_vals, upd_state, ph_vals, iteration, rng):
-                def loss_fn(fv):
-                    tv = fsdp_gather(fv[FSDP_KEY], spec, mesh, axis)
-                    if tp_specs:
-                        # dense_loss pins these to the compute spec
-                        tv = {**tv, **fv[TP_KEY]}
-                    return dense_loss(tv, ph_vals, rng)
-
-                loss, grads = jax.value_and_grad(loss_fn)(var_vals)
-                st_rest, st_tp = split_tp_state(upd_state)
-                new_flat, new_state = apply_update_fsdp(
-                    updater, grads[FSDP_KEY], var_vals[FSDP_KEY],
-                    st_rest, iteration, mesh, axis)
-                new_vars = {FSDP_KEY: new_flat}
+            def loss_of(fv, ph_vals, rng):
+                tv = zero.fsdp_gather(fv[FSDP_KEY], spec, mesh, axis)
                 if tp_specs:
-                    new_tp, us_tp = apply_update_tp(
-                        updater, grads[TP_KEY], var_vals[TP_KEY],
-                        st_tp, iteration, mesh, tp_specs,
-                        gather_params=False)
-                    new_vars[TP_KEY] = new_tp
-                    new_state = merge_tp_state(new_state, us_tp)
-                return new_vars, new_state, loss
-
-            return fsdp_step, trainable
+                    # dense_loss pins these to the compute spec
+                    tv = {**tv, **fv[TP_KEY]}
+                return dense_loss(tv, ph_vals, rng)
 
         def step(var_vals, upd_state, ph_vals, iteration, rng):
             loss, grads = jax.value_and_grad(
-                lambda tv: dense_loss(tv, ph_vals, rng))(var_vals)
-            if mesh is not None and not dense_tail:
-                # ZeRO-1 sharded tail (parallel.zero): updater + state
-                # on 1/N shards; new_vars come back replicated and in
-                # each variable's own dtype. Tensor-parallel variables
-                # get their own elementwise tail (apply_update_tp)
-                # pinned to the model-axis layout
-                from deeplearning4j_tpu.parallel.zero import (
-                    apply_update_encoded, apply_update_sharded,
-                    apply_update_tp, merge_tp_state, split_tp_entry,
-                    split_tp_state)
-                if encoding is not None:
-                    # encoded rung: compress the flat dp gradient
-                    # before the collective (error-feedback state under
-                    # ENCODED_KEY); tp leaves below keep the
-                    # uncompressed elementwise tail
-                    import functools as _ft
-                    apply_dp = _ft.partial(apply_update_encoded,
-                                           encoding=encoding)
-                else:
-                    apply_dp = apply_update_sharded
-                if tp_specs:
-                    g_rest, g_tp = split_tp_entry(grads, tp_specs)
-                    p_rest, p_tp = split_tp_entry(var_vals, tp_specs)
-                    st_rest, st_tp = split_tp_state(upd_state)
-                    if g_rest:
-                        new_rest, new_state = apply_dp(
-                            updater, g_rest, p_rest, st_rest,
-                            iteration, mesh, axis)
-                    else:
-                        new_rest, new_state = p_rest, st_rest
-                    new_tp, us_tp = apply_update_tp(
-                        updater, g_tp, p_tp, st_tp, iteration, mesh,
-                        tp_specs, gather_params=True)
-                    return ({**new_rest, **new_tp},
-                            merge_tp_state(new_state, us_tp), loss)
-                new_vars, new_state = apply_dp(
-                    updater, grads, var_vals, upd_state, iteration,
-                    mesh, axis)
-                return new_vars, new_state, loss
-            updates, new_state = updater.apply(grads, upd_state,
-                                               iteration)
+                lambda vv: loss_of(vv, ph_vals, rng))(var_vals)
             # updater math (bias corrections etc.) may run in f32;
-            # apply it at full precision, then keep each variable's
-            # own dtype — without the cast, bf16 variables silently
-            # promote to f32 after one step (and recompile the step)
-            new_vars = jax.tree_util.tree_map(
-                lambda p, u: (p - u).astype(p.dtype),
-                var_vals, updates)
+            # keep_dtype applies it at full precision, then keeps each
+            # variable's own dtype — without the cast, bf16 variables
+            # silently promote to f32 after one step (and recompile
+            # the step)
+            new_vars, new_state = zero.apply_update(
+                updater, grads, var_vals, upd_state, iteration,
+                mesh=mesh, axis=axis, mode=mode, tp_specs=tp_specs,
+                encoding=encoding, keep_dtype=True)
             return new_vars, new_state, loss
 
         return step, trainable
@@ -1316,11 +1260,7 @@ class SameDiff:
         cached = self._exec_cache.get(("train_multi", key))
         if cached is None:
             raw, trainable = self._build_raw_train_step(
-                tuple(ph_vals),
-                mesh if (sharded or fsdp or encoded or tp_specs)
-                else None,
-                fsdp=fsdp, tp_specs=tp_specs,
-                dense_tail=not (sharded or fsdp or encoded),
+                tuple(ph_vals), mesh, mode=mode, tp_specs=tp_specs,
                 encoding=encoding)
 
             def multi(var_vals, upd_state, ph, rng, it0, n):

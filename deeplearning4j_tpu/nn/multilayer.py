@@ -28,7 +28,7 @@ from deeplearning4j_tpu.nn.conf.constraints import apply_constraints
 from deeplearning4j_tpu.nn.conf.builders import (BackpropType,
                                                  MultiLayerConfiguration)
 from deeplearning4j_tpu.nn.conf.layers import BaseOutputLayer
-from deeplearning4j_tpu.nn.gradient import apply_gradient_normalization
+from deeplearning4j_tpu.nn.ladder import TrainingLadder
 from deeplearning4j_tpu.ops import kernel_select
 from deeplearning4j_tpu.optimize.listeners import TrainingListener
 
@@ -45,7 +45,7 @@ def _as_jnp(x, dtype=None):
     return arr
 
 
-class MultiLayerNetwork:
+class MultiLayerNetwork(TrainingLadder):
     def __init__(self, conf: MultiLayerConfiguration):
         self.conf = conf
         self.params: dict = {}
@@ -57,38 +57,10 @@ class MultiLayerNetwork:
         self.last_batch_size = 0
         self._score = float("nan")
         self._rng = jax.random.PRNGKey(conf.seed)
-        self._train_step = None
-        self._step_gnorm = False    # step emits a real grad norm
         self._initialized = False
         self._dtype = to_jnp_dtype(conf.dtype)
         self._retrace_guard = None
-        # ZeRO-1 sharded update (parallel.zero): when a dp mesh is
-        # installed the step tail runs the updater on 1/N param shards
-        self._dp_mesh = None
-        self._dp_axis = "data"
-        # full FSDP / ZeRO-3 (parallel.zero): params live as 1/N flat
-        # shards ({FSDP_KEY: {dtype: flat}} per layer), gathered
-        # per-layer just-in-time in the forward; _fsdp_specs keeps the
-        # per-layer DpFlatSpec needed to densify
-        self._dp_fsdp = False
-        self._fsdp_specs = {}
-        # dense update tail WITH a mesh installed (dense x tp 2D mode:
-        # the step needs the mesh for tp pins but must not run ZeRO-1)
-        self._dp_dense = False
-        # encoded update exchange (parallel.encoding): the ZeRO-1 tail
-        # with the flat gradient compressed before the data-axis
-        # collective; _dp_encoding holds the static EncodingSpec
-        self._dp_encoded = False
-        self._dp_encoding = None
-        # tensor parallelism (parallel.speclayout): per-layer
-        # {name: TpLeafSpec} for model-axis sharded leaves
-        self._tp_model_axis = None
-        self._tp_specs = {}
-        # gradient accumulation (reference: GradientsAccumulator)
-        self._accum_steps = 1
-        self._accum_grads = None
-        self._accum_count = 0
-        self._updates_applied = 0
+        self._init_ladder()
 
     # ------------------------------------------------------------------
     def init(self) -> "MultiLayerNetwork":
@@ -292,46 +264,15 @@ class MultiLayerNetwork:
         conf = self.conf
         out_layer = self.output_layer_conf
         want_logits = out_layer.wants_logits()
-        updaters = [(layer.updater or conf.updater)
-                    for layer in conf.layers]
-
-        gn = conf.gradient_normalization
-        thr = conf.gradient_normalization_threshold
-        dp_mesh, dp_axis = self._dp_mesh, self._dp_axis
-        fsdp = self._dp_fsdp and dp_mesh is not None
-        dense_tail = self._dp_dense and dp_mesh is not None
-        encoded = self._dp_encoded and dp_mesh is not None
-        encoding = self._dp_encoding if encoded else None
-        tp_specs_all = (dict(self._tp_specs)
-                        if dp_mesh is not None and self._tp_specs else {})
-        if fsdp:
-            from deeplearning4j_tpu.common.environment import Environment
-            from deeplearning4j_tpu.parallel.zero import FsdpParamView
-            fsdp_specs = dict(self._fsdp_specs)
-            fsdp_prefetch = Environment.get().fsdp_prefetch
-            layer_order = [f"layer_{i}" for i in range(len(conf.layers))]
+        layers = {f"layer_{i}": layer
+                  for i, layer in enumerate(conf.layers)}
+        view = self._param_view(layers)
 
         def loss_fn(params, states, x, y, fmask, lmask, rng):
             # fmask: per-timestep features mask (recurrent/pooling hold);
             # lmask: labels mask (loss exclusion) — distinct, as in the
             # reference (featuresMaskArray vs labelsMaskArray)
-            if fsdp:
-                # lazy view over the 1/N flat shards: each layer's
-                # all-gather is emitted at its point of use in the walk
-                params = FsdpParamView(params, fsdp_specs, dp_mesh,
-                                       dp_axis, order=layer_order,
-                                       prefetch=fsdp_prefetch,
-                                       tp_specs=tp_specs_all)
-            elif tp_specs_all:
-                # 2D mode: pin tp leaves to their compute spec; the
-                # custom-vjp pin sends the cotangent to the resident
-                # spec, so dp grad collectives stay on the data axis
-                from deeplearning4j_tpu.parallel.zero import pin_tp_entry
-                params = {k: (pin_tp_entry(sub, dp_mesh,
-                                           tp_specs_all[k])
-                              if k in tp_specs_all and
-                              isinstance(sub, dict) else sub)
-                          for k, sub in params.items()}
+            params = view(params)
             out, new_states = self._forward(params, states, x,
                                             training=True, rng=rng,
                                             want_logits=True, mask=fmask)
@@ -344,350 +285,7 @@ class MultiLayerNetwork:
                 return (data_loss + self._regularization(params),
                         new_states)
 
-        # numerics watchdog (common.diagnostics): when armed, the step
-        # also emits the global grad norm — computed in-jit, fused into
-        # the backward, so the host check is one extra scalar read.
-        # When off it is a free zeros constant and XLA dead-code
-        # eliminates the reduction; the step keeps ONE output shape.
-        from deeplearning4j_tpu.common.diagnostics import watchdog_enabled
-        want_gnorm = watchdog_enabled()
-        self._step_gnorm = want_gnorm
-
-        def grad_norm(grads):
-            if not want_gnorm:
-                return jnp.zeros((), jnp.float32)
-            sq = [jnp.sum(jnp.square(g.astype(jnp.float32)))
-                  for g in jax.tree_util.tree_leaves(grads)]
-            return jnp.sqrt(sum(sq)) if sq else jnp.zeros((),
-                                                          jnp.float32)
-
-        def update_tail(params, upd_states, grads, iteration):
-            """Grads -> (new_params, new_upd). Shared by the fused step
-            and the accumulation apply step. With a dp mesh installed
-            (and not dense×tp) the updater runs ZeRO-1 sharded
-            (parallel.zero; the resolver guarantees
-            gradient_normalization NONE there, so skipping it is
-            exact). Under fsdp params/grads are already the 1/N flat
-            shards and stay that way — no trailing all-gather
-            (constraints skipped: the resolver refuses fsdp when any
-            layer has them). Tensor-parallel leaves (tp_specs) never
-            enter the dp flats: they get their own elementwise tail
-            (apply_update_tp) pinned to the model-axis layout. Under
-            the encoded rung the same structure swaps in
-            apply_update_encoded — flat gradient compressed (with
-            error-feedback residual carried in ENCODED_KEY state)
-            before the data-axis collective; tp leaves keep their
-            uncompressed elementwise tail."""
-            new_params, new_upd = {}, {}
-            for i, up in enumerate(updaters):
-                k = f"layer_{i}"
-                g = grads.get(k, {})
-                if not g:
-                    new_params[k] = params.get(k, {})
-                    new_upd[k] = upd_states.get(k, ())
-                    continue
-                tps = tp_specs_all.get(k)
-                if fsdp:
-                    from deeplearning4j_tpu.learning.updaters import \
-                        FSDP_KEY, TP_KEY
-                    from deeplearning4j_tpu.parallel.zero import (
-                        apply_update_fsdp, apply_update_tp,
-                        merge_tp_state, split_tp_state)
-                    st_rest, st_tp = split_tp_state(upd_states[k])
-                    new_flat, us = apply_update_fsdp(
-                        up, g[FSDP_KEY], params[k][FSDP_KEY],
-                        st_rest, iteration, dp_mesh, dp_axis)
-                    ent = {FSDP_KEY: new_flat}
-                    if tps and TP_KEY in g:
-                        new_tp, us_tp = apply_update_tp(
-                            up, g[TP_KEY], params[k][TP_KEY], st_tp,
-                            iteration, dp_mesh, tps,
-                            gather_params=False)
-                        ent[TP_KEY] = new_tp
-                        us = merge_tp_state(us, us_tp)
-                    new_params[k] = ent
-                    new_upd[k] = us
-                    continue
-                if dp_mesh is not None and not dense_tail:
-                    import functools as _ft
-
-                    from deeplearning4j_tpu.parallel.zero import (
-                        apply_update_encoded, apply_update_sharded,
-                        apply_update_tp, merge_tp_state,
-                        split_tp_entry, split_tp_state)
-                    apply_dp = (_ft.partial(apply_update_encoded,
-                                            encoding=encoding)
-                                if encoded else apply_update_sharded)
-                    if tps:
-                        g_rest, g_tp = split_tp_entry(g, tps)
-                        p_rest, p_tp = split_tp_entry(params[k], tps)
-                        st_rest, st_tp = split_tp_state(upd_states[k])
-                        if g_rest:
-                            new_rest, us = apply_dp(
-                                up, g_rest, p_rest, st_rest,
-                                iteration, dp_mesh, dp_axis)
-                        else:
-                            new_rest, us = p_rest, st_rest
-                        new_tp, us_tp = apply_update_tp(
-                            up, g_tp, p_tp, st_tp, iteration,
-                            dp_mesh, tps, gather_params=True)
-                        new_p = {**new_rest, **new_tp}
-                        us = merge_tp_state(us, us_tp)
-                    else:
-                        new_p, us = apply_dp(
-                            up, g, params[k], upd_states[k], iteration,
-                            dp_mesh, dp_axis)
-                else:
-                    g = apply_gradient_normalization(gn, thr, g)
-                    updates, us = up.apply(g, upd_states[k], iteration)
-                    new_p = jax.tree_util.tree_map(
-                        lambda p, u: p - u, params[k], updates)
-                # post-update projection (reference: constraints are
-                # applied after the updater, inside the same step)
-                new_params[k] = apply_constraints(conf.layers[i], new_p)
-                new_upd[k] = us
-            return new_params, new_upd
-
-        def step(params, states, upd_states, x, y, fmask, lmask,
-                 iteration, rng):
-            (loss, new_states), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params, states, x, y, fmask,
-                                       lmask, rng)
-            gnorm = grad_norm(grads)
-            # attribution scope: the updater sweep reads/writes every
-            # parameter — substantial byte traffic that is not any
-            # layer's compute
-            with layerprof.scope("optimizer"):
-                new_params, new_upd = update_tail(params, upd_states,
-                                                  grads, iteration)
-            return new_params, new_states, new_upd, loss, gnorm
-
-        def grad_step(params, states, x, y, fmask, lmask, rng):
-            # accumulation micro-step: backward only, no update (params
-            # NOT donated — the apply step still reads them)
-            (loss, new_states), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params, states, x, y, fmask,
-                                       lmask, rng)
-            return grads, new_states, loss, grad_norm(grads)
-
-        def apply_step(params, upd_states, grads, scale, iteration):
-            grads = jax.tree_util.tree_map(lambda g: g * scale, grads)
-            with layerprof.scope("optimizer"):
-                new_params, new_upd = update_tail(params, upd_states,
-                                                  grads, iteration)
-            return new_params, new_upd
-
-        # donate params/states/updater-state buffers: XLA reuses them
-        # in place of the reference's workspaces
-        self._step_fn = step        # unjitted (multi-step path reuses)
-        self._train_step = jax.jit(step, donate_argnums=(0, 1, 2))
-        self._grad_step = jax.jit(grad_step, donate_argnums=(1,))
-        self._apply_step = jax.jit(apply_step, donate_argnums=(1, 2))
-        self._accum_add = jax.jit(
-            lambda acc, g: jax.tree_util.tree_map(
-                lambda a, b: a + b, acc, g),
-            donate_argnums=(0,))
-
-    # ------------------------------------------------------------------
-    def set_dp_mesh(self, mesh, axis: str = "data", mode=None, *,
-                    model_axis=None, tp_specs=None, encoding=None):
-        """Install (or clear, with ``mesh=None``) the (possibly 2D)
-        mesh the jitted step tail specializes on (``parallel.zero``).
-        ``mode="fsdp"`` selects the ZeRO-3 tail: params convert to the
-        1/N flat resident layout here (the model owns both param and
-        updater-state conversion under fsdp); ``mode="dense"`` installs
-        the mesh WITHOUT the ZeRO-1 tail (dense×tp: the step needs the
-        mesh for tensor-parallel pins only); ``mode="encoded"`` selects
-        the compressed-collective tail (``encoding=`` takes an
-        ``EncodingSpec`` or scheme string; the ENCODED_KEY
-        error-feedback state is injected at the next layout sync); for
-        the ZeRO-1 tail callers still own converting/placing
-        ``updater_states``. ``model_axis``/``tp_specs``
-        (``parallel.speclayout``) add the tensor-parallel dimension:
-        spec'd leaves pin to the model axis in-step and never enter
-        the dp flats. Invalidates compiled steps."""
-        mode_s = str(getattr(mode, "value", mode) or "").lower()
-        fsdp = mode_s == "fsdp" and mesh is not None
-        dense = mode_s == "dense" and mesh is not None
-        encoded = mode_s == "encoded" and mesh is not None
-        if encoded:
-            from deeplearning4j_tpu.parallel.encoding import \
-                resolve_encoding
-            encoding = resolve_encoding(encoding)
-        else:
-            encoding = None
-        tp_specs = dict(tp_specs or {}) if mesh is not None else {}
-        model_axis = model_axis if tp_specs else None
-        if mesh is self._dp_mesh and axis == self._dp_axis and \
-                fsdp == self._dp_fsdp and dense == self._dp_dense and \
-                encoded == self._dp_encoded and \
-                encoding == self._dp_encoding and \
-                model_axis == self._tp_model_axis and \
-                tp_specs == self._tp_specs:
-            return self
-        self.flush_accumulated()
-        self._dp_mesh = mesh
-        self._dp_axis = axis
-        self._dp_fsdp = fsdp
-        self._dp_dense = dense
-        self._dp_encoded = encoded
-        self._dp_encoding = encoding
-        self._tp_model_axis = model_axis
-        self._tp_specs = tp_specs
-        self._train_step = None
-        self._step_fn = None
-        self._grad_step = None
-        self._apply_step = None
-        self._accum_add = None
-        if hasattr(self, "_multi_steps"):
-            del self._multi_steps
-        self._sync_param_layout()
-        return self
-
-    def set_accumulation_steps(self, n: int):
-        """Apply the updater once every ``n`` fit() micro-batches on the
-        mean of their gradients (the reference's GradientsAccumulator):
-        effective batch = n x micro-batch with no extra activation HBM."""
-        n = max(int(n), 1)
-        if n != self._accum_steps:
-            self.flush_accumulated()
-            self._accum_steps = n
-        return self
-
-    def flush_accumulated(self):
-        """Apply a partial accumulation window now (epoch end / mode
-        change); no-op when nothing is pending."""
-        if self._accum_count:
-            self._apply_accumulated()
-        return self
-
-    def _apply_accumulated(self):
-        k = self._accum_count
-        scale = jnp.asarray(1.0 / k, jnp.float32)
-        self.params, self.updater_states = self._apply_step(
-            self.params, self.updater_states, self._accum_grads, scale,
-            jnp.asarray(self._updates_applied))
-        self._accum_grads = None
-        self._accum_count = 0
-        self._updates_applied += 1
-
-    def _sync_updater_layout(self):
-        """A checkpoint restored from a ZeRO-1 run carries flat sharded
-        updater state; on a plain (no-mesh) model — or under the
-        dense×tp tail, which consumes dense state — convert it back to
-        the dense per-layer layout before stepping (ENCODED_KEY
-        error-feedback state is stripped there: the residual belongs
-        to the compressed exchange). Under ``mode="encoded"`` the
-        inverse sync runs: entries missing their ENCODED_KEY state
-        (first fit, or a dense/sharded checkpoint restored into an
-        encoded run — on any device count) get it injected and placed."""
-        if self._dp_mesh is not None and not self._dp_dense:
-            if self._dp_encoded:
-                from deeplearning4j_tpu.parallel.zero import (
-                    ensure_encoded_states, place_updater_states)
-                n = self._dp_mesh.shape[self._dp_axis]
-                states = self.updater_states
-                new = ensure_encoded_states(
-                    self.dense_params() if self._params_are_fsdp()
-                    else self.params,
-                    states, n, self._dp_encoding,
-                    tp_specs=self._tp_specs)
-                if any(new[k] is not states.get(k) for k in new):
-                    self.updater_states = place_updater_states(
-                        self._dp_mesh, new, self._dp_axis,
-                        tp_specs=self._tp_specs)
-            return
-        from deeplearning4j_tpu.learning.updaters import (has_tp,
-                                                          is_dp_sharded,
-                                                          is_encoded)
-        if any(is_dp_sharded(s) or has_tp(s) or is_encoded(s)
-               for s in self.updater_states.values()):
-            from deeplearning4j_tpu.parallel.zero import (
-                states_to_dense, strip_encoded_states)
-            self.updater_states = strip_encoded_states(
-                states_to_dense(self.params, self.updater_states))
-
-    def _params_are_fsdp(self) -> bool:
-        from deeplearning4j_tpu.learning.updaters import is_fsdp
-        return any(is_fsdp(p) for p in self.params.values()
-                   if isinstance(p, dict))
-
-    def _sync_param_layout(self):
-        """Enter/leave the fsdp flat resident param layout
-        (parallel.zero). Entering converts updater state to the ZeRO-1
-        flat layout too (the fsdp tail consumes it) and places both at
-        1/N per replica; leaving densifies params (gather timed into
-        ``dl4j_fsdp_gather_seconds``).  Elastic re-mesh: flats resident
-        for a DIFFERENT world size (resume onto a new mesh) round-trip
-        through the dense layout and re-enter — params via
-        ``params_to_dense`` -> ``place_fsdp_params``, updater state via
-        its ``DpFlatSpec`` re-ravel inside ``states_to_sharded``."""
-        flat = self._params_are_fsdp()
-        if self._dp_fsdp and self._dp_mesh is not None:
-            from deeplearning4j_tpu.parallel.zero import (
-                fsdp_spec_shards, params_to_fsdp, place_fsdp_params,
-                place_updater_states, states_to_sharded)
-            n = self._dp_mesh.shape[self._dp_axis]
-            if flat:
-                if fsdp_spec_shards(self._fsdp_specs) == n and \
-                        self._tp_layout_matches():
-                    # already resident; placement happened on entry
-                    return
-                # raveled for another world size (or another tp
-                # partition): densify and re-enter
-                self._densify_params_inplace()
-            self.updater_states = states_to_sharded(
-                self.params, self.updater_states, n,
-                tp_specs=self._tp_specs)
-            self.params, self._fsdp_specs = params_to_fsdp(
-                self.params, n, tp_specs=self._tp_specs)
-            self.params = place_fsdp_params(self._dp_mesh, self.params,
-                                            self._dp_axis,
-                                            tp_specs=self._tp_specs)
-            self.updater_states = place_updater_states(
-                self._dp_mesh, self.updater_states, self._dp_axis,
-                tp_specs=self._tp_specs)
-        elif flat:
-            self._densify_params_inplace()
-
-    def _tp_layout_matches(self) -> bool:
-        """True when the resident fsdp entries' TP_KEY split matches
-        the installed tp specs (an fsdp×tp checkpoint restored onto a
-        mesh with a different tp degree must densify and re-enter)."""
-        from deeplearning4j_tpu.learning.updaters import TP_KEY, is_fsdp
-        want = {k: set(v) for k, v in (self._tp_specs or {}).items()}
-        for k, sub in self.params.items():
-            if not isinstance(sub, dict) or not is_fsdp(sub):
-                continue
-            got = set(sub.get(TP_KEY, {}))
-            if got != want.get(k, set()):
-                return False
-        return True
-
-    def _densify_params_inplace(self):
-        if self._params_are_fsdp():
-            from deeplearning4j_tpu.parallel.zero import (on_2d_mesh,
-                                                          params_to_dense)
-            self.params = params_to_dense(self.params, self._fsdp_specs)
-            # specs kept: a later _sync_param_layout re-entry recomputes
-            if any(on_2d_mesh(a)
-                   for a in jax.tree_util.tree_leaves(self.params)):
-                # leaving a 2D (data, model) residency: the densified
-                # leaves still carry the old mesh's shardings, and
-                # re-raveling them through XLA SPMD hits the same
-                # concatenate-lowering bug worked around in
-                # zero.apply_update_sharded — re-enter from host copies
-                self.params = jax.device_get(self.params)
-                self.updater_states = jax.device_get(self.updater_states)
-
-    def dense_params(self) -> dict:
-        """Params in the dense per-layer layout regardless of residency
-        (non-mutating; under fsdp this is a full host-side all-gather —
-        checkpoint/inference/introspection consumers only)."""
-        if not self._params_are_fsdp():
-            return self.params
-        from deeplearning4j_tpu.parallel.zero import params_to_dense
-        return params_to_dense(self.params, self._fsdp_specs)
+        self._build_steps(loss_fn, layers)
 
     # ------------------------------------------------------------------
     @kernel_select.marks_partitions

@@ -607,8 +607,9 @@ class PipelineTrainer:
     the only per-(stage, microbatch) residency is the stage *input*
     stash — exactly what :func:`peak_residency` bounds.
 
-    Each stage applies its own update tail (dense or per-stage ZeRO-1,
-    with tp pinning when stage specs exist), so updater flats stay
+    Each stage applies its own update tail (``zero.apply_update``,
+    dense or per-stage ZeRO-1, with the tp split when stage specs
+    exist), so updater flats stay
     local to the stage's pipe group (``parallel/zero.py``). Microbatch
     grads are summed and scaled by ``1/M`` — with mean losses this is
     bit-for-tolerance the full-batch gradient, which is what makes the
@@ -725,27 +726,20 @@ class PipelineTrainer:
             return lambda p: p
         from deeplearning4j_tpu.parallel import zero
         sub = self.submeshes[s]
-
-        def pin(params):
-            return {k: (zero.pin_tp_entry(v, sub, specs[k])
-                        if k in specs and isinstance(v, dict) else v)
-                    for k, v in params.items()}
-        return pin
+        return lambda params: zero.pin_tp_params(params, sub, specs)
 
     def _make_apply(self, s: int):
+        from deeplearning4j_tpu.parallel import zero
         ad = self.adapter
         names = list(self.part.stage_entries(s))
         ups = {k: ad.updater_for(k) for k in names}
-        gn, thr = ad.gn_threshold()
+        normalization = ad.gn_threshold()
         sub = self.submeshes[s]
         specs_all = self._tp_specs[s]
-        tail = self._tail
+        mode = zero.UpdateExchange(self._tail)
         model = self.model
         data_axis = self.data_axis
         has_reg = ad.has_regularization(names)
-        from deeplearning4j_tpu.nn.gradient import \
-            apply_gradient_normalization
-        from deeplearning4j_tpu.parallel import zero
 
         def apply_fn(stage_params, upd_states, gsum, scale, iteration):
             g_all = jax.tree_util.tree_map(lambda a: a * scale, gsum)
@@ -765,36 +759,11 @@ class PipelineTrainer:
                     new_params[k] = p
                     new_upd[k] = upd_states.get(k, ())
                     continue
-                up = ups[k]
-                tps = specs_all.get(k)
-                if tail == "sharded":
-                    if tps:
-                        g_rest, g_tp = zero.split_tp_entry(g, tps)
-                        p_rest, p_tp = zero.split_tp_entry(p, tps)
-                        st_rest, st_tp = zero.split_tp_state(
-                            upd_states[k])
-                        if g_rest:
-                            n_rest, us = zero.apply_update_sharded(
-                                up, g_rest, p_rest, st_rest, iteration,
-                                sub, data_axis)
-                        else:
-                            n_rest, us = p_rest, st_rest
-                        n_tp, us_tp = zero.apply_update_tp(
-                            up, g_tp, p_tp, st_tp, iteration, sub,
-                            tps, gather_params=True)
-                        new_p = {**n_rest, **n_tp}
-                        us = zero.merge_tp_state(us, us_tp)
-                    else:
-                        new_p, us = zero.apply_update_sharded(
-                            up, g, p, upd_states[k], iteration, sub,
-                            data_axis)
-                else:
-                    g2 = apply_gradient_normalization(gn, thr, g)
-                    updates, us = up.apply(g2, upd_states[k], iteration)
-                    new_p = jax.tree_util.tree_map(
-                        lambda pp, uu: pp - uu, p, updates)
+                new_p, new_upd[k] = zero.apply_update(
+                    ups[k], g, p, upd_states[k], iteration, mesh=sub,
+                    axis=data_axis, mode=mode,
+                    tp_specs=specs_all.get(k), normalization=normalization)
                 new_params[k] = ad.constrain(k, new_p)
-                new_upd[k] = us
             return new_params, new_upd, reg
         return jax.jit(apply_fn)
 

@@ -45,6 +45,16 @@ class ParallelWrapper:
               .prefetch_buffer(2)
               .build())
         pw.fit(train_iterator)
+
+    The wrapper owns the mesh, the placement and the RESOLVED update
+    exchange (``zero.resolve_update_exchange``); it hands the model the
+    mesh and that one ``UpdateExchange`` value through ``set_dp_mesh``
+    and drives the model's own jitted step. What the step's tail does
+    with the mode — dense, ZeRO-1, encoded or fsdp, each times the
+    tensor-parallel split — is ``parallel.zero.apply_update``'s
+    decision, the same for every model class and for the
+    ``PipelineTrainer`` that takes over the fit path when the mesh has a
+    ``pipe`` axis.
     """
 
     #: reference TrainingMode values (accepted; all lower to the same
@@ -397,28 +407,16 @@ class ParallelWrapper:
                 m.params = replicate_tree(self.mesh, m.params)
             m.states = replicate_tree(self.mesh, m.states)
             if hasattr(m, "set_dp_mesh"):
-                if self._tp_specs:
-                    # the mesh must install even for the dense tail so
-                    # the step pins tp leaves (mode="dense" keeps the
-                    # dp-flat machinery out of the update)
-                    m.set_dp_mesh(
-                        self.mesh, self.data_axis,
-                        mode=("encoded"
-                              if mode is UpdateExchange.ENCODED
-                              else "sharded"
-                              if mode is UpdateExchange.SHARDED
-                              else "dense"),
-                        model_axis=self.model_axis,
-                        tp_specs=self._tp_specs,
-                        encoding=self.encoding)
-                elif mode is UpdateExchange.ENCODED:
-                    m.set_dp_mesh(self.mesh, self.data_axis,
-                                  mode="encoded",
-                                  encoding=self.encoding)
-                else:
-                    m.set_dp_mesh(self.mesh
-                                  if mode is UpdateExchange.SHARDED
-                                  else None, self.data_axis)
+                # with tp specs the mesh must install even for the
+                # dense tail, so the step pins the tp leaves
+                # (mode=DENSE keeps the dp-flat machinery out of the
+                # update)
+                m.set_dp_mesh(
+                    self.mesh if self._tp_specs
+                    or mode is not UpdateExchange.DENSE else None,
+                    self.data_axis, mode=mode,
+                    model_axis=self.model_axis,
+                    tp_specs=self._tp_specs, encoding=self.encoding)
         if hasattr(m, "set_accumulation_steps"):
             m.set_accumulation_steps(self.accumulation_steps)
         elif self.accumulation_steps > 1:

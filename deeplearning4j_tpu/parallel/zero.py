@@ -29,6 +29,17 @@ pinned to the shard (no trailing all-gather). Per-chip residency for
 params + grads + updater state drops to ~1/N; the wire total per step
 is unchanged (param AllGather + grad ReduceScatter = one AllReduce).
 
+The training ladder has ONE update tail, :func:`apply_update`, for one
+entry's gradients, parameters and updater state under a resolved
+:class:`UpdateExchange`: it owns the dispatch dense | sharded | encoded
+| fsdp, each times the tensor-parallel split and merge, over the
+per-mode tails below (``apply_update_sharded`` / ``_encoded`` /
+``_fsdp`` / ``_tp``). Its four callers are loops over entries:
+``nn.ladder.TrainingLadder`` (``MultiLayerNetwork`` and
+``ComputationGraph``), ``SameDiff``'s train step (its variable tree is
+one entry) and ``PipelineTrainer``'s per-stage apply. Post-update
+constraints stay with the caller — they are the model's.
+
 Kill switches: ``DL4J_TPU_SHARDED_UPDATE=0`` (common.environment)
 forces the dense tail everywhere, restoring the exact pre-ZeRO
 behavior; ``DL4J_TPU_FSDP=0`` demotes fsdp requests to ZeRO-1;
@@ -616,6 +627,14 @@ def pin_tp_entry(entry, mesh, specs):
     return out
 
 
+def pin_tp_params(params, mesh, tp_specs):
+    """:func:`pin_tp_entry` over a ``{entry: subtree}`` param tree;
+    entries without specs pass through."""
+    return {k: (pin_tp_entry(sub, mesh, tp_specs[k])
+                if k in tp_specs and isinstance(sub, dict) else sub)
+            for k, sub in params.items()}
+
+
 def split_tp_entry(entry, specs):
     """One dense entry -> (rest, tp) by spec'd names."""
     tp = {n: entry[n] for n in specs if n in entry}
@@ -681,6 +700,79 @@ def apply_update_tp(updater, grads, params, state, iteration, mesh,
                      "compute" if gather_params else "resident")
     new_state = pin(new_state, "resident")
     return new_params, new_state
+
+
+def apply_update(updater, grads, params, state, iteration, *, mesh=None,
+                 axis: str = DEFAULT_DATA_AXIS,
+                 mode: UpdateExchange = UpdateExchange.DENSE,
+                 tp_specs=None, encoding=None, normalization=None,
+                 keep_dtype: bool = False):
+    """The update tail of the training ladder for ONE entry (a layer, a
+    vertex, a pipeline stage's entry, or SameDiff's whole variable
+    tree), traced inside the caller's jit: which tail runs for which
+    ``mode``, and how the entry's tensor-parallel leaves (``tp_specs``,
+    ``{name: TpLeafSpec}``) split off it. Returns ``(new_params,
+    new_state)`` in the layout ``params``/``state`` came in.
+
+    - no ``mesh``, or DENSE (a mesh may still be installed: dense×tp
+      needs it for the forward's pins only): ``normalization`` —
+      ``(GradientNormalization, threshold)`` — on the gradients, the
+      updater on full tensors, ``p - u`` (cast back to each leaf's own
+      dtype under ``keep_dtype``: SameDiff's variables may be bf16
+      under an f32 updater, the network classes keep what the
+      subtraction gives);
+    - SHARDED / ENCODED: the dp flats' tail
+      (:func:`apply_update_sharded` / :func:`apply_update_encoded`) on
+      everything but the tp leaves, which never enter the flats and
+      take :func:`apply_update_tp` with the trailing data-axis gather.
+      The resolver guarantees gradient normalization NONE here, so
+      skipping it is exact;
+    - FSDP: ``params``/``grads`` are the entry's resident
+      ``{FSDP_KEY: flats[, TP_KEY: leaves]}`` and stay resident — no
+      trailing all-gather on either half.
+
+    Post-update constraints are the model's, not the exchange's: the
+    caller applies them to what this returns."""
+    mode = UpdateExchange(mode)
+    if mesh is None or mode is UpdateExchange.DENSE:
+        if normalization is not None:
+            from deeplearning4j_tpu.nn.gradient import \
+                apply_gradient_normalization
+            grads = apply_gradient_normalization(*normalization, grads)
+        updates, new_state = updater.apply(grads, state, iteration)
+        sub = ((lambda p, u: (p - u).astype(p.dtype)) if keep_dtype
+               else (lambda p, u: p - u))
+        return jax.tree_util.tree_map(sub, params, updates), new_state
+    if mode is UpdateExchange.FSDP:
+        st_rest, st_tp = split_tp_state(state)
+        new_flat, new_state = apply_update_fsdp(
+            updater, grads[FSDP_KEY], params[FSDP_KEY], st_rest,
+            iteration, mesh, axis)
+        new_params = {FSDP_KEY: new_flat}
+        if tp_specs and TP_KEY in grads:
+            new_params[TP_KEY], st_tp = apply_update_tp(
+                updater, grads[TP_KEY], params[TP_KEY], st_tp,
+                iteration, mesh, tp_specs, gather_params=False)
+            new_state = merge_tp_state(new_state, st_tp)
+        return new_params, new_state
+    apply_dp = (functools.partial(apply_update_encoded, encoding=encoding)
+                if mode is UpdateExchange.ENCODED
+                else apply_update_sharded)
+    if not tp_specs:
+        return apply_dp(updater, grads, params, state, iteration, mesh,
+                        axis)
+    g_rest, g_tp = split_tp_entry(grads, tp_specs)
+    p_rest, p_tp = split_tp_entry(params, tp_specs)
+    st_rest, st_tp = split_tp_state(state)
+    if g_rest:
+        new_rest, new_state = apply_dp(updater, g_rest, p_rest, st_rest,
+                                       iteration, mesh, axis)
+    else:
+        # a fully tensor-parallel entry has no dp flats to exchange
+        new_rest, new_state = p_rest, st_rest
+    new_tp, st_tp = apply_update_tp(updater, g_tp, p_tp, st_tp, iteration,
+                                    mesh, tp_specs, gather_params=True)
+    return {**new_rest, **new_tp}, merge_tp_state(new_state, st_tp)
 
 
 def place_tp_params(mesh, params, tp_specs, *, resident: bool = False):

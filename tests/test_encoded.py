@@ -6,8 +6,8 @@ Covers: encoded-vs-dense 20-step convergence under error feedback,
 the bitwise dense-layout checkpoint round-trip restored onto a
 DIFFERENT device count, the `DL4J_TPU_ENCODED_UPDATE` kill switch and
 resolver fallbacks, and `param_dtype="bf16"|"int8"` serving residency
-(resident bytes shrink; f32 stays bitwise, low-precision stays within
-tolerance).
+(resident bytes shrink; f32 stays the dense math to an ulp,
+low-precision stays within tolerance).
 """
 import numpy as np
 import pytest
@@ -77,9 +77,16 @@ def test_encoded_tracks_dense_convergence_20_steps():
     trajectory within 0.05 absolute of the uncompressed dense run's,
     and the encoded loss must actually descend."""
     batches = [_data(64, seed=i % 4) for i in range(20)]
-    # score on a training batch: a disjoint random-label probe set can
-    # legitimately rise while the fit loss falls
-    probe = _data(64, seed=0)
+    # score on the whole training set, all four batches of the cycle:
+    # a disjoint random-label probe set can legitimately rise while the
+    # fit loss falls, and so can ONE training batch — after step 19
+    # batch 0 was last fitted three steps ago and sits at the top of
+    # its cycle, above where step 0 left it, in the dense run too
+    # (1.0731 against 1.0603) while the set's mean has fallen at every
+    # step (1.1303 -> 1.0484)
+    def score(net):
+        return float(np.mean([net.score(b) for b in batches[:4]]))
+
     finals = {}
     for mode in ("dense", "encoded"):
         net = _mlp(Adam(0.01), seed=7)
@@ -89,13 +96,13 @@ def test_encoded_tracks_dense_convergence_20_steps():
         for ds in batches:
             pw.fit_batch(ds)
             if first is None:
-                first = float(net.score(probe))
-        finals[mode] = float(net.score(probe))
+                first = score(net)
+        finals[mode] = score(net)
+        assert finals[mode] < first, f"{mode} loss did not descend"
         if mode == "encoded":
             assert pw.update_exchange is UpdateExchange.ENCODED
             assert any(is_encoded(s)
                        for s in net.updater_states.values())
-            assert finals[mode] < first, "encoded loss did not descend"
     assert abs(finals["encoded"] - finals["dense"]) < 0.05, finals
 
 
@@ -228,8 +235,9 @@ def _serving_mlp(seed=42):
 def test_serving_param_dtype_shrinks_residency_within_tolerance(mode):
     """register(param_dtype=) acceptance: bf16 halves the resident
     param bytes and int8 cuts them to ~1/4 (+ scales), while outputs
-    stay bitwise for f32 and within float tolerance for the cast
-    storage dtypes."""
+    stay the dense math for f32 (to an ulp: the sharded forward is
+    another partitioning of it, see test_serving_sharded) and within
+    float tolerance for the cast storage dtypes."""
     from deeplearning4j_tpu.parallel.mesh import make_mesh
     from deeplearning4j_tpu.serving import ServingBatcher
     from deeplearning4j_tpu.serving.residency import \
@@ -246,7 +254,7 @@ def test_serving_param_dtype_shrinks_residency_within_tolerance(mode):
         out = b.submit(x).result(timeout=60)
         resident[pd] = resident_param_bytes(b._serve_params)
         if pd is None:
-            np.testing.assert_array_equal(out, ref)
+            np.testing.assert_allclose(out, ref, rtol=1e-6)
         else:
             np.testing.assert_allclose(out, ref, rtol=0.05, atol=0.02)
         b.shutdown()

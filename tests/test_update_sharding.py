@@ -17,6 +17,7 @@ import pytest
 from deeplearning4j_tpu.activations import Activation
 from deeplearning4j_tpu.datasets.dataset import DataSet
 from deeplearning4j_tpu.learning.updaters import (Adam, Nesterovs, Sgd,
+                                                  dp_flatten_spec,
                                                   dp_ravel, dp_unravel,
                                                   is_dp_sharded)
 from deeplearning4j_tpu.lossfunctions import LossFunction
@@ -125,6 +126,143 @@ def test_update_tail_sgd_bitwise_adam_tolerance():
                 assert shards[0].data.shape[0] == leaf.shape[0] // 8
         else:
             assert new_s == ()
+
+
+# -- the dispatch over mode and tp, isolated -------------------------------
+def _entry(rng, names):
+    shapes = {"W": (8, 16), "b": (16,), "g": (7,)}
+    return {n: jnp.asarray(rng.normal(size=shapes[n]), jnp.float32)
+            for n in names}
+
+
+_DISPATCH_CASES = [(mode, tp) for mode in ("dense", "sharded", "encoded",
+                                           "fsdp")
+                   for tp in (None, "mixed")] + \
+    [("sharded", "all_tp"), ("encoded", "all_tp")]
+
+
+@pytest.mark.parametrize("updater", [Sgd(0.1), Adam(0.01)],
+                         ids=["sgd", "adam"])
+@pytest.mark.parametrize("mode,tp", _DISPATCH_CASES)
+def test_apply_update_dispatch_matches_dense(mode, tp, updater):
+    """``zero.apply_update`` is the one place that decides which tail
+    runs for which mode and how tp leaves split off an entry: two steps
+    through it in every mode, with and without tp specs (``mixed``:
+    W/b tensor-parallel, g in the dp flats; ``all_tp``: no dp flats at
+    all, "no rest" keeps state), land on the plain dense updater's
+    params and state. The encoded tail is lossy by design — what it
+    must conserve is the error feedback: the gradient the updater saw
+    is ``g + residual_in - residual_out`` on the flats' leaves."""
+    from deeplearning4j_tpu.learning.updaters import ENCODED_KEY
+    from deeplearning4j_tpu.parallel.encoding import (
+        EncodingSpec, ResidualClippingPostProcessor)
+    from deeplearning4j_tpu.parallel.mesh import make_mesh
+    from deeplearning4j_tpu.parallel.speclayout import SpecLayout
+    from deeplearning4j_tpu.parallel import zero
+    mode = UpdateExchange(mode)
+    rng = np.random.default_rng(3)
+    names = ("W", "b") if tp == "all_tp" else ("W", "b", "g")
+    params = _entry(rng, names)
+    grads = [_entry(rng, names), _entry(rng, names)]
+    if tp:
+        mesh = make_mesh({"data": 4, "model": 2}, jax.devices()[:8])
+        specs = SpecLayout(mesh).infer_entry(
+            params, shard_over_data=mode is not UpdateExchange.DENSE)
+        assert set(specs) == {"W", "b"}
+    else:
+        mesh, specs = MeshFactory.data_parallel(), {}
+    n = mesh.shape["data"]
+    # no residual clipping: it is the one step of the codec that
+    # drops error feedback on purpose
+    encoding = (EncodingSpec(residual_post=ResidualClippingPostProcessor(
+        frequency=0)) if mode is UpdateExchange.ENCODED else None)
+
+    state = updater.init_state(params)
+    if mode is UpdateExchange.ENCODED:
+        st = zero.ensure_encoded_state(params, state, n, encoding,
+                                       tp_names=tuple(specs))
+    elif mode is UpdateExchange.DENSE:
+        st = state
+    else:
+        st = zero.to_sharded_state(params, state, n,
+                                   tp_names=tuple(specs))
+    if mode is UpdateExchange.FSDP:
+        flat, fspecs = zero.params_to_fsdp({"e": params}, n,
+                                           tp_specs={"e": specs})
+        p = flat["e"]
+
+        def to_layout(g):
+            return zero.params_to_fsdp({"e": g}, n,
+                                       tp_specs={"e": specs})[0]["e"]
+
+        def to_dense(q):
+            return zero.params_to_dense({"e": q}, fspecs)["e"]
+    else:
+        p = params
+
+        def to_layout(g):
+            return g
+
+        def to_dense(q):
+            return q
+
+    step = jax.jit(lambda g, q, s, it: zero.apply_update(
+        updater, g, q, s, it, mesh=mesh, mode=mode, tp_specs=specs,
+        encoding=encoding))
+
+    rest_spec = dp_flatten_spec(
+        {k: a for k, a in params.items() if k not in specs}, n)
+
+    def residual(s):
+        if not (isinstance(s, dict) and ENCODED_KEY in s):
+            return None
+        return dp_unravel(s[ENCODED_KEY]["residual"], rest_spec)
+
+    ref_p, ref_s = params, state
+    for it, g in enumerate(grads):
+        res_in = residual(st)
+        p, st = step(to_layout(g), p, st, jnp.asarray(it))
+        res_out = residual(st)
+        if res_out is not None:
+            g = {**g, **{k: g[k] + res_in[k] - res_out[k]
+                         for k in res_out}}
+        u, ref_s = updater.apply(g, ref_s, jnp.asarray(it))
+        ref_p = {k: ref_p[k] - u[k] for k in ref_p}
+        _assert_tree_close(to_dense(p), ref_p, rtol=2e-6, atol=1e-6)
+    if mode is UpdateExchange.ENCODED:
+        assert (res_out is None) == (tp == "all_tp")
+        st = zero.strip_encoded_state(st)
+    _assert_tree_close(zero.to_dense_state(params, st), ref_s,
+                       rtol=2e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("case", ["keep_dtype", "promotes", "clip"])
+def test_apply_update_dense_call_site_arithmetic(case):
+    """The two things a call site chooses about the dense tail:
+    SameDiff keeps each variable's own dtype under an f32 updater, the
+    network classes keep what the subtraction gives; the network
+    classes' gradient normalization runs before the updater."""
+    from deeplearning4j_tpu.nn.conf.builders import GradientNormalization
+    from deeplearning4j_tpu.parallel import zero
+    rng = np.random.default_rng(5)
+    params = {"W": jnp.asarray(rng.normal(size=(4, 4)), jnp.bfloat16)}
+    grads = {"W": jnp.asarray(rng.normal(size=(4, 4)), jnp.float32)}
+    upd = Sgd(0.1)
+    if case == "clip":
+        new_p, _ = zero.apply_update(
+            upd, grads, params, (), jnp.asarray(0), normalization=(
+                GradientNormalization.CLIP_ELEMENT_WISE_ABSOLUTE_VALUE,
+                0.5))
+        u, _ = upd.apply({"W": jnp.clip(grads["W"], -0.5, 0.5)}, (),
+                         jnp.asarray(0))
+        np.testing.assert_array_equal(
+            np.asarray(new_p["W"], np.float32),
+            np.asarray(params["W"] - u["W"], np.float32))
+        return
+    new_p, _ = zero.apply_update(upd, grads, params, (), jnp.asarray(0),
+                                 keep_dtype=case == "keep_dtype")
+    assert new_p["W"].dtype == (jnp.bfloat16 if case == "keep_dtype"
+                                else jnp.float32)
 
 
 # -- resolver --------------------------------------------------------------
