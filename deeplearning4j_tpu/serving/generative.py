@@ -169,6 +169,15 @@ def _disconnects_counter() -> telemetry.Counter:
         "their KV blocks return to the pool on the next iteration")
 
 
+def _sample_path_counter() -> telemetry.Counter:
+    return telemetry.counter(
+        "dl4j_generate_sample_path_total",
+        "executions of the sampler (decode steps and first-token "
+        "samples), by model and the rung its batch asked for (argmax "
+        "| categorical | top_k | sort): the last two order the "
+        "vocabulary for every row of the bucket")
+
+
 class TokenStream:
     """Consumer handle of one generate request: iterate token ids as
     the engine decodes them; ``reason`` tells how the sequence ended.
@@ -654,6 +663,9 @@ class DecodeEngine:
         t = self._prompt_bucket(prompt.size)
         if self.pool.state:
             whose["state_slot"] = self.pool.slot(seq_id)
+        temps = np.asarray([temperature], np.float32)
+        topks = np.asarray([top_k], np.int32)
+        whose.update(self._name_sample(temps, topks))
         with telemetry.span(
                 "generate.prefill", model=self.name,
                 tokens=int(prompt.size), bucket=t,
@@ -677,9 +689,7 @@ class DecodeEngine:
             self._step += 1
             key = jax.random.fold_in(self._rng, self._step)
             first = int(np.asarray(self._sample_jit()(
-                last, key,
-                np.asarray([temperature], np.float32),
-                np.asarray([top_k], np.int32)))[0])
+                last, key, temps, topks))[0])
         now = time.perf_counter()
         _ttft_hist().observe(now - t_submit, model=self.name)
         if ctx is not None:
@@ -725,17 +735,36 @@ class DecodeEngine:
     def _meters(self):
         """The per-step and per-token meters with this engine's labels
         resolved, bound once (again only if the registry is replaced):
-        (decode-step seconds, occupancy, tokens, inter-token gap)."""
+        (decode-step seconds, occupancy, tokens, inter-token gap, the
+        sampler's executions by rung)."""
         reg = telemetry.MetricsRegistry.get()
         if self._meters_of is not reg:
+            from deeplearning4j_tpu.ops.sampling import PATHS
             self._meters_of = reg
             self._bound = (
                 _decode_step_hist().bind(model=self.name),
                 _occupancy_hist().bind(model=self.name,
                                        policy="decode"),
                 _tokens_counter().bind(model=self.name),
-                _intertoken_hist().bind(model=self.name))
+                _intertoken_hist().bind(model=self.name),
+                [_sample_path_counter().bind(model=self.name, path=p)
+                 for p in PATHS])
         return self._bound
+
+    def _name_sample(self, temps, topks) -> dict:
+        """What the sampler's program will do with these per-row
+        arguments, as span attributes, and counted: the rung it takes
+        (the program's own rule, on the host's copy of the arrays),
+        the rows it sees, and for how many of them it orders the
+        vocabulary."""
+        from deeplearning4j_tpu.ops.sampling import PATHS, sample_rung
+        rung = int(sample_rung(temps, topks))
+        *_, sampled = self._meters()
+        sampled[rung].inc()
+        rows = len(temps)
+        return {"sample_path": PATHS[rung], "sample_rows": rows,
+                "sample_ordered":
+                    rows if rung >= PATHS.index("top_k") else 0}
 
     def _decode_iteration(self) -> None:
         """ONE fused step over all live sequences (the iteration of
@@ -762,7 +791,7 @@ class DecodeEngine:
                 step = self._build_step()
             if step is None:
                 return
-        rows, b, inputs = step
+        rows, b, inputs, sample = step
         pool = self.pool
         counts = {}
         if pool.state:
@@ -775,7 +804,7 @@ class DecodeEngine:
                 bucket=b, grid_blocks=b * self.max_blocks,
                 pool_usable=pool.usable_blocks,
                 pool_live=pool.usable_blocks - pool.free_blocks,
-                **counts):
+                **counts, **sample):
             t0 = time.perf_counter()
             with telemetry.span("generate.dispatch",
                                 program="decode_step"):
@@ -828,7 +857,8 @@ class DecodeEngine:
     def _build_step(self, prev: Optional[_Step] = None):
         """Everything the host does before a step can be dispatched:
         pre-step retirement, one more token slot for every row, the
-        padded inputs and the step's key. With ``prev``, the last
+        padded inputs, the step's key and what its sampler will do
+        (:meth:`_name_sample`). With ``prev``, the last
         step dispatched and still in flight, the rows keep their
         places, stand as many positions further as steps in flight
         carry them, and take its ids as their tokens. None when no
@@ -893,15 +923,16 @@ class DecodeEngine:
         self._record(tokens, positions, tables, temps, topks)
         self._step += 1
         key = jax.random.fold_in(self._rng, self._step)
-        return rows, b, (tokens if prev is None else prev.ids,
-                         positions, tables, key, temps, topks,
-                         *self._state_arg(state_slots))
+        inputs = (tokens if prev is None else prev.ids, positions,
+                  tables, key, temps, topks,
+                  *self._state_arg(state_slots))
+        return rows, b, inputs, self._name_sample(temps, topks)
 
     def _emit(self, rows, b, ids, step_s) -> int:
         """Hand every row its token: meters, the stream's queue, the
         request's ``inter_token`` instant, and retirement on EOS or
         ``max_tokens``. Returns how many rows retired."""
-        step_hist, occupancy, tokens, gap_hist = self._meters()
+        step_hist, occupancy, tokens, gap_hist, _ = self._meters()
         step_hist.observe(step_s)
         occupancy.observe(sum(seq is not None for seq in rows)
                           / max(1, b))

@@ -25,6 +25,19 @@ from deeplearning4j_tpu.serving.kvcache import (KVBlockPool,
                                                 PoolExhausted)
 
 
+def _decode_args(eng):
+    """The decode program's arguments at the largest bucket, all
+    zeros: every row dead, greedy."""
+    import jax
+    b = eng.decode_buckets[-1]
+    return (eng.params, eng.pool.arrays, np.zeros((b,), np.int32),
+            np.zeros((b,), np.int32),
+            np.zeros((b, eng.max_blocks), np.int32),
+            jax.random.PRNGKey(0), np.zeros((b,), np.float32),
+            np.zeros((b,), np.int32),
+            *eng._state_arg(np.zeros((b,), np.int32)))
+
+
 def _engine(conf=None, *, kv_blocks=64, block=8, prompt_buckets=(16,),
             decode_buckets=(4,), max_seq_len=64, **kw):
     conf = conf or DecoderConfig.tiny()
@@ -37,6 +50,143 @@ def _engine(conf=None, *, kv_blocks=64, block=8, prompt_buckets=(16,),
                        max_seq_len=max_seq_len, **kw)
     eng.warmup()
     return model, pool, eng
+
+
+def _always_sort_sample_logits(logits, key, temperature=1.0, top_k=0):
+    """``ops.sampling.sample_logits`` as it stood before ISSUE 31: it
+    sorted the whole vocabulary and drew noise for every row of every
+    call, and discarded both for greedy rows. The oracle of the rungs:
+    the same ids, bit for bit."""
+    import jax
+    import jax.numpy as jnp
+    logits = jnp.asarray(logits)
+    temp = jnp.broadcast_to(jnp.asarray(temperature, logits.dtype),
+                            logits.shape[:-1])
+    greedy_ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    safe_temp = jnp.where(temp > 0, temp, 1.0)
+    scaled = logits / safe_temp[..., None]
+    vocab = scaled.shape[-1]
+    k = jnp.broadcast_to(jnp.asarray(top_k, jnp.int32),
+                         scaled.shape[:-1])
+    kc = jnp.clip(k, 1, vocab)
+    sorted_desc = jnp.sort(scaled, axis=-1)[..., ::-1]
+    thresh = jnp.take_along_axis(sorted_desc, kc[..., None] - 1,
+                                 axis=-1)
+    filtered = jnp.where(scaled >= thresh, scaled, -1e9)
+    scaled = jnp.where(k[..., None] > 0, filtered, scaled)
+    sampled_ids = jax.random.categorical(key, scaled,
+                                         axis=-1).astype(jnp.int32)
+    return jnp.where(temp > 0, sampled_ids, greedy_ids)
+
+
+def _sampler_logits(vocab=1017, rows=8, seed=0):
+    # a vocabulary that is no multiple of 128
+    return np.random.default_rng(seed).normal(
+        size=(rows, vocab)).astype(np.float32) * 3.0
+
+
+def _tied_logits():
+    # whole numbers in [-3, 3]: some 145 ties at every value, so every
+    # threshold is tied and the ties are kept
+    return np.random.default_rng(4).integers(
+        -3, 4, size=(8, 1017)).astype(np.float32)
+
+
+def _ks(*values):
+    return np.asarray(values, np.int32)
+
+
+_MIXED = np.asarray([0, 1.0, 0, 0.7, 1.3, 0, 2.0, 0.4], np.float32)
+_ALL = np.full((8,), 0.9, np.float32)
+_K0 = np.zeros((8,), np.int32)
+
+
+def _cap():
+    from deeplearning4j_tpu.ops.sampling import TOP_K_CAP
+    return TOP_K_CAP
+
+
+#: name -> () -> (logits, temperature, top_k, the rung it lands on)
+_SAMPLER_CASES = {
+    "all_greedy": lambda: (
+        _sampler_logits(), np.zeros((8,), np.float32), _K0, "argmax"),
+    "greedy_rows_with_a_top_k": lambda: (
+        _sampler_logits(), np.zeros((8,), np.float32),
+        np.full((8,), 1000, np.int32), "argmax"),
+    "greedy_and_sampled_mixed": lambda: (
+        _sampler_logits(), _MIXED, _K0, "categorical"),
+    "all_sampled_top_k_0": lambda: (
+        _sampler_logits(), _ALL, _K0, "categorical"),
+    "a_greedy_rows_top_k_is_not_read": lambda: (
+        _sampler_logits(), _MIXED,
+        _ks(1000, 0, 40, 0, 0, 0, 0, 0),
+        "categorical"),
+    "top_k_1": lambda: (
+        _sampler_logits(), _MIXED, np.full((8,), 1, np.int32),
+        "top_k"),
+    "top_k_40": lambda: (
+        _sampler_logits(), _ALL, np.full((8,), 40, np.int32), "top_k"),
+    "top_k_one_row_of_eight": lambda: (
+        _sampler_logits(), _MIXED,
+        _ks(0, 40, 0, 0, 0, 0, 0, 0), "top_k"),
+    "top_k_cap": lambda: (
+        _sampler_logits(), _MIXED,
+        _ks(0, _cap(), 0, 3, 0, 0, 50, _cap()),
+        "top_k"),
+    "top_k_cap_plus_1": lambda: (
+        _sampler_logits(), _MIXED,
+        _ks(0, _cap() + 1, 0, 3, 0, 0, 50, 0),
+        "sort"),
+    "top_k_whole_vocabulary": lambda: (
+        _sampler_logits(), _ALL,
+        _ks(1017, 1016, 5000, 1, 0, 129, 128, 500),
+        "sort"),
+    "ties_at_the_threshold_top_k": lambda: (
+        _tied_logits(), _MIXED, np.full((8,), 7, np.int32), "top_k"),
+    "ties_at_the_threshold_sort": lambda: (
+        _tied_logits(), _MIXED, np.full((8,), 300, np.int32), "sort"),
+    "vocabulary_under_the_cap": lambda: (
+        _sampler_logits(vocab=16), _MIXED,
+        _ks(0, 3, 16, 100, 1, 0, 15, 2), "top_k"),
+    "vocabulary_of_whole_tiles": lambda: (
+        _sampler_logits(vocab=1024), _MIXED,
+        _ks(0, 3, 16, 100, 1, 0, 15, 2), "top_k"),
+    "scalar_temperature_and_top_k": lambda: (
+        _sampler_logits(), 0.8, 40, "top_k"),
+    "scalar_top_k_past_the_cap": lambda: (
+        _sampler_logits(), 1.2, 400, "sort"),
+    "scalar_temperature_alone": lambda: (
+        _sampler_logits(), 0.8, 0, "categorical"),
+    "scalar_greedy": lambda: (_sampler_logits(), 0.0, 0, "argmax"),
+}
+
+
+def _ordering_ops(lowered):
+    """``(operation, under a conditional)`` for every operation of the
+    lowered program that orders its operand (a sort, a top-k
+    selection), following calls from ``main``: a function called from
+    a branch is under the conditional too."""
+    from jax._src.lib.mlir import ir
+    module = lowered.compiler_ir("stablehlo")
+    funcs = {ir.StringAttr(op.attributes["sym_name"]).value: op
+             for op in module.body.operations}
+    found = []
+
+    def walk(op, under):
+        name = op.operation.name
+        if name in ("stablehlo.sort", "chlo.top_k"):
+            found.append((name, under))
+        if name in ("func.call", "call"):
+            callee = ir.FlatSymbolRefAttr(op.attributes["callee"]).value
+            walk(funcs[callee], under)
+        inner = under or name in ("stablehlo.case", "stablehlo.if")
+        for region in op.operation.regions:
+            for block in region.blocks:
+                for child in block.operations:
+                    walk(child, inner)
+
+    walk(funcs["main"], False)
+    return sorted(set(found))
 
 
 class TestSampling:
@@ -91,6 +241,83 @@ class TestSampling:
         assert 0.5 < counts[0] / max(counts[1], 1) * 0.5 < 2.0
 
 
+    @pytest.mark.parametrize("mode", ["jit", "eager"])
+    @pytest.mark.parametrize("case", sorted(_SAMPLER_CASES))
+    def test_every_rung_gives_the_ids_of_the_always_sort_formula(
+            self, case, mode):
+        """Bit for bit: whatever rung the batch lands on, the ids are
+        those of the formula that sorted the whole vocabulary in every
+        call. ``jit`` hands the per-row arguments to one compiled
+        program (the device picks the rung); ``eager`` hands them over
+        concrete (the rung is picked while tracing)."""
+        import jax
+        from deeplearning4j_tpu.ops.sampling import (PATHS,
+                                                     sample_logits,
+                                                     sample_rung)
+        logits, temp, top_k, path = _SAMPLER_CASES[case]()
+        scalar = np.ndim(temp) == 0
+        if not scalar:
+            assert PATHS[int(sample_rung(temp, top_k))] == path
+        new, old = sample_logits, _always_sort_sample_logits
+        if mode == "jit" and scalar:
+            # Python scalars closed over, as bench_charrnn.py has them
+            new = jax.jit(lambda x, k: sample_logits(x, k, temp, top_k))
+            old = jax.jit(lambda x, k: _always_sort_sample_logits(
+                x, k, temp, top_k))
+            args = ()
+        elif mode == "jit":
+            new, old, args = jax.jit(new), jax.jit(old), (temp, top_k)
+        else:
+            args = (temp, top_k)
+        for seed in range(5):
+            key = jax.random.PRNGKey(seed)
+            got = np.asarray(new(logits, key, *args))
+            want = np.asarray(old(logits, key, *args))
+            assert got.dtype == np.int32 and got.shape == want.shape
+            assert got.tolist() == want.tolist(), (case, seed)
+
+    def test_the_rungs_differ_where_they_should(self):
+        """The oracle comparison is not vacuous: a ``top_k`` changes a
+        sampling row's ids, and a greedy row's never."""
+        import jax
+        from deeplearning4j_tpu.ops.sampling import sample_logits
+        logits = _sampler_logits()
+        temp = np.asarray([0, 1.5, 1.5, 0, 1.5, 1.5, 1.5, 1.5],
+                          np.float32)
+        free = [np.asarray(jax.jit(sample_logits)(
+            logits, jax.random.PRNGKey(s), temp,
+            np.zeros((8,), np.int32))) for s in range(5)]
+        cut = [np.asarray(jax.jit(sample_logits)(
+            logits, jax.random.PRNGKey(s), temp,
+            np.full((8,), 2, np.int32))) for s in range(5)]
+        best = np.argmax(logits, axis=-1)
+        assert any((a != b).any() for a, b in zip(free, cut))
+        assert all((a[[0, 3]] == best[[0, 3]]).all() for a in free + cut)
+
+    def test_the_vocabulary_is_ordered_only_under_a_branch(self):
+        """In the lowered program no sort and no top-k selection stands
+        outside a region of the conditional: a batch that does not ask
+        for one does not run one. One module, the four arguments."""
+        import jax
+        from deeplearning4j_tpu.ops.sampling import sample_logits
+        lowered = jax.jit(sample_logits).lower(
+            np.zeros((4, 1017), np.float32), jax.random.PRNGKey(0),
+            np.zeros((4,), np.float32), np.zeros((4,), np.int32))
+        assert _ordering_ops(lowered) == [("chlo.top_k", True),
+                                          ("stablehlo.sort", True)]
+        assert lowered.as_text().count("module @") == 1
+        assert len(lowered.in_avals[0]) == 4
+        # concrete arguments: the one rung is picked while tracing
+        scalar = jax.jit(lambda x, k: sample_logits(x, k, 0.8, 40)) \
+            .lower(np.zeros((4, 1017), np.float32),
+                   jax.random.PRNGKey(0))
+        assert _ordering_ops(scalar) == [("chlo.top_k", False)]
+        greedy = jax.jit(lambda x, k: sample_logits(x, k, 0.0)) \
+            .lower(np.zeros((4, 1017), np.float32),
+                   jax.random.PRNGKey(0))
+        assert _ordering_ops(greedy) == []
+
+
 class TestDecodeEngine:
     def test_greedy_decode_matches_dense_reference(self):
         model, pool, eng = _engine()
@@ -101,6 +328,80 @@ class TestDecodeEngine:
         assert got == ref
         assert eng.retraces_since_warmup() == 0
         eng.shutdown()
+
+    def test_a_top_k_request_joins_greedy_rows_and_leaves_again(self):
+        """ISSUE 31: the decode program does the work its batch asks
+        for, and the engine says which. Two greedy requests decode,
+        one with a ``top_k`` joins them for three steps: no retrace,
+        the greedy rows' tokens are the reference's, the steps name
+        the rung they took and the counter counts them."""
+        from deeplearning4j_tpu.common.telemetry import MetricsRegistry
+        from deeplearning4j_tpu.serving.generative import \
+            _sample_path_counter
+        MetricsRegistry._reset_for_tests()
+        model, pool, eng = _engine()
+        try:
+            # of one length: the reference compiles once a length
+            prompts = [np.array([5, 9, 2, 7]), np.array([8, 3, 6, 1])]
+            s1 = eng.submit(prompts[0], 16)
+            s2 = eng.submit(prompts[1], 16)
+            head = [s1.next(timeout=30) for _ in range(3)]
+            s3 = eng.submit(np.array([4, 4, 1]), 4, temperature=0.9,
+                            top_k=5)
+            sampled = list(s3)
+            got = [head + list(s1), list(s2)]
+            eng.shutdown()
+            assert len(sampled) == 4
+            assert eng.retraces_since_warmup() == 0
+            for prompt, tokens in zip(prompts, got):
+                assert tokens == list(model.reference_decode(
+                    eng.params, prompt, 16, eos_id=model.conf.eos_id))
+            steps = [e["args"] for e in _spans()
+                     if e["name"] == "generate.decode_step"]
+            paths = [a["sample_path"] for a in steps]
+            # its first token came from its prefill, three from steps
+            assert paths.count("top_k") == 3
+            assert [p for p, q in zip(paths, [None] + paths)
+                    if p != q] == ["argmax", "top_k", "argmax"]
+            assert all(a["sample_rows"] == a["bucket"] == 4
+                       and a["sample_ordered"]
+                       == (4 if a["sample_path"] == "top_k" else 0)
+                       for a in steps)
+            prefills = [e["args"] for e in _spans()
+                        if e["name"] == "generate.prefill"]
+            assert [(a["sample_path"], a["sample_rows"],
+                     a["sample_ordered"]) for a in prefills] == [
+                ("argmax", 1, 0), ("argmax", 1, 0), ("top_k", 1, 1)]
+            count = _sample_path_counter()
+            assert count.value(model="t-gen", path="top_k") == 3 + 1
+            assert count.value(model="t-gen", path="argmax") \
+                == len(steps) - 3 + 2
+            assert count.value(model="t-gen", path="sort") == 0
+        finally:
+            eng.shutdown()
+            MetricsRegistry._reset_for_tests()
+
+    def test_the_decode_program_orders_nothing_outside_a_branch(self):
+        """The sampler's conditional lives inside the one decode
+        program: no sort or top-k selection outside a branch, one
+        module, the arguments it had (parameters, cache, tokens,
+        positions, tables, key, temperatures, top_ks)."""
+        import jax
+        model, pool, eng = _engine()
+        try:
+            args = _decode_args(eng)
+            lowered = eng._decode_jit().lower(*args)
+            assert _ordering_ops(lowered) == [("chlo.top_k", True),
+                                              ("stablehlo.sort", True)]
+            text = lowered.as_text()
+            assert text.count("module @") == 1
+            assert text.count("stablehlo.case") == 1
+            assert len(jax.tree.leaves(lowered.in_avals)) \
+                == len(jax.tree.leaves(args))
+            assert set(eng._jits) == {"prefill", "commit", "sample",
+                                      "decode"}
+        finally:
+            eng.shutdown()
 
     def test_multi_block_generation_chains_and_matches(self):
         """A completion long enough to cross several block
@@ -271,10 +572,9 @@ def test_the_cache_is_updated_in_place(kind, program):
     program alias each array of the cache to an output (K, V and the
     state kinds: no second pool is written), and the arrays the pool
     held before a call are gone after it."""
-    import jax
     pool, eng = _cache_engine(kind)
     try:
-        b, t = eng.decode_buckets[-1], eng.prompt_buckets[-1]
+        t = eng.prompt_buckets[-1]
         if program == "commit":
             _, *new = eng._prefill_jit()(
                 eng.params, np.zeros((1, t), np.int32),
@@ -285,12 +585,7 @@ def test_the_cache_is_updated_in_place(kind, program):
                     *eng._state_arg(np.int32(0)))
         else:
             jit = eng._decode_jit()
-            args = (eng.params, pool.arrays, np.zeros((b,), np.int32),
-                    np.zeros((b,), np.int32),
-                    np.zeros((b, eng.max_blocks), np.int32),
-                    jax.random.PRNGKey(0), np.zeros((b,), np.float32),
-                    np.zeros((b,), np.int32),
-                    *eng._state_arg(np.zeros((b,), np.int32)))
+            args = _decode_args(eng)
         n = len(pool.arrays)
         assert n == (2 if kind == "decoder" else 4)
         lowered = jit.lower(*args)
