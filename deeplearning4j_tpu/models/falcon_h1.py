@@ -47,6 +47,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deeplearning4j_tpu.models.served import (
+    last_position, mm as _mm, pool_rows, swiglu, write_rows)
+
 
 @dataclass
 class FalconH1Config:
@@ -120,15 +123,6 @@ class FalconH1Config:
 def _rms(x, w, eps):
     return x * jax.lax.rsqrt(
         jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * w
-
-
-def _mm(x, w):
-    """``x @ w`` with ``x`` rounded to the weights' type and a float32
-    result: what the TPU's default precision does to a float32 product
-    anyway, said outright so that bfloat16 weights are read as they
-    are stored and never widened in memory."""
-    return jnp.dot(x.astype(w.dtype), w,
-                   preferred_element_type=jnp.float32)
 
 
 def _rope(x, positions, theta):
@@ -266,9 +260,8 @@ class FalconH1LM:
     def _mlp(self, p, x):
         c = self.conf
         h = _rms(x, p["norm2"], c.rms_norm_eps)
-        gate = jax.nn.silu(_mm(h, p["gate"]) * c.mlp_multipliers[0])
-        return x + _mm(gate * _mm(h, p["up"]),
-                       p["down"]) * c.mlp_multipliers[1]
+        return x + swiglu(h, p["gate"], p["up"], p["down"],
+                          c.mlp_multipliers[0]) * c.mlp_multipliers[1]
 
     def _logits(self, params, x):
         c = self.conf
@@ -352,8 +345,8 @@ class FalconH1LM:
         """Prompt pass: ``(last_logits [b, vocab], k, v, ssm, conv)``.
         The head runs on position ``length - 1`` only."""
         x, k, v, ssm, conv = self._trunk(params, tokens, length)
-        last = x[jnp.arange(tokens.shape[0]), jnp.asarray(length) - 1]
-        return self._logits(params, last), k, v, ssm, conv
+        return (self._logits(params, last_position(x, length)),
+                k, v, ssm, conv)
 
     # -- one fused decode step over the cache ---------------------------
     def decode_step(self, params, tokens, positions, k_pool, v_pool,
@@ -378,17 +371,14 @@ class FalconH1LM:
                   else paged_attention_reference)
         x = (params["embed"]["tok"][tokens].astype(jnp.float32)
              * c.embedding_multiplier)                       # [b, d]
-        blk = block_tables[jnp.arange(b), positions // bs]   # [b]
-        off = positions % bs
+        blk, off = pool_rows(block_tables, positions, bs)    # [b] each
         lengths = positions + 1
         for i in range(c.n_layers):
             p = params[f"layer_{i}"]
             h = _rms(x, p["norm1"], c.rms_norm_eps)
             q, k_new, v_new = self._qkv(p, h, positions)
-            k_pool = k_pool.at[i, blk, off].set(
-                jnp.reshape(k_new, (b, -1)).astype(k_pool.dtype))
-            v_pool = v_pool.at[i, blk, off].set(
-                jnp.reshape(v_new, (b, -1)).astype(v_pool.dtype))
+            k_pool = write_rows(k_pool, i, blk, off, k_new)
+            v_pool = write_rows(v_pool, i, blk, off, v_new)
             a = attend(q, k_pool, v_pool, block_tables, lengths, i)
             a = _mm(jnp.reshape(a, (b, -1)),
                     p["wo"]) * c.attention_out_multiplier

@@ -211,7 +211,8 @@ def _paged_gather(pool, layer, block_tables, d):
 
 def paged_attention_reference(q, k_pool, v_pool, block_tables,
                               lengths, layer=0,
-                              scale: Optional[float] = None):
+                              scale: Optional[float] = None,
+                              v_group: int = 1):
     """Dense-gather fallback AND numerical reference for paged decode
     attention.
 
@@ -223,6 +224,12 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables,
     int32 — valid KV tokens per sequence (>= 1, the current token's KV
     already written). Returns [b, h, d].
 
+    ``v_group`` KV heads side by side share their values (differential
+    attention: K heads of ``d``, a V of ``v_group * d`` for the pair):
+    query head ``r``, which scores against KV head ``k = r // (h /
+    h_kv)``, then reads the values of heads ``[k - k % v_group, ... +
+    v_group)`` and the result is [b, h, v_group * d].
+
     The gather materializes [b, max_blocks*block, h_kv, d] whatever
     the lengths are — the bytes the Pallas kernel does not move — but
     runs everywhere and defines the semantics the kernel must match
@@ -233,8 +240,8 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables,
     t = k.shape[1]
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    if h != k.shape[2]:
-        return _paged_reference_grouped(q, k, v, lengths, scale)
+    if h != k.shape[2] or v_group != 1:
+        return _paged_reference_grouped(q, k, v, lengths, scale, v_group)
     s = jnp.einsum("bhd,bthd->bht", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     valid = (jnp.arange(t, dtype=jnp.int32)[None, :]
@@ -247,14 +254,18 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables,
     return out.astype(q.dtype)
 
 
-def _paged_reference_grouped(q, k, v, lengths, scale):
+def _paged_reference_grouped(q, k, v, lengths, scale, v_group=1):
     """:func:`paged_attention_reference` where the gathered ``k`` /
     ``v`` [b, t, h_kv, d] hold fewer KV heads than ``q`` has query
     heads (grouped-query attention): query head ``i`` reads KV head
-    ``i // (h / h_kv)``."""
+    ``i // (h / h_kv)``, and the values of that head's ``v_group``."""
     f32 = jnp.float32
     b, h, d = q.shape
     t, h_kv = k.shape[1:3]
+    if v_group != 1:
+        # every KV head gets its group's values, v_group * d wide
+        v = jnp.repeat(v.reshape(b, t, h_kv // v_group, v_group * d),
+                       v_group, axis=2)
     qg = q.astype(f32).reshape(b, h_kv, h // h_kv, d)
     s = jnp.einsum("bkgd,btkd->bkgt", qg, k.astype(f32)) * scale
     valid = jnp.arange(t, dtype=jnp.int32)[None, :] < lengths[:, None]
@@ -263,7 +274,7 @@ def _paged_reference_grouped(q, k, v, lengths, scale):
     p = jnp.where(s <= _PAGED_NEG_INF / 2, 0.0, jnp.exp(s - m))
     w = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
     out = jnp.einsum("bkgt,btkd->bkgd", w, v.astype(f32))
-    return out.reshape(b, h, d).astype(q.dtype)
+    return out.reshape(b, h, v_group * d).astype(q.dtype)
 
 
 #: KV tokens one inner step of the paged kernel multiplies at once:
@@ -290,7 +301,7 @@ def _paged_decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_hbm,
                          v_hbm, out_ref, kbuf, vbuf, sem, slot_ref,
                          m_ref, l_ref, acc_ref, *, head_dim: int,
                          block: int, blocks_per_step: int, scale: float,
-                         group: int = 1):
+                         group: int = 1, v_group: int = 1):
     """One sequence a grid step; inside it, a loop over the sequence's
     own ``ceil(length / block)`` blocks, ``blocks_per_step`` at a time.
 
@@ -310,7 +321,13 @@ def _paged_decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_hbm,
     ``P @ V -> [H, h*d]``, of which row ``r`` is kept on head ``r``'s
     lanes when the row is finished. Both products take bf16 operands
     into float32 (the model's default precision); the softmax
-    statistics and the accumulator are float32."""
+    statistics and the accumulator are float32.
+
+    With ``v_group > 1`` (differential attention) a row scores against
+    its own KV head's lanes as ever and keeps, of ``P @ V``, the lanes
+    of its head's whole group of ``v_group``: ``out_ref`` then has
+    ``group * v_group`` rows, row ``c * group + j`` holding on group
+    ``G``'s lanes query head ``(G * v_group + c) * group + j``."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -372,6 +389,14 @@ def _paged_decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_hbm,
         owns = [own & (row % group == j) for j in range(group)]
         qbd = sum(jnp.where(o, q_ref[j:j + 1, :].astype(f32), 0.0)
                   for j, o in enumerate(owns)).astype(bf16)
+    keeps = owns
+    if v_group != 1:
+        kv = row // group
+        wide = v_group * head_dim
+        lo = (kv // v_group) * wide
+        keep = (lane >= lo) & (lane < lo + wide)
+        keeps = [keep & (kv % v_group == c) & (row % group == j)
+                 for c in range(v_group) for j in range(group)]
     m_ref[...] = jnp.full_like(m_ref, _PAGED_NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
@@ -415,14 +440,15 @@ def _paged_decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_hbm,
 
     jax.lax.fori_loop(0, n_steps, step, 0)
     inv_l = 1.0 / jnp.maximum(l_ref[...], 1e-30)
-    for j, o in enumerate(owns):
+    for j, o in enumerate(keeps):
         out_ref[j:j + 1, :] = jnp.sum(
             acc_ref[...] * jnp.where(o, inv_l, 0.0), axis=0,
             keepdims=True).astype(out_ref.dtype)
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
-                           layer=0, scale: Optional[float] = None):
+                           layer=0, scale: Optional[float] = None,
+                           v_group: int = 1):
     """Pallas paged decode attention — same contract as
     :func:`paged_attention_reference`, at the model's default product
     precision (bf16 operands, float32 accumulation and softmax). The
@@ -445,13 +471,14 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
     return _paged_call(q, k_pool, v_pool, block_tables, lengths,
                        jnp.asarray(layer, jnp.int32).reshape(1),
                        scale=float(scale), per_step=per_step,
-                       interpret=kernel_select.interpret_mode())
+                       interpret=kernel_select.interpret_mode(),
+                       v_group=int(v_group))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("scale", "per_step", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "per_step",
+                                             "interpret", "v_group"))
 def _paged_call(q, k_pool, v_pool, block_tables, lengths, layer, *,
-                scale, per_step, interpret):
+                scale, per_step, interpret, v_group=1):
     """The ``pallas_call``, jitted on its own so that a model's layers
     share one trace and one lowering of the kernel (the layer index is
     an operand): traced layer by layer, GPT-2 large's 36 added 3.5 s
@@ -476,7 +503,7 @@ def _paged_call(q, k_pool, v_pool, block_tables, lengths, layer, *,
             pl.BlockSpec(memory_space=pl.ANY),    # k pool: stays in HBM
             pl.BlockSpec(memory_space=pl.ANY),    # v pool
         ],
-        out_specs=pl.BlockSpec((None, g, hd), row),
+        out_specs=pl.BlockSpec((None, g * v_group, hd), row),
         scratch_shapes=[
             pltpu.VMEM((2, per_step * block, hd), k_pool.dtype),
             pltpu.VMEM((2, per_step * block, hd), v_pool.dtype),
@@ -489,7 +516,7 @@ def _paged_call(q, k_pool, v_pool, block_tables, lengths, layer, *,
     )
     kernel = functools.partial(_paged_decode_kernel, head_dim=d,
                                block=block, blocks_per_step=per_step,
-                               scale=scale, group=g)
+                               scale=scale, group=g, v_group=v_group)
 
     def by_kv_head(a):        # [b, h, d] -> [b, g, h_kv * d]
         if g == 1:
@@ -500,15 +527,19 @@ def _paged_call(q, k_pool, v_pool, block_tables, lengths, layer, *,
         out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, g, hd), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((b, g * v_group, hd), q.dtype),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
         )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
           layer, by_kv_head(q), k_pool, v_pool)
-    if g == 1:
+    if g == 1 and v_group == 1:
         return out.reshape(b, h, d)
-    return jnp.swapaxes(out.reshape(b, g, h_kv, d), 1, 2).reshape(b, h, d)
+    if v_group == 1:
+        return jnp.swapaxes(out.reshape(b, g, h_kv, d), 1, 2).reshape(b, h, d)
+    # rows [c, j], lanes [G, v_group * d] -> query head (G, c, j)
+    out = out.reshape(b, v_group, g, h_kv // v_group, v_group * d)
+    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(b, h, v_group * d)
 
 
 def select_paged_backend(batch: int, max_blocks: int, *,

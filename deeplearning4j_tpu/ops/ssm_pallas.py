@@ -1,5 +1,5 @@
-"""Selective state-space (Mamba-2 / SSD) recurrence: the chunked prefill
-scan and the one-token decode update of a slotted state pool.
+"""Selective state-space recurrences (Mamba-2 / SSD and Mamba-1): the
+prefill scans and the one-token decode updates of a slotted state pool.
 
 The recurrence, per head with state ``S [p, n]`` (``p`` the head's
 channels, ``n`` the state size), decay ``a_t = exp(dt_t * A)``::
@@ -23,6 +23,27 @@ groups: head ``i`` reads group ``i // (heads / groups)``.
   arithmetic. :func:`ssm_state_update_reference` is its ``jax.numpy``
   form — the fallback everywhere the ladder does not take the kernel,
   and the kernel's numerical reference.
+
+Mamba-1 has one decay for every channel and state, ``exp(dt_t[c] *
+A[c, n])``, so nothing of it is a matrix product::
+
+    S_t[n, c] = exp(dt_t[c] A[n, c]) S_{t-1}[n, c] + dt_t[c] B_t[n] x_t[c]
+    y_t[c] = sum_n C_t[n] S_t[n, c]
+
+Its state is held ``[n, c]``: the ``n`` (16) states down the sublanes
+and the channels along the lanes, whole float32 tiles (``[c, n]``
+would pad 16 lanes to 128, eight times the bytes on the device).
+
+- :func:`selective_state_update` — decode over the slotted pool
+  ``[layers, slots, n, c]``, in place on the rows' slots (kernel scope
+  ``pallas.selective_state_update``; :func:`selective_state_update_
+  reference` its ``jax.numpy`` form).
+- :func:`selective_scan` — the prefill form: the kernel (scope
+  ``pallas.selective_scan``) walks the sequence ``_SCAN_TOKENS`` a
+  grid step with the state resident in on-chip memory, so the
+  ``[t, n, c]`` states are never in HBM; :func:`selective_scan_
+  reference` is the ``lax.scan`` over tokens.
+Both go through the ``ssm_state`` ladder (:func:`select_ssm_backend`).
 """
 from __future__ import annotations
 
@@ -231,3 +252,205 @@ def ssm_state_update(state, layer: int, slots, x, dt, decay, b, c):
     fn = (ssm_state_update_pallas if backend == "kernel"
           else ssm_state_update_reference)
     return fn(state, layer, slots, x, dt, decay, b, c)
+
+
+# ---------------------------------------------------------------------------
+# Mamba-1: a decay for every channel and state
+# ---------------------------------------------------------------------------
+#: tokens one grid step of the prefill scan walks: a float32 sublane tile
+_SCAN_TOKENS = 8
+
+
+def _selective_step(s, dt, x, a, b, c):
+    """One token: ``s``/``a`` ``[n, c]``, ``dt``/``x`` ``[1, c]``,
+    ``b``/``c`` ``[n, 1]``. Returns ``(s', y [1, c])``."""
+    s = s * jnp.exp(dt * a) + (dt * x) * b
+    return s, jnp.sum(s * c, axis=0, keepdims=True)
+
+
+def selective_state_update_reference(state, layer: int, slots, x, dt, a,
+                                     b, c):
+    """``jax.numpy`` form of one Mamba-1 decode step on the state pool.
+
+    ``state [layers, slots, n, ch]`` float32; ``slots [rows]`` int32 (0
+    = scratch); ``x``/``dt`` ``[rows, ch]`` (``dt`` softplus'd), ``a
+    [n, ch]`` (negative), ``b``/``c`` ``[rows, n]``. Returns ``(state,
+    y [rows, ch])`` with the rows' slots of ``state[layer]`` replaced."""
+    f32 = jnp.float32
+    x, dt, b, c = (v.astype(f32) for v in (x, dt, b, c))
+    s = (state[layer, slots] * jnp.exp(dt[:, None, :] * a.astype(f32))
+         + (dt * x)[:, None, :] * b[:, :, None])
+    return state.at[layer, slots].set(s), jnp.einsum("rnc,rn->rc", s, c)
+
+
+def _selective_update_kernel(slots_ref, layer_ref, x_ref, dt_ref, b_ref,
+                             c_ref, a_ref, s_ref, so_ref, y_ref):
+    """One row a grid step. ``s_ref``/``so_ref`` are the same HBM block
+    ``[n, ch]`` of the row's slot (aliased)."""
+    import jax.experimental.pallas as pl
+
+    row = pl.program_id(0)
+
+    @pl.when(slots_ref[row] != 0)
+    def _live():                                  # noqa: ANN202
+        so_ref[...], y_ref[...] = _selective_step(
+            s_ref[...], dt_ref[...], x_ref[...], a_ref[...], b_ref[...],
+            c_ref[...])
+
+    @pl.when(slots_ref[row] == 0)
+    def _dead():                                  # noqa: ANN202
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+
+def selective_state_update_pallas(state, layer: int, slots, x, dt, a, b,
+                                  c):
+    """The Pallas kernel — same contract as
+    :func:`selective_state_update_reference`."""
+    from deeplearning4j_tpu.ops import kernel_select
+    return _selective_update_call(
+        state, jnp.asarray([layer], jnp.int32), slots, x, dt, a, b, c,
+        interpret=kernel_select.interpret_mode())
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _selective_update_call(state, layer, slots, x, dt, a, b, c, *,
+                           interpret):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    rows, ch = x.shape
+    n = b.shape[1]
+    row_lanes = pl.BlockSpec((None, 1, ch),
+                             lambda r, slots, layer: (r, 0, 0))
+    row_col = pl.BlockSpec((None, n, 1),
+                           lambda r, slots, layer: (r, 0, 0))
+    slot_block = pl.BlockSpec(
+        (None, None, n, ch),
+        lambda r, slots, layer: (layer[0], slots[r], 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,                    # slots, layer
+        grid=(rows,),
+        in_specs=[row_lanes, row_lanes, row_col, row_col,
+                  pl.BlockSpec((n, ch), lambda r, slots, layer: (0, 0)),
+                  slot_block],
+        out_specs=[slot_block, row_lanes],
+    )
+    with jax.named_scope("pallas.selective_state_update"):
+        state, y = pl.pallas_call(
+            _selective_update_kernel,
+            grid_spec=grid_spec,
+            out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
+                       jax.ShapeDtypeStruct((rows, 1, ch), f32)],
+            # operand 7 (after the two prefetched scalars): the pool,
+            # updated in place
+            input_output_aliases={7: 0},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=interpret,
+        )(slots.astype(jnp.int32), layer, x.astype(f32)[:, None, :],
+          dt.astype(f32)[:, None, :], b.astype(f32)[:, :, None],
+          c.astype(f32)[:, :, None], a.astype(f32), state)
+    return state, y[:, 0]
+
+
+def selective_state_update(state, layer: int, slots, x, dt, a, b, c):
+    """One Mamba-1 decode step on the state pool through the ladder."""
+    backend, _ = select_ssm_backend(int(b.shape[1]), int(x.shape[1]))
+    fn = (selective_state_update_pallas if backend == "kernel"
+          else selective_state_update_reference)
+    return fn(state, layer, slots, x, dt, a, b, c)
+
+
+def selective_scan_reference(x, dt, a, b, c):
+    """The Mamba-1 recurrence token by token (``lax.scan``).
+
+    ``x``/``dt`` ``[bt, t, ch]`` (``dt`` softplus'd; 0 at a padded
+    position leaves the state as it is), ``a [n, ch]``, ``b``/``c``
+    ``[bt, t, n]``. Returns ``(y [bt, t, ch], final_state [bt, n,
+    ch])``, float32."""
+    f32 = jnp.float32
+    x, dt, a, b, c = (v.astype(f32) for v in (x, dt, a, b, c))
+
+    def step(s, xs):
+        x_t, dt_t, b_t, c_t = xs
+        s = (s * jnp.exp(dt_t[:, None, :] * a)
+             + (dt_t * x_t)[:, None, :] * b_t[:, :, None])
+        return s, jnp.einsum("znc,zn->zc", s, c_t)
+
+    s0 = jnp.zeros((x.shape[0], a.shape[0], x.shape[2]), f32)
+    final, y = jax.lax.scan(step, s0, tuple(
+        jnp.moveaxis(v, 1, 0) for v in (x, dt, b, c)))
+    return jnp.moveaxis(y, 0, 1), final
+
+
+def _selective_scan_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, y_ref,
+                           s_ref):
+    """``_SCAN_TOKENS`` tokens of one sequence a grid step, in order:
+    ``s_ref [n, ch]`` is the sequence's one output block, resident
+    across its steps, so the state is carried in on-chip memory."""
+    import jax.experimental.pallas as pl
+
+    @pl.when(pl.program_id(1) == 0)
+    def _first():                                 # noqa: ANN202
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    s, a = s_ref[...], a_ref[...]
+    for i in range(x_ref.shape[0]):
+        s, y_ref[i:i + 1, :] = _selective_step(
+            s, dt_ref[i:i + 1, :], x_ref[i:i + 1, :], a, b_ref[i],
+            c_ref[i])
+    s_ref[...] = s
+
+
+def selective_scan_pallas(x, dt, a, b, c):
+    """The Pallas kernel — same contract as
+    :func:`selective_scan_reference`."""
+    from deeplearning4j_tpu.ops import kernel_select
+    return _selective_scan_call(x, dt, a, b, c,
+                                interpret=kernel_select.interpret_mode())
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _selective_scan_call(x, dt, a, b, c, *, interpret):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    bt, t, ch = x.shape
+    n = a.shape[0]
+    q = _SCAN_TOKENS
+    pad = -t % q
+    if pad:                             # dt = 0: the state stands still
+        x, dt, b, c = (jnp.pad(v, ((0, 0), (0, pad), (0, 0)))
+                       for v in (x, dt, b, c))
+    nq = (t + pad) // q
+    lanes = pl.BlockSpec((None, None, q, ch), lambda z, j: (z, j, 0, 0))
+    cols = pl.BlockSpec((None, None, q, n, 1),
+                        lambda z, j: (z, j, 0, 0, 0))
+    with jax.named_scope("pallas.selective_scan"):
+        y, final = pl.pallas_call(
+            _selective_scan_kernel,
+            grid=(bt, nq),
+            in_specs=[lanes, lanes, cols, cols,
+                      pl.BlockSpec((n, ch), lambda z, j: (0, 0))],
+            out_specs=[lanes,
+                       pl.BlockSpec((None, n, ch), lambda z, j: (z, 0, 0))],
+            out_shape=[jax.ShapeDtypeStruct((bt, nq, q, ch), f32),
+                       jax.ShapeDtypeStruct((bt, n, ch), f32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
+            interpret=interpret,
+        )(x.astype(f32).reshape(bt, nq, q, ch),
+          dt.astype(f32).reshape(bt, nq, q, ch),
+          b.astype(f32).reshape(bt, nq, q, n, 1),
+          c.astype(f32).reshape(bt, nq, q, n, 1), a.astype(f32))
+    return y.reshape(bt, nq * q, ch)[:, :t], final
+
+
+def selective_scan(x, dt, a, b, c):
+    """The Mamba-1 prefill scan through the ladder."""
+    backend, _ = select_ssm_backend(int(a.shape[0]), int(x.shape[2]))
+    fn = (selective_scan_pallas if backend == "kernel"
+          else selective_scan_reference)
+    return fn(x, dt, a, b, c)

@@ -314,7 +314,9 @@ class ServingBatcher(ParallelInference):
                 f"state_slots {state_slots} < largest decode bucket + 1 "
                 f"= {need} (slot 0 is scratch)")
         pool = KVBlockPool(
-            c.n_layers,
+            # the layers whose K/V grows with the context: all of them,
+            # unless the model keeps windows or shares one cache
+            getattr(m, "kv_layers", c.n_layers),
             int(cfg.get("kv_blocks", 64)),
             int(cfg.get("kv_block_size", 16)),
             getattr(c, "n_kv_heads", c.n_heads), c.head_dim,

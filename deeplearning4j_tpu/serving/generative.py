@@ -78,6 +78,17 @@ a sequence a layer into the buffers they were given, and the pool
 holds what they return. The spans then carry ``state_live`` of
 ``state_slots`` (``generate.decode_step``) and ``state_slot``
 (``generate.prefill``).
+
+A model that keeps **window rings** beside one shared K/V layer (it has
+``cache_reads()``; the pool has ``window_bytes``) is served by the same
+programs: its rings are slot kinds like any state. Its
+``generate.decode_step`` spans also carry ``kv_tokens`` (the growing
+layer's live tokens over the step's rows), ``ring_tokens`` (a ring's,
+``min(context, window)`` a row), and what the step's layers read of
+them, ``window_read_tokens`` of ``kv_read_tokens``; its
+``generate.prefill`` spans ``positions`` (the bucket's) and
+``layer_positions`` of ``layer_positions_dense`` (a prefill that runs
+its upper layers on the last position only computes about half).
 """
 from __future__ import annotations
 
@@ -344,6 +355,8 @@ class DecodeEngine:
         self._warmed = False
         self.warm_signatures = 0
         self._jits: dict = {}
+        #: who reads which cache in a step, where the pool holds rings
+        self._reads = model.cache_reads() if pool.window_kinds else None
         import jax
         self._rng = jax.random.PRNGKey(rng_seed)
 
@@ -403,8 +416,11 @@ class DecodeEngine:
                 vp = vp.at[layer, blocks].set(tiles(v, vp))
                 # recurrent state: the prompt's last-token state into
                 # the sequence's slot, every layer at once
-                state = [a.at[:, state_slot[0]].set(n[:, 0].astype(a.dtype))
-                         for a, n in zip(state, new_state)]
+                # (a ring arrives [layers, 1, window, lanes] and is
+                # stored in the pool's blocks: same bytes, same order)
+                state = [a.at[:, state_slot[0]].set(
+                    n[:, 0].reshape(a.shape[:1] + a.shape[2:])
+                    .astype(a.dtype)) for a, n in zip(state, new_state)]
                 return (kp, vp, *state)
             # the cache is donated: the pool's arrays are updated in
             # place, never written a second time
@@ -663,6 +679,10 @@ class DecodeEngine:
         t = self._prompt_bucket(prompt.size)
         if self.pool.state:
             whose["state_slot"] = self.pool.slot(seq_id)
+        if hasattr(self.model, "prefill_layer_positions"):
+            done, dense = self.model.prefill_layer_positions(t)
+            whose.update(positions=t, layer_positions=done,
+                         layer_positions_dense=dense)
         temps = np.asarray([temperature], np.float32)
         topks = np.asarray([top_k], np.int32)
         whose.update(self._name_sample(temps, topks))
@@ -926,7 +946,24 @@ class DecodeEngine:
         inputs = (tokens if prev is None else prev.ids, positions,
                   tables, key, temps, topks,
                   *self._state_arg(state_slots))
-        return rows, b, inputs, self._name_sample(temps, topks)
+        return rows, b, inputs, {**self._name_sample(temps, topks),
+                                 **self._cache_reads(rows, positions)}
+
+    def _cache_reads(self, rows, positions) -> dict:
+        """The K/V tokens this step's live rows hold and its layers
+        read, as span attributes: nothing for a model whose layers all
+        read one growing cache."""
+        reads = self._reads
+        if reads is None:
+            return {}
+        live = np.asarray([seq is not None for seq in rows])
+        ctx = positions[:len(rows)][live].astype(np.int64) + 1
+        kv = int(ctx.sum())
+        ring = int(np.minimum(ctx, reads["window"]).sum())
+        window = reads["window_layers"] * ring
+        return {"kv_tokens": kv, "ring_tokens": ring,
+                "window_read_tokens": window,
+                "kv_read_tokens": reads["kv_readers"] * kv + window}
 
     def _emit(self, rows, b, ids, step_s) -> int:
         """Hand every row its token: meters, the stream's queue, the

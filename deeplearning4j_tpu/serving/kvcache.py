@@ -44,6 +44,21 @@ write there). ``n_heads`` is the KV head count: a grouped-query model
 passes fewer KV heads than it has query heads. Gauges
 ``dl4j_state_pool_slots{state=free|live}`` / ``dl4j_state_pool_bytes``.
 
+A model whose layers do not all keep every token gets a **third kind**:
+the K/V arrays hold only its layers that grow with the context
+(``n_layers`` is their count, one table a sequence as ever), and each
+**window layer** keeps a **ring** of its last ``window`` positions in
+the sequence's slot, a slot kind written ``{"shape": (window, lanes),
+"dtype": None, "layers": n, "window": window}`` (``dtype`` None: the
+K/V type; ``layers``: this kind's own layer count, which a recurrent
+kind may name too). A ring is stored as the paged kernel reads a pool,
+``[layers, slots, window / block, block, lanes]``: slot ``s`` is blocks
+``[s * window / block, ...)`` of ``[layers, slots * window / block,
+block, lanes]``, a merge of leading axes. Its bytes a sequence are
+constant: position ``p`` lives at ``p mod window``.
+:attr:`KVBlockPool.window_bytes` and ``dl4j_window_pool_bytes`` count
+the rings; ``state_bytes`` and its gauge the recurrent kinds.
+
 The pool's device bytes are a first-class **resident class** in
 ``diagnostics.memory_report`` (next to params / updater state), looked
 up lazily via ``sys.modules`` so diagnostics keeps zero import edges
@@ -106,6 +121,13 @@ def _state_bytes_gauge() -> telemetry.Gauge:
         "(all kinds, all slots; constant for the pool's lifetime)")
 
 
+def _window_bytes_gauge() -> telemetry.Gauge:
+    return telemetry.gauge(
+        "dl4j_window_pool_bytes",
+        "preallocated device bytes of a pool's window rings (K and V, "
+        "every window layer, all slots; constant whatever the contexts)")
+
+
 def _shed_counter() -> telemetry.Counter:
     return telemetry.counter(
         "dl4j_kv_pool_shed_total",
@@ -154,13 +176,29 @@ class KVBlockPool:
         else:               # allocator-only pool (tests, sizing math)
             self.k = np.zeros(shape, dtype=dtype)
             self.v = np.zeros(shape, dtype=dtype)
-        #: recurrent-state arrays by kind, [n_layers, slots, *shape]
+        #: slot arrays by kind, [layers, slots, *shape]: recurrent
+        #: state, and the rings of window layers
         self.state_slots = int(state_slots) if state else 0
         xp = jnp if device_arrays else np
-        self.state = {
-            kind: xp.zeros((self.n_layers, self.state_slots)
-                           + tuple(shp), dtype=dt)
-            for kind, (shp, dt) in (state or {}).items()}
+        self.state = {}
+        #: the kinds that are window rings
+        self.window_kinds: set = set()
+        for kind, spec in (state or {}).items():
+            if not isinstance(spec, dict):
+                spec = {"shape": spec[0], "dtype": spec[1]}
+            shp = tuple(spec["shape"])
+            if spec.get("window"):
+                if shp[0] % self.block_size:
+                    raise ValueError(
+                        f"window {shp[0]} is not whole blocks of "
+                        f"{self.block_size}")
+                shp = (shp[0] // self.block_size, self.block_size) + shp[1:]
+                self.window_kinds.add(kind)
+            self.state[kind] = xp.zeros(
+                (int(spec.get("layers") or self.n_layers),
+                 self.state_slots) + shp,
+                dtype=spec["dtype"] if spec.get("dtype") is not None
+                else dtype)
         self._lock = threading.RLock()
         #: free state slots, LIFO (slot 0 reserved)
         self._free_slots: List[int] = list(
@@ -177,6 +215,9 @@ class KVBlockPool:
             if self.state:
                 _state_bytes_gauge().set(self.state_bytes, pool=self.name)
                 self._export_slots()
+            if self.window_kinds:
+                _window_bytes_gauge().set(self.window_bytes,
+                                          pool=self.name)
 
     # -- sizing ---------------------------------------------------------
     @property
@@ -192,7 +233,14 @@ class KVBlockPool:
     @property
     def state_bytes(self) -> int:
         """Preallocated device bytes of the recurrent-state arrays."""
-        return sum(int(a.nbytes) for a in self.state.values())
+        return sum(int(a.nbytes) for kind, a in self.state.items()
+                   if kind not in self.window_kinds)
+
+    @property
+    def window_bytes(self) -> int:
+        """Preallocated device bytes of the window layers' rings: a
+        fixed share a slot, whatever the contexts."""
+        return sum(int(self.state[kind].nbytes) for kind in self.window_kinds)
 
     @property
     def arrays(self) -> tuple:
@@ -370,7 +418,14 @@ class KVBlockPool:
                           "live": self.usable_slots - self.free_slots,
                           "reserved": 1, "total": self.state_slots},
                 "layout": {k: list(a.shape)
-                           for k, a in self.state.items()}}}
+                           for k, a in self.state.items()
+                           if k not in self.window_kinds}}}
+        if self.window_kinds:
+            state["window"] = {
+                "bytes": self.window_bytes,
+                "bytes_per_slot": self.window_bytes // self.state_slots,
+                "layout": {k: list(self.state[k].shape)
+                           for k in sorted(self.window_kinds)}}
         return {
             **state,
             "pool": self.name,

@@ -23,8 +23,10 @@ accelerator needed):
    gauge.
 
 The gate runs once for each model class with the serving contract:
-``DecoderLM`` and ``FalconH1LM`` (grouped-query attention beside a
-state-space mixer: KV blocks and a state slot a sequence).
+``DecoderLM``, ``FalconH1LM`` (grouped-query attention beside a
+state-space mixer: KV blocks and a state slot a sequence) and
+``Phi4FlashLM`` (one shared K/V layer, window rings and Mamba-1 state
+in the slot).
 
 Usage: JAX_PLATFORMS=cpu python scripts/check_generative.py
 Exit 0 = gate holds, 1 = a clause failed.
@@ -66,10 +68,23 @@ def _falcon_h1():
                               state=model.state_shapes(), state_slots=9)
 
 
+def _phi4_flash():
+    """The decoder-decoder: one K/V layer that grows, read by every
+    cross layer; window rings and Mamba-1 state in the slot."""
+    from deeplearning4j_tpu.models.phi4_flash import (Phi4FlashConfig,
+                                                      Phi4FlashLM)
+    from deeplearning4j_tpu.serving.kvcache import KVBlockPool
+    conf = Phi4FlashConfig()
+    model = Phi4FlashLM(conf)
+    return model, KVBlockPool(model.kv_layers, 64, 8, conf.n_kv_heads,
+                              conf.head_dim, name="gate",
+                              state=model.state_shapes(), state_slots=9)
+
+
 def main() -> int:
     import gc
     failures = []
-    for build in (_decoder, _falcon_h1):
+    for build in (_decoder, _falcon_h1, _phi4_flash):
         model, pool = build()
         label = type(model).__name__
         print(f"== {label}")

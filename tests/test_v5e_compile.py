@@ -32,8 +32,12 @@ from deeplearning4j_tpu.serving.kvcache import KVBlockPool
 
 LAYERS = 2
 BUCKET, PROMPT = 32, 256
-_H1 = os.path.join(os.path.dirname(__file__), "..", "chipbench", "configs",
-                   "falcon-h1-34b.json")
+_CONFIGS = os.path.join(os.path.dirname(__file__), "..", "chipbench",
+                        "configs")
+_H1 = os.path.join(_CONFIGS, "falcon-h1-34b.json")
+_P4 = os.path.join(_CONFIGS, "phi4-mini-flash.json")
+#: the fewest layers that hold every mixer kind of the flash decoder
+P4_LAYERS = 8
 
 
 @pytest.fixture(scope="module")
@@ -60,6 +64,22 @@ def _engine(kind):
         params = jax.eval_shape(model.init)
         pool = KVBlockPool(LAYERS, 385, 16, 20, 64, dtype=jnp.bfloat16,
                            name="t-v5e-gpt2", device_arrays=False)
+    elif kind == "phi4-mini-flash":
+        from deeplearning4j_tpu.models.phi4_flash import (Phi4FlashConfig,
+                                                          Phi4FlashLM)
+        cfg = dict(json.load(open(_P4)), num_hidden_layers=P4_LAYERS)
+        model = Phi4FlashLM(Phi4FlashConfig.from_published(
+            cfg, max_len=cfg["max_len"], eos_id=cfg["vocab_size"]))
+        params = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, jnp.bfloat16 if a.ndim == 2 and a.shape[0] > 16
+                else a.dtype),
+            jax.eval_shape(model.init))
+        c = model.conf
+        pool = KVBlockPool(model.kv_layers, 8193, 16, c.n_kv_heads,
+                           c.head_dim, dtype=jnp.bfloat16, name="t-v5e-p4",
+                           state=model.state_shapes(), state_slots=65,
+                           device_arrays=False)
     else:
         from deeplearning4j_tpu.models.falcon_h1 import (FalconH1Config,
                                                          FalconH1LM)
@@ -79,6 +99,15 @@ def _engine(kind):
                        prompt_buckets=(PROMPT,), decode_buckets=(BUCKET,),
                        max_seq_len=1024, paged=True)
     return model, pool, eng
+
+
+def _kernels(pool):
+    """Mosaic calls in a decode step: the paged kernel in every layer
+    that attends and a state kernel in every layer with a recurrent
+    state."""
+    if pool.window_bytes:           # 2 window, 1 full, 1 cross; 3 Mamba-1
+        return 4 + pool.state["ssm"].shape[0]
+    return LAYERS * (2 if pool.state else 1)
 
 
 def _program(kind, program, one_chip):
@@ -108,25 +137,48 @@ def _program(kind, program, one_chip):
 
 
 @pytest.mark.parametrize("program", ["commit", "decode"])
-@pytest.mark.parametrize("kind", ["gpt2-large", "falcon-h1-34b"])
+@pytest.mark.parametrize("kind", ["gpt2-large", "falcon-h1-34b",
+                                  "phi4-mini-flash"])
 def test_the_pool_is_written_in_place_and_never_relaid(one_chip, kind,
                                                        program):
     pool, jit, args = _program(kind, program, one_chip)
     with mock.patch.object(kernel_select, "interpret_mode", lambda: False), \
             mock.patch.object(kernel_select, "platform", lambda: "tpu"):
         compiled = jit.lower(*args).compile()
-    # every cache input is an output's buffer
-    assert compiled.memory_analysis().alias_size_in_bytes == \
-        pool.pool_bytes + pool.state_bytes
+    # every cache input is an output's buffer (the rings too; the
+    # device pads the flash decoder's 65 x 3 convolution rows to whole
+    # sublane tiles, 0.2 % of its cache)
+    held = pool.pool_bytes + pool.state_bytes + pool.window_bytes
+    alias = compiled.memory_analysis().alias_size_in_bytes
+    assert held <= alias <= (held if not pool.window_bytes
+                             else 1.005 * held)
     text = compiled.as_text()
-    dims = ",".join(str(n) for n in pool.k.shape)        # the whole pool
-    layer = ",".join(str(n) for n in pool.k.shape[1:])   # one layer of it
+    shapes = []                 # the whole pool, one layer of it, the rings
+    for a in [pool.k] + [pool.state[k] for k in sorted(pool.window_kinds)]:
+        shapes += [a.shape, a.shape[1:], (a.shape[0], a.shape[1] * a.shape[2])
+                   + a.shape[3:]]
+    dims = "|".join(re.escape(",".join(str(n) for n in shp))
+                    for shp in shapes)
     moved = [m.group(0) for m in re.finditer(
-        r"= bf16\[(?:%s|%s)\]\S* (copy|transpose|slice|reshape|"
-        r"dynamic-slice)\(" % (re.escape(dims), re.escape(layer)), text)]
+        r"= bf16\[(?:%s)\]\S* (copy|transpose|slice|reshape|"
+        r"dynamic-slice)\(" % dims, text)]
     assert not moved, moved[:4]
     if program == "decode":
         # the paged kernel a layer on the stacked pool (and the state
         # kernel a layer where the model has recurrent state)
         kernels = text.count("custom_call_target=\"tpu_custom_call\"")
-        assert kernels == LAYERS * (2 if pool.state else 1)
+        assert kernels == _kernels(pool)
+
+
+def test_the_mamba1_prefill_scan_compiles_at_the_published_widths(one_chip):
+    """The selective scan kernel for the described chip at the cell's
+    largest prompt bucket: 16 states x 5120 channels resident, 8 tokens a
+    grid step."""
+    from deeplearning4j_tpu.ops import ssm_pallas as sp
+    f32 = jnp.float32
+    shape = lambda *s: jax.ShapeDtypeStruct(s, f32, sharding=one_chip)  # noqa: E731
+    with mock.patch.object(kernel_select, "interpret_mode", lambda: False):
+        compiled = jax.jit(sp.selective_scan_pallas).lower(
+            shape(1, 512, 5120), shape(1, 512, 5120), shape(16, 5120),
+            shape(1, 512, 16), shape(1, 512, 16)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
