@@ -7,10 +7,10 @@ weights, and checks what comes out by the repo's own means:
   native    the host library is built by ``make`` and loaded;
   train     ResNet-50 (224 px, 1000 classes, bf16, batch 128):
             ``fit`` x3, a burst of ``fit``, ``fit_steps`` x2 — finite
-            loss after every step; the BN kernel families are
-            auto-fused and the compiled step holds their Mosaic calls;
-            step 1 agrees with an identically seeded net whose ladder
-            is killed;
+            loss after every step; the BN families take XLA's
+            lowering by the auto rung and the compiled step holds no
+            Mosaic call; an identically seeded net whose ladder is
+            forced holds the kernels and agrees with step 1;
   serve     the same network behind ``ModelRegistry`` +
             ``InferenceServer`` over loopback: concurrent ``:predict``
             requests agree with ``net.output``, ``/readyz`` is 200,
@@ -241,127 +241,139 @@ def _train_step_mosaic_calls(net):
     return mosaic_calls(net.lower_train_step())
 
 
-def phase_train(*, batch=128, hw=224, classes=1000, stages=None,
-                fit_calls=3, burst=10, steps=10,
-                force_kernels=False) -> dict:
-    """Train ResNet-50 through ``fit`` and ``fit_steps``; returns the
-    trained net under ``"net"`` for the serve phase.
+def _bn_sites(rung, fused):
+    """The BN sites of the step traced last, every one required to
+    have decided both families by ``rung``."""
+    from deeplearning4j_tpu.common import layerprof
+    sites = layerprof.kernel_decisions()
+    bn_sites = [s for s, per in sites.items() if "bn_bwd" in per]
+    require(bn_sites, "no BN site reached the ladder")
+    for s in bn_sites:
+        for fam in ("bn_fwd", "bn_bwd"):
+            d = sites[s].get(fam)
+            require(d is not None and d["fused"] == fused
+                    and d["decision"] == rung,
+                    f"{s}: {fam} is {d}, expected {rung}")
+    return bn_sites
 
-    ``force_kernels`` forces the BN families on through the ladder's
-    override rung — the CPU test's way to run the kernels (interpret
-    mode); the chip run leaves the ladder on auto and requires
-    ``auto_fused``."""
+
+def phase_train(*, batch=128, hw=224, classes=1000, stages=None,
+                fit_calls=3, burst=10, steps=10) -> dict:
+    """Train ResNet-50 through ``fit`` and ``fit_steps`` with the
+    ladder on auto; returns the trained net under ``"net"`` for the
+    serve phase.
+
+    Auto is XLA's lowering at every BN site, on the chip as on the
+    CPU (``auto_dense``: the whole step is 2.58 times faster without
+    the kernels, PERF.md section 6, PR 33), and the compiled step
+    holds no Mosaic call. The kernels stay reachable by the force
+    rung: the same step from the same seed with both BN families
+    forced must take them at every site, hold their Mosaic calls (on
+    the chip; interpret mode on the CPU) and agree with the auto
+    step."""
     import jax
     from deeplearning4j_tpu.common import layerprof
 
-    rung = "forced" if force_kernels else "auto_fused"
-    gates = ({"fused_conv": "1", "fused_bn_bwd": "1"}
-             if force_kernels else {})
     _, _, ds = _image_batch(batch, hw, classes)
 
-    with ladder(**gates):
-        layerprof.reset_decisions()
-        before = decisions_now()
-        net = _resnet(hw, classes, stages)
-        loss1, dnorm1, first = _step1(net, ds)
-        traced = decisions_since(before)
-        say("train", f"fit #1 (compile + step): {first:.1f}s "
-                     f"loss={loss1:.4f} |dparams|={dnorm1:.4f}")
-        require(np.isfinite(loss1), f"step 1 loss {loss1}")
-        say("train", f"ladder decisions in the step's trace: {traced}")
+    layerprof.reset_decisions()
+    before = decisions_now()
+    net = _resnet(hw, classes, stages)
+    loss1, dnorm1, first = _step1(net, ds)
+    traced = decisions_since(before)
+    say("train", f"fit #1 (compile + step): {first:.1f}s "
+                 f"loss={loss1:.4f} |dparams|={dnorm1:.4f}")
+    require(np.isfinite(loss1), f"step 1 loss {loss1}")
+    say("train", f"ladder decisions in the step's trace: {traced}")
 
-        # every BN site took the kernels, by the rung we expect
-        sites = layerprof.kernel_decisions()
-        bn_sites = [s for s, per in sites.items() if "bn_bwd" in per]
-        fused_calls = 0
-        for s in bn_sites:
-            for fam in ("bn_fwd", "bn_bwd"):
-                d = sites[s].get(fam)
-                require(d is not None and d["fused"]
-                        and d["decision"] == rung,
-                        f"{s}: {fam} is {d}, expected {rung}")
-                # bn_fwd = statistics + normalize, bn_bwd = sums + dx
-                fused_calls += 2
-        require(bn_sites, "no BN site reached the ladder")
-        require(set(traced["bn_fwd"]) == {rung}
-                and set(traced["bn_bwd"]) == {rung},
-                f"BN families not uniformly {rung}: {traced}")
-        # ResNet-50's convs carry no bias and identity activation
-        require(set(traced["conv_epilogue"]) <= {"structural"},
-                f"conv_epilogue: {traced['conv_epilogue']}")
+    # every BN site took XLA's lowering, by the auto rung
+    bn_sites = _bn_sites("auto_dense", False)
+    require(set(traced["bn_fwd"]) == {"auto_dense"}
+            and set(traced["bn_bwd"]) == {"auto_dense"},
+            f"BN families not uniformly auto_dense: {traced}")
+    # ResNet-50's convs carry no bias and identity activation
+    require(set(traced["conv_epilogue"]) <= {"structural"},
+            f"conv_epilogue: {traced['conv_epilogue']}")
 
-        losses = [loss1]
-        later = []
-        for _ in range(fit_calls - 1):
-            t0 = time.perf_counter()
-            net.fit(ds)
-            losses.append(float(net.score()))
-            later.append(time.perf_counter() - t0)
-            require(np.isfinite(losses[-1]),
-                    f"loss {losses[-1]} at step {len(losses)}")
-        say("train", "fit later calls (each synced on its loss): "
-                     + ", ".join(f"{t:.3f}s" for t in later)
-                     + " losses=" + ", ".join(f"{v:.4f}" for v in losses))
-
-        # what a fit() dispatch costs: a burst of un-synced calls
+    losses = [loss1]
+    later = []
+    for _ in range(fit_calls - 1):
         t0 = time.perf_counter()
-        for _ in range(burst):
-            net.fit(ds)
+        net.fit(ds)
+        losses.append(float(net.score()))
+        later.append(time.perf_counter() - t0)
+        require(np.isfinite(losses[-1]),
+                f"loss {losses[-1]} at step {len(losses)}")
+    say("train", "fit later calls (each synced on its loss): "
+                 + ", ".join(f"{t:.3f}s" for t in later)
+                 + " losses=" + ", ".join(f"{v:.4f}" for v in losses))
+
+    # what a fit() dispatch costs: a burst of un-synced calls
+    t0 = time.perf_counter()
+    for _ in range(burst):
+        net.fit(ds)
+    jax.block_until_ready(net.params)
+    t_ready = time.perf_counter()
+    loss_b = float(net.score())
+    t_read = time.perf_counter()
+    require(np.isfinite(loss_b), f"loss {loss_b} after the burst")
+    say("train", f"{burst} fit() calls, one sync: "
+                 f"{t_ready - t0:.3f}s to block_until_ready, "
+                 f"+{t_read - t_ready:.4f}s to read the loss on "
+                 f"the host (loss={loss_b:.4f})")
+
+    walls = []
+    for _ in range(2):
+        t0 = time.perf_counter()
+        net.fit_steps(ds, steps)
         jax.block_until_ready(net.params)
         t_ready = time.perf_counter()
-        loss_b = float(net.score())
-        t_read = time.perf_counter()
-        require(np.isfinite(loss_b), f"loss {loss_b} after the burst")
-        say("train", f"{burst} fit() calls, one sync: "
-                     f"{t_ready - t0:.3f}s to block_until_ready, "
-                     f"+{t_read - t_ready:.4f}s to read the loss on "
-                     f"the host (loss={loss_b:.4f})")
+        loss_s = float(net.score())
+        walls.append((t_ready - t0, time.perf_counter() - t_ready))
+        require(np.isfinite(loss_s), f"fit_steps loss {loss_s}")
+    say("train", f"fit_steps({steps}) first call (compile + run): "
+                 f"{walls[0][0]:.1f}s; later call: "
+                 f"{walls[1][0]:.3f}s to block_until_ready, "
+                 f"+{walls[1][1]:.4f}s to read the loss "
+                 f"(loss={loss_s:.4f})")
 
-        walls = []
-        for _ in range(2):
-            t0 = time.perf_counter()
-            net.fit_steps(ds, steps)
-            jax.block_until_ready(net.params)
-            t_ready = time.perf_counter()
-            loss_s = float(net.score())
-            walls.append((t_ready - t0, time.perf_counter() - t_ready))
-            require(np.isfinite(loss_s), f"fit_steps loss {loss_s}")
-        say("train", f"fit_steps({steps}) first call (compile + run): "
-                     f"{walls[0][0]:.1f}s; later call: "
-                     f"{walls[1][0]:.3f}s to block_until_ready, "
-                     f"+{walls[1][1]:.4f}s to read the loss "
-                     f"(loss={loss_s:.4f})")
+    n_auto, t_hlo = _train_step_mosaic_calls(net)
+    say("train", f"compiled train step on auto: {n_auto} Mosaic custom "
+                 f"calls for {len(bn_sites)} BN sites (lower+compile "
+                 f"{t_hlo:.1f}s)")
+    require(n_auto == 0, f"{n_auto} Mosaic calls in the auto step")
 
-        n_mosaic, t_hlo = _train_step_mosaic_calls(net)
-        want = expected_mosaic(fused_calls)
-        say("train", f"compiled train step: {n_mosaic} Mosaic custom "
-                     f"calls for {len(bn_sites)} BN sites x 4 kernels "
-                     f"(expected {want}; lower+compile {t_hlo:.1f}s)")
-        require(n_mosaic == want,
-                f"{n_mosaic} Mosaic calls in the compiled step, "
-                f"{want} kernel sites counted as fused")
-
-    # the same step with both BN families killed, identical seed
-    with ladder(fused_conv="0", fused_bn_bwd="0"):
+    # the same step with both BN families forced, identical seed
+    with ladder(fused_conv="1", fused_bn_bwd="1"):
+        layerprof.reset_decisions()
         before = decisions_now()
-        dense = _resnet(hw, classes, stages)
-        loss_d, dnorm_d, wall_d = _step1(dense, ds)
-        killed = decisions_since(before)
-        n_dense, _ = _train_step_mosaic_calls(dense)
-    del dense
-    require(set(killed["bn_fwd"]) == {"killed"}
-            and set(killed["bn_bwd"]) == {"killed"},
-            f"ladder kill did not take: {killed}")
-    require(n_dense == 0, f"{n_dense} Mosaic calls in the dense step")
-    e_loss = abs(loss1 - loss_d) / max(abs(loss_d), 1e-30)
-    e_norm = abs(dnorm1 - dnorm_d) / max(dnorm_d, 1e-30)
-    say("train", f"dense ladder, same seed ({wall_d:.1f}s): "
-                 f"loss={loss_d:.4f} |dparams|={dnorm_d:.4f}; "
-                 f"kernels vs dense: loss rel {e_loss:.2e}, "
+        forced_net = _resnet(hw, classes, stages)
+        loss_k, dnorm_k, wall_k = _step1(forced_net, ds)
+        forced = decisions_since(before)
+        forced_sites = _bn_sites("forced", True)
+        n_mosaic, _ = _train_step_mosaic_calls(forced_net)
+    del forced_net
+    require(set(forced["bn_fwd"]) == {"forced"}
+            and set(forced["bn_bwd"]) == {"forced"}
+            and len(forced_sites) == len(bn_sites),
+            f"ladder force did not take: {forced}")
+    # bn_fwd = statistics + normalize, bn_bwd = sums + dx
+    want = expected_mosaic(4 * len(forced_sites))
+    say("train", f"forced ladder, same seed ({wall_k:.1f}s): "
+                 f"{n_mosaic} Mosaic custom calls for "
+                 f"{len(forced_sites)} BN sites x 4 kernels (expected "
+                 f"{want})")
+    require(n_mosaic == want,
+            f"{n_mosaic} Mosaic calls in the forced step, {want} "
+            f"kernel sites counted as fused")
+    e_loss = abs(loss_k - loss1) / max(abs(loss1), 1e-30)
+    e_norm = abs(dnorm_k - dnorm1) / max(dnorm1, 1e-30)
+    say("train", f"loss={loss_k:.4f} |dparams|={dnorm_k:.4f}; "
+                 f"kernels vs auto (dense): loss rel {e_loss:.2e}, "
                  f"|dparams| rel {e_norm:.2e} (tol {STEP_REL_TOL})")
     require(e_loss <= STEP_REL_TOL and e_norm <= STEP_REL_TOL,
-            f"kernel step disagrees with dense step: loss {loss1} vs "
-            f"{loss_d}, |dparams| {dnorm1} vs {dnorm_d}")
+            f"kernel step disagrees with dense step: loss {loss_k} vs "
+            f"{loss1}, |dparams| {dnorm_k} vs {dnorm1}")
     return {"ok": True, "net": net, "first_call_s": first,
             "bn_sites": len(bn_sites), "mosaic_calls": n_mosaic}
 
@@ -483,18 +495,22 @@ def phase_serve(net, *, hw=224, n_clients=6, rows=(1, 2, 3, 4),
             require(worst <= STEP_REL_TOL,
                     f"served outputs differ from net.output: {worst}")
 
-            # inference BN is an epilogue site: fused on one chip, a
-            # counted structural demotion when the mesh partitions it
+            # inference BN is an epilogue site: XLA's lowering by the
+            # auto rung on one chip (PERF.md section 6, PR 33), the
+            # kernel by the force rung, a counted structural demotion
+            # when the mesh partitions it
             ce = traced["conv_epilogue"]
-            rung = "forced" if force_kernels else "auto_fused"
+            rung = "forced" if force_kernels else "auto_dense"
             if n_dev > 1:
                 require(set(ce) == {"structural"},
                         f"partitioned serve took kernels: {ce}")
                 fused = 0
             else:
-                require(ce.get(rung, 0) > 0,
-                        f"no {rung} conv_epilogue site: {ce}")
-                fused = ce[rung] // len(ver.batcher.buckets)
+                require(ce.get(rung, 0) > 0
+                        and set(ce) <= {rung, "structural"},
+                        f"conv_epilogue sites not {rung}: {ce}")
+                fused = (ce[rung] // len(ver.batcher.buckets)
+                         if force_kernels else 0)
             b = ver.batcher
             placed, _ = b._place_chunk(
                 np.zeros((b.buckets[0], hw, hw, 3), np.float32))

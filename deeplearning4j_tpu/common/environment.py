@@ -125,10 +125,13 @@ class Environment:
       each mutating pass), DL4J_TPU_FLASH_ATTENTION (tri-state: =1
       forces the Pallas flash sdpa backend, =0 kills it, unset =
       auto heuristic), DL4J_TPU_FUSED_BN_BWD (fused BN backward:
-      default on-for-TPU; =0 kills, =1 forces anywhere),
+      unset = XLA's lowering on every platform, the chip's whole
+      ResNet-50 step being 2.58x faster that way, PERF.md section 6
+      PR 33; =1 forces the kernels anywhere, =0 kills),
       DL4J_TPU_FUSED_CONV (tri-state like the flash gate: the Pallas
       conv/BN/ReLU epilogue family — conv-bias-act, BN statistics +
-      normalize, matmul+epilogue for aligned 1x1 convs),
+      normalize, matmul+epilogue for aligned 1x1 convs; unset = XLA's
+      lowering on every platform, same reading),
       DL4J_TPU_PAGED_ATTENTION (tri-state: the paged decode-attention
       Pallas kernel for the serving KV pool), DL4J_TPU_SSM_STATE
       (tri-state: the in-place recurrent-state update kernel for the

@@ -462,20 +462,20 @@ class BatchNormalization(Layer):
 
     def forward(self, params, x, *, training, rng=None, state=None):
         if training:
-            # shared forward math (one-pass E[x]/E[x^2] for bf16 — one
-            # fused HBM read, the dominant ResNet-50 cost per
-            # benchmarks/profile_resnet.py — two-pass for f32; see
-            # ops/bn_pallas.py:bn_forward_math). With
-            # DL4J_TPU_FUSED_BN_BWD the SAME forward runs under a
-            # custom_vjp whose backward is the hand Pallas kernel
-            # pair (measured slower than XLA's autodiff on ResNet-50;
-            # kept as the tuning seam — BENCH_notes_r03.md), and the
-            # bn_fwd ladder (DL4J_TPU_FUSED_CONV family) additionally
-            # routes its statistics + normalize through the one-pass
-            # Pallas kernels in ops/conv_pallas.py. Without the fused
-            # backward, maybe_fused_bn_train runs the same kernels
-            # with the relu/identity activation streamed into the
-            # normalize epilogue.
+            # shared forward math (one-pass E[x]/E[x^2] for bf16,
+            # two-pass for f32; ops/bn_pallas.py:bn_forward_math)
+            # under stock autodiff: what the ladder's auto rung picks
+            # on every platform, the chip included, where the whole
+            # ResNet-50 step is 2.58 times faster this way than with
+            # the kernels (PERF.md section 6, PR 33), and what a
+            # GSPMD-partitioned step always ran. The force rung keeps
+            # the hand kernels reachable: DL4J_TPU_FUSED_BN_BWD=1 runs
+            # the SAME forward under a custom_vjp whose backward is
+            # the Pallas pair, DL4J_TPU_FUSED_CONV=1 (family bn_fwd)
+            # routes the statistics and the normalize through the
+            # one-pass kernels of ops/conv_pallas.py; without the
+            # fused backward, maybe_fused_bn_train runs those with the
+            # relu/identity activation streamed into the epilogue.
             from deeplearning4j_tpu.ops.bn_pallas import (
                 bn_forward_math, bn_train_normalize,
                 fused_bn_bwd_enabled)

@@ -20,7 +20,13 @@ from the sums (the algebra: dx = γr(dy − Σdy/M − x̂·Σdyx̂/M) plus the
 running-stat cotangent terms, rearranged into one FMA form so the
 inner loop is two mul-adds per element).
 
-Enabled behind ``DL4J_TPU_FUSED_BN_BWD=1`` (Environment
+What the profile of one site promised did not show in the whole
+step: on the chip the ResNet-50 b256 step runs 2.58 times faster
+without the kernels (PERF.md section 6, PR 33: each ``pallas_call``
+costs a relayout to ``[M, C]`` and back, and cuts the ReLU masks and
+residual adds out of XLA's fusions), so the ladder's auto rung picks
+XLA's autodiff everywhere and the pair runs only when forced:
+``DL4J_TPU_FUSED_BN_BWD=1`` (Environment
 ``extra["fused_bn_bwd"]``).  Off-TPU the kernels run in Pallas
 interpret mode (``kernel_select.interpret_mode`` — the platform alone
 decides), so the f64 gradient checks exercise the SAME code path the
@@ -37,23 +43,40 @@ from jax.experimental import pallas as pl
 from deeplearning4j_tpu.ops import kernel_select
 
 
-def fused_bn_bwd_enabled() -> bool:
-    """Default ON on TPU (the kernel is gradient-checked and the
-    ~21 ms HBM re-read saving — BENCH_notes_r02 — is otherwise dead);
-    off elsewhere, where the dense XLA lowering wins and interpret
-    mode would crawl. DL4J_TPU_FUSED_BN_BWD=0 is the kill switch,
-    =1 forces it on anywhere (Environment ``extra["fused_bn_bwd"]``
-    overrides the env var).  Since the ISSUE-13 unification the
-    decision runs through the shared ``ops/kernel_select.py`` ladder
-    (family ``bn_bwd``) and is counted in
-    ``dl4j_kernel_select_total``."""
-    def _auto():
-        platform = kernel_select.platform()
-        if platform == "tpu":
-            return True, "auto: tpu — fused backward pays (r02)"
+def auto_rung(platform: str, tpu_reading: str):
+    """The auto rung of the conv/BN families (``bn_bwd`` here,
+    ``bn_fwd`` and ``conv_epilogue`` in ops/conv_pallas.py), by the
+    ladder's rule: on for the TPU only where a chip run recorded in
+    PERF.md shows the whole program faster with the kernels. None
+    does, so every platform gets the dense lowering and the reason
+    names the reading (``tpu_reading``) that says so."""
+    if platform != "tpu":
         return False, f"auto: platform '{platform}' is not tpu"
+    return False, f"auto: dense: {tpu_reading}"
 
-    return kernel_select.select("bn_bwd", auto=_auto).fused
+
+#: the four corners of the two gates on ``resnet50.train-1chip``
+#: (PERF.md section 6, PR 33): 2701.5 samples/s with both families
+#: off, 1047.2 with both on, 1366.8 with this backward alone, 1008.4
+#: with the forward kernels alone
+BN_TPU_READING = ("XLA's lowering 2.58x the kernels' on ResNet-50 b256, "
+                  "PERF.md §6 PR 33")
+
+
+def fused_bn_bwd_enabled() -> bool:
+    """Whether training-mode BN runs under the ``custom_vjp`` whose
+    backward is the Pallas kernel pair: family ``bn_bwd`` of the
+    ``ops/kernel_select.py`` ladder, counted in
+    ``dl4j_kernel_select_total``. Off by default on every platform:
+    on the chip the whole ResNet-50 step is 2.58 times faster under
+    XLA's autodiff of ``bn_forward_math`` (:data:`BN_TPU_READING`),
+    and elsewhere interpret mode would crawl.
+    ``DL4J_TPU_FUSED_BN_BWD=1`` (or Environment
+    ``extra["fused_bn_bwd"]``, which overrides the env var) forces
+    the kernels on anywhere, ``=0`` is the kill switch."""
+    return kernel_select.select(
+        "bn_bwd", auto=lambda: auto_rung(kernel_select.platform(),
+                                         BN_TPU_READING)).fused
 
 
 def _block_rows(M: int, C: int) -> int:

@@ -33,10 +33,13 @@ Dispatch runs through the unified ``ops/kernel_select.py`` ladder
 ``DL4J_TPU_FUSED_CONV`` tri-state gate): structural gates — dtype,
 sublane channel alignment, streamable activation (relu/identity),
 training vs inference BN — demote to the dense lowering with a
-counted reason; unset, the auto heuristic engages on TPU above a
-size floor.  Off-TPU the kernels run in Pallas interpret mode, so
-the f64 gradient checks exercise the SAME code path the chip runs
-(the bn_pallas.py pattern).
+counted reason; unset, the auto rung picks XLA's own lowering on
+every platform, because no chip run shows a whole program faster
+with these kernels (PERF.md section 6, PR 33: the ``pallas_call``
+border costs a relayout each way and the fusions XLA makes across
+it); ``=1`` forces them on.  Off-TPU the kernels run in Pallas
+interpret mode, so the f64 gradient checks exercise the SAME code
+path the chip runs (the bn_pallas.py pattern).
 """
 from __future__ import annotations
 
@@ -49,15 +52,19 @@ from jax.experimental import pallas as pl
 
 from deeplearning4j_tpu.common import telemetry
 from deeplearning4j_tpu.ops import kernel_select
-from deeplearning4j_tpu.ops.bn_pallas import _block_rows
+from deeplearning4j_tpu.ops.bn_pallas import (BN_TPU_READING, _block_rows,
+                                              auto_rung)
 
 #: activations the epilogue kernels stream (relu as a max against the
 #: zero of the accumulator dtype; identity as a pure FMA)
 STREAMABLE_ACTIVATIONS = ("relu", "identity")
-#: below this many output elements the kernel-launch bookkeeping beats
-#: the saved HBM round-trip and the XLA fusion wins (r06 proxy figure,
-#: pending a chip window)
-FUSED_CONV_MIN_ELEMENTS = 1 << 16
+#: what the chip said of whole programs with the family on
+#: (``scripts/probe_conv_epilogue.py``; PERF.md section 6, PR 33):
+#: ResNet-50 forward-only at b256 13714.9 img/s without it and 3070.1
+#: with it, a VGG16 ``fit`` step at b32 728.2 and 433.7 samples/s, an
+#: AlexNet one at b256 15818.8 and 7716.5
+CONV_TPU_READING = ("XLA's lowering 4.47x the kernel's on ResNet-50 b256 "
+                    "forward, 1.68x on a VGG16 fit step, PERF.md §6 PR 33")
 #: MXU lane width — the pointwise-matmul path requires both contracted
 #: and output channels to tile it exactly
 MXU_LANE = 128
@@ -88,16 +95,6 @@ def _family_structural(shape, dtype, platform) -> Optional[str]:
     return None
 
 
-def _auto_heuristic(n_elements, platform):
-    if platform != "tpu":
-        return False, f"auto: platform '{platform}' is not tpu"
-    if n_elements < FUSED_CONV_MIN_ELEMENTS:
-        return False, (f"auto: {n_elements} elements below the fusion "
-                       f"floor {FUSED_CONV_MIN_ELEMENTS}")
-    return True, (f"auto: tpu, {n_elements} elements >= "
-                  f"{FUSED_CONV_MIN_ELEMENTS}")
-
-
 def select_conv_epilogue(out_shape, dtype, act_name: str, *,
                          has_epilogue: bool = True,
                          platform: Optional[str] = None,
@@ -115,14 +112,11 @@ def select_conv_epilogue(out_shape, dtype, act_name: str, *,
         structural = f"activation '{act_name}' is not streamable"
     else:
         structural = _family_structural(out_shape, dtype, platform)
-    n = 1
-    for d in out_shape:
-        n *= int(d)
     if override is None and use_env_override:
         override = kernel_select.gate_override("conv_epilogue")
     return kernel_select.select(
         "conv_epilogue", structural=structural,
-        auto=lambda: _auto_heuristic(n, platform),
+        auto=lambda: auto_rung(platform, CONV_TPU_READING),
         override=override, use_env_override=False, record=record)
 
 
@@ -141,14 +135,11 @@ def select_bn_forward(shape, dtype, *, training: bool,
                       "(no batch-stats pass)")
     else:
         structural = _family_structural(shape, dtype, platform)
-    n = 1
-    for d in shape:
-        n *= int(d)
     if override is None and use_env_override:
         override = kernel_select.gate_override("bn_fwd")
     return kernel_select.select(
         "bn_fwd", structural=structural,
-        auto=lambda: _auto_heuristic(n, platform),
+        auto=lambda: auto_rung(platform, BN_TPU_READING),
         override=override, use_env_override=False, record=record)
 
 

@@ -18,9 +18,13 @@ different shapes.  The ladder is the cuDNN-helper dispatch discipline
      so tests and embedding apps can flip gates without touching
      ``os.environ``.
   3. **measured auto-heuristic** — kernel-specific, supplied by the
-     caller as a thunk returning ``(fused, reason)``; thresholds are
-     backed by bench rounds (FLASH_MIN_SEQ by BENCH_notes_r03, the
-     conv-family on-TPU default by BENCH_notes_r06).
+     caller as a thunk returning ``(fused, reason)``. The rule: a
+     family's auto rung is on for the TPU only where a chip run
+     recorded in PERF.md shows the whole program faster with it
+     (``paged_attention`` and ``ssm_state`` by the served cells,
+     section 6, PR 27-32; FLASH_MIN_SEQ still by BENCH_notes_r03).
+     ``bn_bwd``, ``bn_fwd`` and ``conv_epilogue`` are off since
+     PR 33, and their reason strings name the reading.
 
 Every decision increments ``dl4j_kernel_select_total{kernel,decision}``
 so a profile that shows a dense conv where a fused one was expected is

@@ -33,7 +33,12 @@
 #      central-difference check) and every kernel family must
 #      dispatch through the unified kernel-select ladder with
 #      counted decisions (the ISSUE 13 acceptance bar,
-#      tests/test_conv_pallas.py + tests/test_kernel_select.py);
+#      tests/test_conv_pallas.py + tests/test_kernel_select.py). The
+#      families' auto rung is XLA's lowering on every platform since
+#      PR 33 (the chip's whole ResNet-50 step is 2.58x faster without
+#      them, PERF.md section 6); the force rung (=1) keeps them
+#      reachable and this gate keeps them honest until ROADMAP C4
+#      deletes them;
 #   7. layer-attribution conformance gate: per-layer flops/bytes on
 #      LeNet + BERT-tiny must sum to the whole-model cost_analysis
 #      within 1%, with the named-scope annotations actually reaching

@@ -175,14 +175,16 @@ class TestBackendSelection:
 
 
 class TestFusedBnBwdDefault:
-    """DL4J_TPU_FUSED_BN_BWD semantics change: default ON on TPU, off
-    elsewhere; =0 stays the kill switch, =1 forces anywhere."""
+    """DL4J_TPU_FUSED_BN_BWD: the auto rung is XLA's lowering on every
+    platform, the TPU included (PERF.md section 6, PR 33); =0 stays
+    the kill switch, =1 forces the kernels anywhere."""
 
-    def test_default_tracks_platform(self, monkeypatch):
-        from deeplearning4j_tpu.ops import bn_pallas
+    def test_default_is_dense_on_every_platform(self, monkeypatch):
+        from deeplearning4j_tpu.ops import bn_pallas, kernel_select
         monkeypatch.delenv("DL4J_TPU_FUSED_BN_BWD", raising=False)
-        assert bn_pallas.fused_bn_bwd_enabled() == \
-            (jax.devices()[0].platform == "tpu")
+        assert bn_pallas.fused_bn_bwd_enabled() is False
+        monkeypatch.setattr(kernel_select, "platform", lambda: "tpu")
+        assert bn_pallas.fused_bn_bwd_enabled() is False
         monkeypatch.setenv("DL4J_TPU_FUSED_BN_BWD", "1")
         assert bn_pallas.fused_bn_bwd_enabled() is True
         monkeypatch.setenv("DL4J_TPU_FUSED_BN_BWD", "0")

@@ -141,3 +141,71 @@ class TestFusedBnBwd:
                                    rtol=1e-5, atol=1e-5)
         np.testing.assert_allclose(np.asarray(st["mean"]),
                                    np.asarray(st2["mean"]), rtol=1e-5)
+
+
+class TestAutoDenseAgainstForcedKernels:
+    """The ladder's auto rung runs ``bn_forward_math`` under stock
+    autodiff (PERF.md section 6, PR 33); the force rung still reaches
+    the kernels. A conv + BN residual block gives the same gradients
+    either way, within the tolerance the kernel's own checks use."""
+
+    @pytest.mark.parametrize("act", ["relu", "identity"])
+    def test_block_gradients_agree(self, act):
+        from deeplearning4j_tpu.activations import Activation
+        from deeplearning4j_tpu.nn.conf.layers import BatchNormalization
+        from deeplearning4j_tpu.ops import conv_pallas, kernel_select
+        bn = BatchNormalization(activation=Activation(act))
+        c = 16
+        x = R.randn(4, 8, 8, c).astype(np.float32)
+        params = {
+            "w1": (0.2 * R.randn(3, 3, c, c)).astype(np.float32),
+            "w2": (0.2 * R.randn(1, 1, c, c)).astype(np.float32),
+            "bn1": {"gamma": (1 + 0.1 * R.randn(c)).astype(np.float32),
+                    "beta": (0.1 * R.randn(c)).astype(np.float32)},
+            "bn2": {"gamma": (1 + 0.1 * R.randn(c)).astype(np.float32),
+                    "beta": (0.1 * R.randn(c)).astype(np.float32)}}
+        state = {"mean": jnp.zeros(c), "var": jnp.ones(c)}
+
+        def conv(a, w):
+            return conv_pallas.conv_forward(
+                a, w, window_strides=(1, 1), padding="SAME",
+                rhs_dilation=(1, 1),
+                dimension_numbers=("NHWC", "HWIO", "NHWC"))
+
+        def loss(p, a):
+            h, st1 = bn.forward(p["bn1"], conv(a, p["w1"]),
+                                training=True, state=state)
+            h, st2 = bn.forward(p["bn2"], conv(h, p["w2"]),
+                                training=True, state=state)
+            # the running statistics' cotangents flow too
+            return (jnp.sum(jnp.square(h + a)) + jnp.sum(st1["mean"])
+                    - jnp.sum(st2["var"]))
+
+        def grads(**gates):
+            env = Environment.get()
+            env.extra.update(gates)
+            try:
+                before = {k: kernel_select.decisions(k)
+                          for k in ("bn_fwd", "bn_bwd")}
+                out = jax.grad(loss, argnums=(0, 1))(params, x)
+                took = {k: {d: n - before[k][d] for d, n in
+                            kernel_select.decisions(k).items()
+                            if n != before[k][d]} for k in before}
+            finally:
+                for k in gates:
+                    env.extra.pop(k, None)
+            return out, took
+
+        dense, took = grads()
+        assert {k: set(v) for k, v in took.items()} == {
+            "bn_fwd": {"auto_dense"}, "bn_bwd": {"auto_dense"}}
+        fused, took = grads(fused_conv="1", fused_bn_bwd="1")
+        assert took == {"bn_fwd": {"forced": 2}, "bn_bwd": {"forced": 2}}
+        # a leaf whose gradient is analytically zero (bn1's beta before
+        # a second BN) is rounding on both sides: one scale for all
+        scale = max(float(np.abs(np.asarray(d)).max())
+                    for d in jax.tree_util.tree_leaves(dense))
+        for d, f in zip(jax.tree_util.tree_leaves(dense),
+                        jax.tree_util.tree_leaves(fused)):
+            np.testing.assert_allclose(np.asarray(f), np.asarray(d),
+                                       rtol=2e-4, atol=2e-4 * scale)

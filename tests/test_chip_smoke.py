@@ -70,7 +70,8 @@ class TestPhasesTiny:
     def test_train_then_serve(self):
         r = chip_smoke.phase_train(
             batch=4, hw=16, classes=10, stages=TINY_STAGES,
-            fit_calls=2, burst=2, steps=2, force_kernels=True)
+            fit_calls=2, burst=2, steps=2)
+        # auto_dense at every site, then the kernels by the force rung
         assert r["ok"] and r["bn_sites"] == 5
         assert r["mosaic_calls"] == 0         # interpret mode
         assert chip_smoke.phase_serve(

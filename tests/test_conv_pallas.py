@@ -23,9 +23,9 @@ R = np.random.RandomState(13)
 
 @pytest.fixture
 def fused_conv():
-    """Force the conv-epilogue family on (the auto heuristic keeps it
-    off-CPU off, so the fused path needs the force rung to run under
-    tier-1)."""
+    """Force the conv-epilogue family on (the auto rung is XLA's
+    lowering on every platform, so the fused path needs the force
+    rung to run at all)."""
     env = Environment.get()
     env.extra["fused_conv"] = "1"
     yield
@@ -327,9 +327,10 @@ class TestBatchNormLayerParity:
 
     def test_composes_with_fused_bn_backward(self):
         """DL4J_TPU_FUSED_CONV stats forward + DL4J_TPU_FUSED_BN_BWD
-        backward: the full hand-kernel round trip tracks the dense
-        autodiff (the ISSUE-13 'composes with bn_pallas backward'
-        claim)."""
+        backward, both by the force rung: the full hand-kernel round
+        trip tracks the dense autodiff, which is what both gates
+        unset (the auto rung, on the chip too since PR 33) and both
+        gates killed give, bit for bit."""
         bn, p, st = self._bn(Activation.RELU)
         x = R.randn(4, 8, 8, 16).astype(np.float32)
 
@@ -338,8 +339,15 @@ class TestBatchNormLayerParity:
             return jnp.sum(y ** 2)
 
         env = Environment.get()
+        assert "fused_conv" not in env.extra
+        assert "fused_bn_bwd" not in env.extra
+        ga = jax.grad(loss, argnums=(0, 1))(p, x)
         env.extra["fused_bn_bwd"] = "0"
         gd = _with_gate("0", jax.grad(loss, argnums=(0, 1)), p, x)
+        for leaf_a, leaf_d in zip(jax.tree_util.tree_leaves(ga),
+                                  jax.tree_util.tree_leaves(gd)):
+            np.testing.assert_array_equal(np.asarray(leaf_a),
+                                          np.asarray(leaf_d))
         env.extra["fused_bn_bwd"] = "1"
         try:
             gc = _with_gate("1", jax.grad(loss, argnums=(0, 1)), p, x)

@@ -14,7 +14,8 @@ from deeplearning4j_tpu.common.environment import Environment
 from deeplearning4j_tpu.ops import conv_pallas, kernel_select
 from deeplearning4j_tpu.ops.attention_pallas import (
     flash_attention_override, select_attention_backend)
-from deeplearning4j_tpu.ops.bn_pallas import fused_bn_bwd_enabled
+from deeplearning4j_tpu.ops.bn_pallas import (BN_TPU_READING,
+                                              fused_bn_bwd_enabled)
 
 
 @pytest.fixture(autouse=True)
@@ -71,6 +72,11 @@ class TestLadder:
             override=None, use_env_override=False))
         assert sel.fused and sel.decision == "auto_fused"
         assert counts == {"auto_fused": 1}
+        sel, counts = _delta("conv_epilogue", lambda: kernel_select.select(
+            "conv_epilogue", auto=lambda: (False, "auto: dense: measured"),
+            override=None, use_env_override=False))
+        assert not sel.fused and sel.reason == "auto: dense: measured"
+        assert counts == {"auto_dense": 1}
 
     def test_extra_overrides_env_var(self, monkeypatch):
         monkeypatch.setenv("DL4J_TPU_FUSED_CONV", "0")
@@ -130,23 +136,25 @@ class TestConvFamilyGates:
             (2, 8, 8, 16), jnp.float32, training=True,
             platform="tpu", override=True).fused
 
-    def test_auto_heuristic_platform_and_floor(self):
-        kw = dict(out_shape=(256, 1024), dtype=jnp.float32,
-                  act_name="relu")
-        sel = conv_pallas.select_conv_epilogue(
-            platform="cpu", override=None, use_env_override=False,
-            **kw)
-        assert not sel.fused and "not tpu" in sel.reason
-        sel = conv_pallas.select_conv_epilogue(
-            platform="tpu", override=None, use_env_override=False,
-            **kw)
-        assert sel.fused and sel.decision == "auto_fused"
-        small = dict(out_shape=(8, 16), dtype=jnp.float32,
-                     act_name="relu")
-        sel = conv_pallas.select_conv_epilogue(
-            platform="tpu", override=None, use_env_override=False,
-            **small)
-        assert not sel.fused and "below the fusion floor" in sel.reason
+    def test_auto_rung_is_dense_and_names_its_chip_reading(self):
+        """A family's auto rung is on for the TPU only where a chip
+        run in PERF.md shows the whole program faster with it: none
+        does for the conv family, at any size."""
+        for shape in ((256, 1024), (8, 16), (256, 56, 56, 256)):
+            kw = dict(out_shape=shape, dtype=jnp.float32,
+                      act_name="relu", override=None,
+                      use_env_override=False)
+            sel = conv_pallas.select_conv_epilogue(platform="cpu", **kw)
+            assert not sel.fused and "not tpu" in sel.reason
+            sel = conv_pallas.select_conv_epilogue(platform="tpu", **kw)
+            assert not sel.fused and sel.decision == "auto_dense"
+            assert sel.reason == ("auto: dense: "
+                                  + conv_pallas.CONV_TPU_READING)
+            assert "PERF.md" in sel.reason and "PR 33" in sel.reason
+            # the force rung still reaches the kernel
+            assert conv_pallas.select_conv_epilogue(
+                shape, jnp.float32, "relu", platform="tpu",
+                override=True).decision == "forced"
 
     def test_counter_increments_per_decision(self):
         _, counts = _delta("conv_epilogue", lambda: [
@@ -210,14 +218,63 @@ class TestBnBwdGateMirrored:
         monkeypatch.setenv("DL4J_TPU_FUSED_BN_BWD", "0")
         assert fused_bn_bwd_enabled() is False
         monkeypatch.delenv("DL4J_TPU_FUSED_BN_BWD", raising=False)
-        # auto rung: ON exactly on tpu
-        expected = jax.devices()[0].platform == "tpu"
-        assert fused_bn_bwd_enabled() is expected
+        # auto rung: XLA's lowering, on the cpu and on the tpu
+        assert fused_bn_bwd_enabled() is False
+        monkeypatch.setattr(kernel_select, "platform", lambda: "tpu")
+        _, counts = _delta("bn_bwd", fused_bn_bwd_enabled)
+        assert counts == {"auto_dense": 1}
 
     def test_decisions_counted(self, monkeypatch):
         monkeypatch.setenv("DL4J_TPU_FUSED_BN_BWD", "1")
         _, counts = _delta("bn_bwd", fused_bn_bwd_enabled)
         assert counts == {"forced": 1}
+
+
+#: every distinct BN site of ``resnet50.train-1chip`` (b256, 224 px)
+RESNET50_BN_SITES = [
+    (256, 112, 112, 64), (256, 56, 56, 64), (256, 56, 56, 256),
+    (256, 28, 28, 128), (256, 28, 28, 512), (256, 14, 14, 256),
+    (256, 14, 14, 1024), (256, 7, 7, 512), (256, 7, 7, 2048)]
+
+
+class TestBnFamiliesLeaveTheOneChipStep:
+    """On a TPU the auto rung of ``bn_fwd`` and ``bn_bwd`` is XLA's
+    lowering at every BN site of the training cell (PERF.md section 6,
+    PR 33: the whole step is 2.58 times faster without the kernels);
+    the force rung still gives the kernels, and the counter says
+    which rung decided. Traced through the layer by shape alone."""
+
+    @staticmethod
+    def _trace(shape, monkeypatch, **gates):
+        from deeplearning4j_tpu.nn.conf.layers import BatchNormalization
+        monkeypatch.setattr(kernel_select, "platform", lambda: "tpu")
+        Environment.get().extra.update(gates)
+        bn = BatchNormalization(activation=Activation.RELU)
+        c = shape[-1]
+        params = {"gamma": jnp.ones(c), "beta": jnp.zeros(c)}
+        state = {"mean": jnp.zeros(c), "var": jnp.ones(c)}
+        (out, bwd), fwd = _delta("bn_fwd", lambda: _delta(
+            "bn_bwd", lambda: jax.eval_shape(
+                lambda x: bn.forward(params, x, training=True,
+                                     state=state)[0],
+                jax.ShapeDtypeStruct(shape, jnp.bfloat16))))
+        assert out.shape == shape and out.dtype == jnp.bfloat16
+        return {"bn_fwd": fwd, "bn_bwd": bwd}
+
+    @pytest.mark.parametrize("shape", RESNET50_BN_SITES,
+                             ids=lambda s: "x".join(map(str, s)))
+    def test_auto_is_dense_and_force_is_the_kernel(self, shape,
+                                                   monkeypatch):
+        took = self._trace(shape, monkeypatch)
+        assert set(took["bn_bwd"]) == {"auto_dense"}
+        assert set(took["bn_fwd"]) == {"auto_dense"}
+        sel = conv_pallas.select_bn_forward(shape, jnp.bfloat16,
+                                            training=True, record=False)
+        assert sel.reason == "auto: dense: " + BN_TPU_READING
+        assert "PERF.md" in sel.reason and "PR 33" in sel.reason
+        took = self._trace(shape, monkeypatch, fused_conv="1",
+                           fused_bn_bwd="1")
+        assert took == {"bn_fwd": {"forced": 1}, "bn_bwd": {"forced": 1}}
 
 
 class TestFusedSitesCounter:

@@ -182,3 +182,44 @@ def test_the_mamba1_prefill_scan_compiles_at_the_published_widths(one_chip):
             shape(1, 512, 5120), shape(1, 512, 5120), shape(16, 5120),
             shape(1, 512, 16), shape(1, 512, 16)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("gates, per_site", [
+    ({}, 0), ({"fused_conv": "1", "fused_bn_bwd": "1"}, 4)],
+    ids=["auto", "forced"])
+def test_the_bn_kernels_are_in_the_train_step_only_when_forced(
+        one_chip, gates, per_site):
+    """The train step of a small ResNet for the described chip: on
+    auto the ladder picks XLA's lowering at every BN site, as on the
+    chip since PR 33 (PERF.md section 6), and the compiled step holds
+    no Mosaic call; under ``=1`` it holds the four kernels of every
+    site (statistics, normalize, backward sums, dx), and they
+    compile."""
+    from deeplearning4j_tpu.common.environment import Environment
+    from deeplearning4j_tpu.models.zoo import ResNet50
+    extra = Environment.get().extra
+    extra.update(gates)
+    try:
+        net = ResNet50(num_classes=16, height=32, width=32,
+                       compute_dtype="bfloat16",
+                       STAGES=((1, 16), (1, 32))).init()
+        net._build_train_step()
+        args = (net.params, net.states, net.updater_states,
+                [np.zeros((8, 32, 32, 3), np.float32)],
+                [np.zeros((8, 16), np.float32)], None, None,
+                jnp.asarray(0), jax.random.PRNGKey(0))
+        args = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), args)
+        with mock.patch.object(kernel_select, "interpret_mode",
+                               lambda: False), \
+                mock.patch.object(kernel_select, "platform", lambda: "tpu"):
+            lowered = net._train_step.lower(*args)
+            compiled = lowered.compile()
+    finally:
+        for k in gates:
+            extra.pop(k, None)
+    sites = 9                       # the stem, 2 x 3 in blocks, 2 shortcuts
+    assert lowered.as_text().count("tpu_custom_call") == per_site * sites
+    assert compiled.as_text().count(
+        "custom_call_target=\"tpu_custom_call\"") == per_site * sites
