@@ -135,7 +135,9 @@ class Environment:
       DL4J_TPU_PAGED_ATTENTION (tri-state: the paged decode-attention
       Pallas kernel for the serving KV pool), DL4J_TPU_SSM_STATE
       (tri-state: the in-place recurrent-state update kernel for the
-      serving state pool; all five gates resolve
+      serving state pool), DL4J_TPU_MOE_GROUPED (tri-state: the Pallas
+      grouped matrix product of the served expert layer, ops/moe.py;
+      all six gates resolve
       through the ops/kernel_select.py ladder: structural gate, then
       force/kill, then auto heuristic, every decision counted in
       dl4j_kernel_select_total),

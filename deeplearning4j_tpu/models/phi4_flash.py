@@ -61,7 +61,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu.models.served import (
-    last_position, mm as _mm, pool_rows, swiglu, write_rows)
+    greedy_by_reforward, last_position, mm as _mm, pool_rows, swiglu,
+    write_rows)
 
 _NEG = -1e30
 
@@ -535,25 +536,6 @@ class Phi4FlashLM:
     # -- reference decode (conformance gate) ----------------------------
     def reference_decode(self, params, prompt, max_tokens: int,
                          eos_id: Optional[int] = None):
-        """Greedy decode by full re-forward each step (no cache, no
-        ring, no state carried): what cached decode must match token
-        for token. The ids are padded to a multiple of 32 under
-        ``length``, so the forward compiles once a size and not once a
-        token."""
-        eos = self.conf.eos_id if eos_id is None else eos_id
-        ids = list(np.asarray(prompt, np.int32))
-        if self._forward_jit is None:
-            self._forward_jit = jax.jit(self.forward)
-        forward = self._forward_jit
-        out = []
-        for _ in range(max_tokens):
-            n = len(ids)
-            padded = np.zeros((1, -(-n // 32) * 32), np.int32)
-            padded[0, :n] = ids
-            logits = forward(params, padded, np.asarray([n], np.int32))
-            nxt = int(jnp.argmax(logits[0, n - 1]))
-            out.append(nxt)
-            ids.append(nxt)
-            if nxt == eos:
-                break
-        return out
+        """Greedy decode by full re-forward each step: what cached
+        decode must match token for token."""
+        return greedy_by_reforward(self, params, prompt, max_tokens, eos_id)

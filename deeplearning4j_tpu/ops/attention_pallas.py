@@ -212,7 +212,7 @@ def _paged_gather(pool, layer, block_tables, d):
 def paged_attention_reference(q, k_pool, v_pool, block_tables,
                               lengths, layer=0,
                               scale: Optional[float] = None,
-                              v_group: int = 1):
+                              v_group: int = 1, sink=None):
     """Dense-gather fallback AND numerical reference for paged decode
     attention.
 
@@ -223,6 +223,13 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables,
     [b, max_blocks] int32 (scratch-block-0 padded); ``lengths`` [b]
     int32 — valid KV tokens per sequence (>= 1, the current token's KV
     already written). Returns [b, h, d].
+
+    The V pool may hold heads of another width than K's (``d_v`` lanes
+    a head where K has ``d``: ``h_kv * d_v`` lanes a token): the
+    result is then [b, h, d_v]. ``sink`` [h] is a logit a query head
+    that joins the softmax's denominator and adds no value (a learned
+    attention sink): ``p_j = exp(s_j - m) / (exp(sink - m) + sum_j
+    exp(s_j - m))``.
 
     ``v_group`` KV heads side by side share their values (differential
     attention: K heads of ``d``, a V of ``v_group * d`` for the pair):
@@ -236,12 +243,14 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables,
     bit-for-tolerance."""
     b, h, d = q.shape
     k = _paged_gather(k_pool, layer, block_tables, d)
-    v = _paged_gather(v_pool, layer, block_tables, d)
+    d_v = v_pool.shape[3] // k.shape[2]
+    v = _paged_gather(v_pool, layer, block_tables, d_v)
     t = k.shape[1]
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    if h != k.shape[2] or v_group != 1:
-        return _paged_reference_grouped(q, k, v, lengths, scale, v_group)
+    if h != k.shape[2] or v_group != 1 or d_v != d or sink is not None:
+        return _paged_reference_grouped(q, k, v, lengths, scale, v_group,
+                                        sink)
     s = jnp.einsum("bhd,bthd->bht", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     valid = (jnp.arange(t, dtype=jnp.int32)[None, :]
@@ -254,14 +263,18 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables,
     return out.astype(q.dtype)
 
 
-def _paged_reference_grouped(q, k, v, lengths, scale, v_group=1):
-    """:func:`paged_attention_reference` where the gathered ``k`` /
-    ``v`` [b, t, h_kv, d] hold fewer KV heads than ``q`` has query
-    heads (grouped-query attention): query head ``i`` reads KV head
-    ``i // (h / h_kv)``, and the values of that head's ``v_group``."""
+def _paged_reference_grouped(q, k, v, lengths, scale, v_group=1,
+                             sink=None):
+    """:func:`paged_attention_reference` where the gathered ``k`` [b,
+    t, h_kv, d] / ``v`` [b, t, h_kv, d_v] hold fewer KV heads than
+    ``q`` has query heads (grouped-query attention): query head ``i``
+    reads KV head ``i // (h / h_kv)``, and the values of that head's
+    ``v_group``; with ``sink`` [h], a logit a head in the
+    denominator."""
     f32 = jnp.float32
     b, h, d = q.shape
     t, h_kv = k.shape[1:3]
+    d_v = v.shape[3]
     if v_group != 1:
         # every KV head gets its group's values, v_group * d wide
         v = jnp.repeat(v.reshape(b, t, h_kv // v_group, v_group * d),
@@ -271,10 +284,15 @@ def _paged_reference_grouped(q, k, v, lengths, scale, v_group=1):
     valid = jnp.arange(t, dtype=jnp.int32)[None, :] < lengths[:, None]
     s = jnp.where(valid[:, None, None, :], s, _PAGED_NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
+    extra = 0.0
+    if sink is not None:
+        sk = sink.astype(f32).reshape(1, h_kv, h // h_kv, 1)
+        m = jnp.maximum(m, sk)
+        extra = jnp.exp(sk - m)
     p = jnp.where(s <= _PAGED_NEG_INF / 2, 0.0, jnp.exp(s - m))
-    w = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+    w = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True) + extra, 1e-30)
     out = jnp.einsum("bkgt,btkd->bkgd", w, v.astype(f32))
-    return out.reshape(b, h, v_group * d).astype(q.dtype)
+    return out.reshape(b, h, v_group * d_v).astype(q.dtype)
 
 
 #: KV tokens one inner step of the paged kernel multiplies at once:
@@ -298,10 +316,11 @@ def _paged_blocks_per_step(block: int, hd: int, itemsize: int,
 
 
 def _paged_decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_hbm,
-                         v_hbm, out_ref, kbuf, vbuf, sem, slot_ref,
-                         m_ref, l_ref, acc_ref, *, head_dim: int,
+                         v_hbm, *rest, head_dim: int,
                          block: int, blocks_per_step: int, scale: float,
-                         group: int = 1, v_group: int = 1):
+                         group: int = 1, v_group: int = 1,
+                         v_head_dim: Optional[int] = None,
+                         sink: bool = False):
     """One sequence a grid step; inside it, a loop over the sequence's
     own ``ceil(length / block)`` blocks, ``blocks_per_step`` at a time.
 
@@ -327,17 +346,28 @@ def _paged_decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_hbm,
     its own KV head's lanes as ever and keeps, of ``P @ V``, the lanes
     of its head's whole group of ``v_group``: ``out_ref`` then has
     ``group * v_group`` rows, row ``c * group + j`` holding on group
-    ``G``'s lanes query head ``(G * v_group + c) * group + j``."""
+    ``G``'s lanes query head ``(G * v_group + c) * group + j``.
+
+    With ``v_head_dim`` (V heads of another width than K's; ``v_group``
+    1) the V slab and the accumulator are ``h_kv * v_head_dim`` lanes
+    wide and a row keeps its KV head's ``v_head_dim`` lanes of them.
+    With ``sink`` an operand ``[H, 1]`` follows the pools: query head
+    ``r``'s sink logit, which the row starts from (``m = sink``, ``l =
+    1``, ``acc = 0``: exactly the extra term of the denominator)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     f32, bf16 = jnp.float32, jnp.bfloat16
+    sink_ref = None
+    if sink:
+        sink_ref, *rest = rest
+    out_ref, kbuf, vbuf, sem, slot_ref, m_ref, l_ref, acc_ref = rest
     i = pl.program_id(0)
     n_rows = pl.num_programs(0)
     layer = layer_ref[0]
     max_blocks = tables_ref.shape[1]
     step_tokens = blocks_per_step * block
-    hp, hd = acc_ref.shape
+    hp, hd = acc_ref.shape[0], kbuf.shape[2]
 
     def n_blocks(row):
         return jnp.clip(pl.cdiv(lens_ref[row], block), 1, max_blocks)
@@ -397,8 +427,20 @@ def _paged_decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_hbm,
         keep = (lane >= lo) & (lane < lo + wide)
         keeps = [keep & (kv % v_group == c) & (row % group == j)
                  for c in range(v_group) for j in range(group)]
-    m_ref[...] = jnp.full_like(m_ref, _PAGED_NEG_INF)
-    l_ref[...] = jnp.zeros_like(l_ref)
+    elif v_head_dim is not None:
+        # V heads of their own width: row r keeps KV head r // group's
+        # lanes of the [H, h_kv * v_head_dim] accumulator
+        lane = jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 0)
+        lo = (row // group) * v_head_dim
+        keep = (lane >= lo) & (lane < lo + v_head_dim)
+        keeps = [keep & (row % group == j) for j in range(group)]
+    if sink_ref is None:
+        m_ref[...] = jnp.full_like(m_ref, _PAGED_NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+    else:
+        m_ref[...] = sink_ref[...]
+        l_ref[...] = jnp.ones_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def step(s, carry):
@@ -448,7 +490,7 @@ def _paged_decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_hbm,
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
                            layer=0, scale: Optional[float] = None,
-                           v_group: int = 1):
+                           v_group: int = 1, sink=None):
     """Pallas paged decode attention — same contract as
     :func:`paged_attention_reference`, at the model's default product
     precision (bf16 operands, float32 accumulation and softmax). The
@@ -468,8 +510,9 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
     per_step = _paged_blocks_per_step(
         int(k_pool.shape[2]), int(k_pool.shape[3]),
         k_pool.dtype.itemsize, int(block_tables.shape[1]))
+    extra = () if sink is None else (sink,)
     return _paged_call(q, k_pool, v_pool, block_tables, lengths,
-                       jnp.asarray(layer, jnp.int32).reshape(1),
+                       jnp.asarray(layer, jnp.int32).reshape(1), *extra,
                        scale=float(scale), per_step=per_step,
                        interpret=kernel_select.interpret_mode(),
                        v_group=int(v_group))
@@ -477,7 +520,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
 
 @functools.partial(jax.jit, static_argnames=("scale", "per_step",
                                              "interpret", "v_group"))
-def _paged_call(q, k_pool, v_pool, block_tables, lengths, layer, *,
+def _paged_call(q, k_pool, v_pool, block_tables, lengths, layer, *sink,
                 scale, per_step, interpret, v_group=1):
     """The ``pallas_call``, jitted on its own so that a model's layers
     share one trace and one lowering of the kernel (the layer index is
@@ -491,9 +534,21 @@ def _paged_call(q, k_pool, v_pool, block_tables, lengths, layer, *,
     h_kv = hd // d
     g = h // h_kv                         # query heads a KV head
     hp = -(-h // 16) * 16                 # heads, a bf16 sublane tile up
+    hdv = v_pool.shape[3]                 # the V pool's lanes a token
+    dv = hdv // h_kv
+    if dv != d and v_group != 1:
+        raise ValueError("a shared V group takes V heads as wide as K's")
 
     def row(i, tables, lens, layer):              # one sequence's q/out
         return (i, 0, 0)
+
+    operands, specs = (), []
+    if sink:
+        # a sink logit a query head, in the kernel's row order (row r
+        # is query head r), padded to the sublane tile
+        operands = (jnp.pad(sink[0].astype(jnp.float32).reshape(h, 1),
+                            ((0, hp - h), (0, 0))),)
+        specs = [pl.BlockSpec((hp, 1), lambda i, *_: (0, 0))]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,          # block_tables, lengths, layer
@@ -502,21 +557,27 @@ def _paged_call(q, k_pool, v_pool, block_tables, lengths, layer, *,
             pl.BlockSpec((None, g, hd), row),
             pl.BlockSpec(memory_space=pl.ANY),    # k pool: stays in HBM
             pl.BlockSpec(memory_space=pl.ANY),    # v pool
-        ],
-        out_specs=pl.BlockSpec((None, g * v_group, hd), row),
+        ] + specs,
+        out_specs=pl.BlockSpec((None, g * v_group, hdv), row),
         scratch_shapes=[
             pltpu.VMEM((2, per_step * block, hd), k_pool.dtype),
-            pltpu.VMEM((2, per_step * block, hd), v_pool.dtype),
+            pltpu.VMEM((2, per_step * block, hdv), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),      # [k | v, slot]
             pltpu.SMEM((1,), jnp.int32),          # slot being filled
             pltpu.VMEM((hp, 1), jnp.float32),     # running max
             pltpu.VMEM((hp, 1), jnp.float32),     # running sum
-            pltpu.VMEM((hp, hd), jnp.float32),    # output accumulator
+            pltpu.VMEM((hp, hdv), jnp.float32),   # output accumulator
         ],
     )
+    more = {}
+    if dv != d:
+        more["v_head_dim"] = dv
+    if sink:
+        more["sink"] = True
     kernel = functools.partial(_paged_decode_kernel, head_dim=d,
                                block=block, blocks_per_step=per_step,
-                               scale=scale, group=g, v_group=v_group)
+                               scale=scale, group=g, v_group=v_group,
+                               **more)
 
     def by_kv_head(a):        # [b, h, d] -> [b, g, h_kv * d]
         if g == 1:
@@ -527,16 +588,16 @@ def _paged_call(q, k_pool, v_pool, block_tables, lengths, layer, *,
         out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, g * v_group, hd), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((b, g * v_group, hdv), q.dtype),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
         )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-          layer, by_kv_head(q), k_pool, v_pool)
+          layer, by_kv_head(q), k_pool, v_pool, *operands)
     if g == 1 and v_group == 1:
-        return out.reshape(b, h, d)
+        return out.reshape(b, h, dv)
     if v_group == 1:
-        return jnp.swapaxes(out.reshape(b, g, h_kv, d), 1, 2).reshape(b, h, d)
+        return jnp.swapaxes(out.reshape(b, g, h_kv, dv), 1, 2).reshape(b, h, dv)
     # rows [c, j], lanes [G, v_group * d] -> query head (G, c, j)
     out = out.reshape(b, v_group, g, h_kv // v_group, v_group * d)
     return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(b, h, v_group * d)

@@ -56,6 +56,7 @@ GATES = {
     "attention": ("flash_attention", "DL4J_TPU_FLASH_ATTENTION"),
     "paged_attention": ("paged_attention", "DL4J_TPU_PAGED_ATTENTION"),
     "ssm_state": ("ssm_state", "DL4J_TPU_SSM_STATE"),
+    "moe_grouped": ("moe_grouped", "DL4J_TPU_MOE_GROUPED"),
 }
 
 _select_total = telemetry.counter(

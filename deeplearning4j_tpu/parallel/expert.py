@@ -18,6 +18,10 @@ residual connection carries them through — standard Switch behavior).
 
 All functions run inside ``shard_map``. Gradients flow through
 dispatch/combine einsums and all_to_all transposes automatically.
+
+This layer drops at its capacity and trains (the dry-run step of
+``models/transformer.py``). The layer that serves is ``ops/moe.py``:
+dropless, told which experts it holds, no exchange on one chip.
 """
 from __future__ import annotations
 

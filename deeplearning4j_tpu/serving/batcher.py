@@ -321,7 +321,8 @@ class ServingBatcher(ParallelInference):
             int(cfg.get("kv_block_size", 16)),
             getattr(c, "n_kv_heads", c.n_heads), c.head_dim,
             dtype=kv_dtype, name=self.name,
-            state=state, state_slots=state_slots)
+            state=state, state_slots=state_slots,
+            v_head_dim=getattr(c, "v_head_dim", None))
         params, view_fn = m.params, None
         if self.mode != "dense":
             from deeplearning4j_tpu.serving.residency import (

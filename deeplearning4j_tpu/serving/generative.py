@@ -89,6 +89,20 @@ them, ``window_read_tokens`` of ``kv_read_tokens``; its
 ``generate.prefill`` spans ``positions`` (the bucket's) and
 ``layer_positions`` of ``layer_positions_dense`` (a prefill that runs
 its upper layers on the last position only computes about half).
+Where ``cache_reads()`` names a token's bytes in each cache
+(``kv_token_bytes``, ``window_token_bytes``) the step's spans carry
+``kv_read_bytes`` and ``window_read_bytes`` too.
+
+A model with **sparse experts** (it has ``step_counts``, the names of
+the int32 its ``prefill`` and ``decode_step`` return last) routes on
+the device, so the host learns what a step's routing did only with the
+step's ids: the counts ride the same pull, ``RUN_AHEAD`` steps after
+the dispatch, and land on that step's ``generate.emit`` span (a
+prompt's on its ``generate.prefill``): ``moe_rows`` of ``moe_rows_all``
+``(row, expert)`` pairs fell on the experts held here, ``moe_experts_hit``
+of ``moe_experts_held`` experts had a row, ``moe_rows_max`` the fullest
+ones' rows. Counters ``dl4j_moe_rows_total{model,where=held|elsewhere}``
+and ``dl4j_moe_experts_idle_total{model}``.
 """
 from __future__ import annotations
 
@@ -189,6 +203,22 @@ def _sample_path_counter() -> telemetry.Counter:
         "vocabulary for every row of the bucket")
 
 
+def _moe_rows_counter() -> telemetry.Counter:
+    return telemetry.counter(
+        "dl4j_moe_rows_total",
+        "(row, expert) pairs the router chose, by model and where the "
+        "expert lives (held: on this chip, computed here | elsewhere: "
+        "another chip's share of the expert-parallel layer)")
+
+
+def _moe_idle_counter() -> telemetry.Counter:
+    return telemetry.counter(
+        "dl4j_moe_experts_idle_total",
+        "held experts that no row of a step or prompt was routed to, "
+        "summed over expert layers and executions, by model: their "
+        "weights were resident and unread")
+
+
 class TokenStream:
     """Consumer handle of one generate request: iterate token ids as
     the engine decodes them; ``reason`` tells how the sequence ended.
@@ -287,10 +317,11 @@ class _Step:
     the sequence of each row (None: a dead row or a hole), its bucket,
     the ids, and when it was dispatched."""
 
-    __slots__ = ("rows", "bucket", "ids", "t0")
+    __slots__ = ("rows", "bucket", "ids", "t0", "counts")
 
-    def __init__(self, rows, bucket, ids, t0):
+    def __init__(self, rows, bucket, ids, t0, counts=None):
         self.rows, self.bucket, self.ids, self.t0 = rows, bucket, ids, t0
+        self.counts = counts        # the model's step_counts, on the device
 
 
 class DecodeEngine:
@@ -357,6 +388,9 @@ class DecodeEngine:
         self._jits: dict = {}
         #: who reads which cache in a step, where the pool holds rings
         self._reads = model.cache_reads() if pool.window_kinds else None
+        #: names of the int32 the model's programs return last (what
+        #: its routing did), () for a model without sparse experts
+        self._counted = tuple(getattr(model, "step_counts", ()))
         import jax
         self._rng = jax.random.PRNGKey(rng_seed)
 
@@ -446,8 +480,9 @@ class DecodeEngine:
                 logits, *cache = self.model.decode_step(
                     self._view(params), tokens, positions, *cache,
                     tables, *state_slots, paged=paged)
+                counts = (cache.pop(),) if self._counted else ()
                 ids = sample_logits(logits, key, temps, topks)
-                return ids, tuple(cache)
+                return (ids, tuple(cache), *counts)
             self._jits["decode"] = jax.jit(fn, donate_argnums=(1,))
         return self._jits["decode"]
 
@@ -465,6 +500,8 @@ class DecodeEngine:
             self.guard.record(tokens, length)
             last, *new = self._prefill_jit()(self.params, tokens,
                                              length)
+            if self._counted:
+                new.pop()
             blocks = np.zeros((self.pool.blocks_for(t),), np.int32)
             self.guard.record(new[0], blocks)
             cache = self._commit_jit()(
@@ -490,13 +527,13 @@ class DecodeEngine:
             key = _jax.random.fold_in(self._rng, 0)
             rest = (positions, tables, key, temps, topks,
                     *self._state_arg(np.zeros((b,), np.int32)))
-            ids, cache = self._decode_jit()(
+            ids, cache, *_ = self._decode_jit()(
                 self.params, self.pool.arrays, tokens, *rest)
             self.pool.update_arrays(*cache)
             # and with its tokens as every step but the first after an
             # admission gets them: the ids of the step before, still
             # on the device
-            ids, cache = self._decode_jit()(
+            ids, cache, *_ = self._decode_jit()(
                 self.params, self.pool.arrays, ids, *rest)
             self.pool.update_arrays(*cache)
             jax.block_until_ready(ids)
@@ -690,13 +727,14 @@ class DecodeEngine:
                 "generate.prefill", model=self.name,
                 tokens=int(prompt.size), bucket=t,
                 queue_ms=round((t_prefill - t_submit) * 1e3, 3),
-                **whose):
+                **whose) as args:
             tokens = np.zeros((1, t), np.int32)
             tokens[0, :prompt.size] = prompt
             length = np.asarray([prompt.size], np.int32)
             self._record(tokens, length)
             last, *new = self._prefill_jit()(self.params, tokens,
                                              length)
+            counts = new.pop() if self._counted else None
             # the bucket's K/V into the prompt's pool blocks (the
             # blocks of the bucket past the prompt's last land in
             # scratch block 0)
@@ -710,6 +748,7 @@ class DecodeEngine:
             key = jax.random.fold_in(self._rng, self._step)
             first = int(np.asarray(self._sample_jit()(
                 last, key, temps, topks))[0])
+            args.update(self._name_counts(counts))
         now = time.perf_counter()
         _ttft_hist().observe(now - t_submit, model=self.name)
         if ctx is not None:
@@ -756,7 +795,8 @@ class DecodeEngine:
         """The per-step and per-token meters with this engine's labels
         resolved, bound once (again only if the registry is replaced):
         (decode-step seconds, occupancy, tokens, inter-token gap, the
-        sampler's executions by rung)."""
+        sampler's executions by rung, the routed pairs on held experts
+        and elsewhere and the idle held experts)."""
         reg = telemetry.MetricsRegistry.get()
         if self._meters_of is not reg:
             from deeplearning4j_tpu.ops.sampling import PATHS
@@ -768,7 +808,11 @@ class DecodeEngine:
                 _tokens_counter().bind(model=self.name),
                 _intertoken_hist().bind(model=self.name),
                 [_sample_path_counter().bind(model=self.name, path=p)
-                 for p in PATHS])
+                 for p in PATHS],
+                (_moe_rows_counter().bind(model=self.name, where="held"),
+                 _moe_rows_counter().bind(model=self.name,
+                                          where="elsewhere"),
+                 _moe_idle_counter().bind(model=self.name)))
         return self._bound
 
     def _name_sample(self, temps, topks) -> dict:
@@ -779,12 +823,25 @@ class DecodeEngine:
         vocabulary."""
         from deeplearning4j_tpu.ops.sampling import PATHS, sample_rung
         rung = int(sample_rung(temps, topks))
-        *_, sampled = self._meters()
+        sampled = self._meters()[4]
         sampled[rung].inc()
         rows = len(temps)
         return {"sample_path": PATHS[rung], "sample_rows": rows,
                 "sample_ordered":
                     rows if rung >= PATHS.index("top_k") else 0}
+
+    def _name_counts(self, counts) -> dict:
+        """What a program's routing did (the model's ``step_counts``,
+        pulled with its ids), as span attributes, and counted."""
+        if counts is None:
+            return {}
+        named = dict(zip(self._counted, (int(c) for c in np.asarray(counts))))
+        if "moe_rows" in named:
+            held, elsewhere, idle = self._meters()[5]
+            held.inc(named["moe_rows"])
+            elsewhere.inc(named["moe_rows_all"] - named["moe_rows"])
+            idle.inc(named["moe_experts_held"] - named["moe_experts_hit"])
+        return named
 
     def _decode_iteration(self) -> None:
         """ONE fused step over all live sequences (the iteration of
@@ -828,7 +885,7 @@ class DecodeEngine:
             t0 = time.perf_counter()
             with telemetry.span("generate.dispatch",
                                 program="decode_step"):
-                ids, cache = self._decode_jit()(
+                ids, cache, *routed = self._decode_jit()(
                     self.params, pool.arrays, *inputs)
                 # a donated cache is gone once dispatched: the pool
                 # holds the step's own arrays from here on
@@ -836,7 +893,7 @@ class DecodeEngine:
             for seq in rows:
                 if seq is not None:
                     seq.flying += 1
-            flying.append(_Step(rows, b, ids, t0))
+            flying.append(_Step(rows, b, ids, t0, *routed))
             if len(flying) > RUN_AHEAD:
                 got = self._pull(flying.popleft())
         if got is not None:
@@ -848,6 +905,8 @@ class DecodeEngine:
         it landed, whichever came later)."""
         with telemetry.span("generate.pull"):
             ids = np.asarray(step.ids)
+            if step.counts is not None:
+                step.counts = np.asarray(step.counts)
         now = time.perf_counter()
         step_s = now - max(step.t0, self._t_landed)
         self._t_landed = now
@@ -867,6 +926,7 @@ class DecodeEngine:
                 "generate.emit",
                 tokens=sum(seq is not None for seq in rows)) as args:
             args["retired"] = self._emit(rows, step.bucket, ids, step_s)
+            args.update(self._name_counts(step.counts))
 
     def _land(self) -> None:
         """Pull and emit every step in flight, oldest first, without
@@ -961,15 +1021,22 @@ class DecodeEngine:
         kv = int(ctx.sum())
         ring = int(np.minimum(ctx, reads["window"]).sum())
         window = reads["window_layers"] * ring
-        return {"kv_tokens": kv, "ring_tokens": ring,
-                "window_read_tokens": window,
-                "kv_read_tokens": reads["kv_readers"] * kv + window}
+        out = {"kv_tokens": kv, "ring_tokens": ring,
+               "window_read_tokens": window,
+               "kv_read_tokens": reads["kv_readers"] * kv + window}
+        if "kv_token_bytes" in reads:
+            # a ring token and a pool token differ in width
+            ring_bytes = window * reads["window_token_bytes"]
+            out.update(window_read_bytes=ring_bytes,
+                       kv_read_bytes=reads["kv_readers"] * kv
+                       * reads["kv_token_bytes"] + ring_bytes)
+        return out
 
     def _emit(self, rows, b, ids, step_s) -> int:
         """Hand every row its token: meters, the stream's queue, the
         request's ``inter_token`` instant, and retirement on EOS or
         ``max_tokens``. Returns how many rows retired."""
-        step_hist, occupancy, tokens, gap_hist, _ = self._meters()
+        step_hist, occupancy, tokens, gap_hist, *_ = self._meters()
         step_hist.observe(step_s)
         occupancy.observe(sum(seq is not None for seq in rows)
                           / max(1, b))

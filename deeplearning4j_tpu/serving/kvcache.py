@@ -9,7 +9,9 @@ the fragmentation paged attention (vLLM) eliminates. This module is
 that allocator for the TPU stack:
 
 - One preallocated device array pair per pool — ``k`` / ``v`` shaped
-  ``[n_layers, num_blocks, block_size, n_heads * head_dim]`` — carved
+  ``[n_layers, num_blocks, block_size, n_heads * head_dim]`` (``v``
+  ``n_heads * v_head_dim`` lanes where a model's value heads have a
+  width of their own) — carved
   into fixed-size **blocks** of ``block_size`` token slots. That is
   the form the paged decode kernel reads: a token's heads side by
   side down the lanes (no ``head_dim``-wide minor axis for the
@@ -154,7 +156,8 @@ class KVBlockPool:
                  block_size: int, n_heads: int, head_dim: int, *,
                  dtype=np.float32, name: str = "model",
                  device_arrays: bool = True,
-                 state: Optional[dict] = None, state_slots: int = 0):
+                 state: Optional[dict] = None, state_slots: int = 0,
+                 v_head_dim: Optional[int] = None):
         if num_blocks < 2:
             raise ValueError("num_blocks must be >= 2 (block 0 is "
                              "reserved scratch)")
@@ -166,16 +169,19 @@ class KVBlockPool:
         self.block_size = int(block_size)
         self.n_heads = int(n_heads)
         self.head_dim = int(head_dim)
+        #: a value head's lanes: the key head's unless the model says
+        self.v_head_dim = int(v_head_dim or head_dim)
         self.name = name
         shape = (self.n_layers, self.num_blocks, self.block_size,
                  self.n_heads * self.head_dim)
+        v_shape = shape[:3] + (self.n_heads * self.v_head_dim,)
         if device_arrays:
             import jax.numpy as jnp
             self.k = jnp.zeros(shape, dtype=dtype)
-            self.v = jnp.zeros(shape, dtype=dtype)
+            self.v = jnp.zeros(v_shape, dtype=dtype)
         else:               # allocator-only pool (tests, sizing math)
             self.k = np.zeros(shape, dtype=dtype)
-            self.v = np.zeros(shape, dtype=dtype)
+            self.v = np.zeros(v_shape, dtype=dtype)
         #: slot arrays by kind, [layers, slots, *shape]: recurrent
         #: state, and the rings of window layers
         self.state_slots = int(state_slots) if state else 0
@@ -426,6 +432,8 @@ class KVBlockPool:
                 "bytes_per_slot": self.window_bytes // self.state_slots,
                 "layout": {k: list(self.state[k].shape)
                            for k in sorted(self.window_kinds)}}
+        if self.v_head_dim != self.head_dim:
+            state["layout_v"] = list(self.v.shape)
         return {
             **state,
             "pool": self.name,

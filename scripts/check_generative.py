@@ -24,9 +24,11 @@ accelerator needed):
 
 The gate runs once for each model class with the serving contract:
 ``DecoderLM``, ``FalconH1LM`` (grouped-query attention beside a
-state-space mixer: KV blocks and a state slot a sequence) and
+state-space mixer: KV blocks and a state slot a sequence),
 ``Phi4FlashLM`` (one shared K/V layer, window rings and Mamba-1 state
-in the slot).
+in the slot) and ``MiMoV2LM`` (K and V of different widths in pool and
+rings, a sink in the window layers, sparse experts of which a share is
+held: the routing counts ride each step's pull).
 
 Usage: JAX_PLATFORMS=cpu python scripts/check_generative.py
 Exit 0 = gate holds, 1 = a clause failed.
@@ -81,10 +83,23 @@ def _phi4_flash():
                               state=model.state_shapes(), state_slots=9)
 
 
+def _mimo_v2():
+    """Full and window layers with K heads wider than V heads, and
+    expert layers that compute a quarter of the experts' part."""
+    from deeplearning4j_tpu.models.mimo_v2 import MiMoV2Config, MiMoV2LM
+    from deeplearning4j_tpu.serving.kvcache import KVBlockPool
+    conf = MiMoV2Config()
+    model = MiMoV2LM(conf)
+    return model, KVBlockPool(model.kv_layers, 64, 8, conf.n_kv_heads,
+                              conf.head_dim, v_head_dim=conf.v_head_dim,
+                              name="gate", state=model.state_shapes(),
+                              state_slots=9)
+
+
 def main() -> int:
     import gc
     failures = []
-    for build in (_decoder, _falcon_h1, _phi4_flash):
+    for build in (_decoder, _falcon_h1, _phi4_flash, _mimo_v2):
         model, pool = build()
         label = type(model).__name__
         print(f"== {label}")

@@ -20,12 +20,13 @@ MAX_BLOCKS = 7                    # 56 tokens; not a multiple of 3 blocks
 FULL = BLOCK * MAX_BLOCKS
 
 
-def _pools(h, d, n_blocks, block, dtype, seed):
+def _pools(h, d, n_blocks, block, dtype, seed, dv=None):
     """One layer's K and V as the pool stores them: ``[n_blocks, block,
-    h * d]``, a token's heads side by side."""
+    h * d]``, a token's heads side by side (V ``h * dv`` where its
+    heads have a width of their own)."""
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
     k = jax.random.normal(kk, (n_blocks, block, h * d), jnp.float32)
-    v = jax.random.normal(kv, (n_blocks, block, h * d), jnp.float32)
+    v = jax.random.normal(kv, (n_blocks, block, h * (dv or d)), jnp.float32)
     return kq, np.array(k.astype(dtype)), np.array(v.astype(dtype))
 
 
@@ -60,20 +61,25 @@ def _poison(pool, tables, lens):
     return out.astype(pool.dtype)
 
 
-def _check(lens, *, h, d, block, max_blocks, dtype, seed=0):
+def _check(lens, *, h, d, block, max_blocks, dtype, seed=0, h_kv=None,
+           dv=None, sink=False):
     lens = list(lens)
     n_blocks = 2 + sum(-(-n // block) for n in lens)
-    kq, k, v = _pools(h, d, n_blocks, block, dtype, seed)
+    kq, k, v = _pools(h_kv or h, d, n_blocks, block, dtype, seed, dv)
     q = jax.random.normal(kq, (len(lens), h, d), jnp.float32)
     tables = _tables(lens, block, max_blocks, n_blocks, seed)
     lengths = jnp.asarray(lens, jnp.int32)
+    more = {}
+    if sink:
+        more["sink"] = 2.0 * jax.random.normal(jax.random.fold_in(kq, 1),
+                                               (h,), jnp.float32)
     want = ap.paged_attention_reference(
         q, jnp.asarray(k)[None], jnp.asarray(v)[None],
-        jnp.asarray(tables), lengths)
+        jnp.asarray(tables), lengths, **more)
     got = jax.jit(ap.paged_decode_attention)(
         q, jnp.asarray(_poison(k, tables, lens))[None],
         jnp.asarray(_poison(v, tables, lens))[None], jnp.asarray(tables),
-        lengths)
+        lengths, **more)
     got, want = np.asarray(got), np.asarray(want)
     assert got.shape == want.shape and got.dtype == want.dtype
     assert np.all(np.isfinite(got)), "poisoned KV reached the output"
@@ -125,6 +131,40 @@ def test_the_cells_shapes(h, d, dtype):
     assert ap._paged_blocks_per_step(16, h * d, 2, 21) == 16
     _check((330, 1, 17, 256, 1), h=h, d=d, block=16, max_blocks=21,
            dtype=dtype, seed=2)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("h,h_kv,d,dv,sink", [
+    (16, 2, 24, 16, False), (16, 2, 24, 16, True), (16, 1, 24, 16, True),
+    (8, 8, 32, 32, True), (64, 4, 192, 128, False), (64, 8, 192, 128, True)],
+    ids=["g8-v16", "g8-v16-sink", "g16-v16-sink", "g1-sink", "full-layer",
+         "window-layer"])
+def test_value_heads_of_their_own_width_and_a_sink(h, h_kv, d, dv, sink,
+                                                   dtype):
+    """V heads narrower than K heads (the accumulator and the output
+    ``h_kv * dv`` lanes wide, the K slab ``h_kv * d``), a learned sink
+    logit a query head in the softmax's denominator, groups of 8 and 16
+    query heads a KV head: against the dense gather, rows at a block's
+    and a ring's edges with dead rows between, KV poisoned wherever it
+    is not live. The last two are the MiMo-V2.5 cell's layers: 64 query
+    heads on 4 KV heads of 192 / 128 over the growing pool, and on 8
+    over a ring of 128 positions (8 blocks of 16) with the sink."""
+    ring = (h, h_kv) == (64, 8)
+    _check((128, 1, 17, 128, 1, 100) if ring else (330, 1, 17, 256, 1),
+           h=h, h_kv=h_kv, d=d, dv=dv, sink=sink, block=16,
+           max_blocks=8 if ring else 21, dtype=dtype, seed=5)
+    if sink:
+        # the sink takes probability: without it the result is another
+        q = jnp.ones((1, h, d))
+        kp = jnp.zeros((1, 2, 16, h_kv * d), dtype)
+        vp = jnp.ones((1, 2, 16, h_kv * dv), dtype)
+        args = (q, kp, vp, jnp.asarray([[1]]), jnp.asarray([16]))
+        out = ap.paged_decode_attention(*args, sink=jnp.zeros((h,)))
+        # 16 keys of score 0 and a sink of 0: 16 / 17 of the value
+        np.testing.assert_allclose(out, 16 / 17, rtol=1e-2)
+        np.testing.assert_allclose(ap.paged_decode_attention(*args), 1.0,
+                                   rtol=1e-2)
 
 
 @pytest.mark.parametrize("layer", [0, 1])
