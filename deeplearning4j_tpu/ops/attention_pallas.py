@@ -335,12 +335,25 @@ def _paged_decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_hbm,
 
     The math keeps the pool's own lane-dense order ``[T, h*d]`` and
     has no head axis: with ``Qbd [H, h*d]`` holding q's head ``r`` in
-    row ``r`` and zeros elsewhere (``H`` = heads padded to a bf16
-    sublane tile), scores are ``Qbd @ K^T -> [H, T]`` and the output
-    ``P @ V -> [H, h*d]``, of which row ``r`` is kept on head ``r``'s
-    lanes when the row is finished. Both products take bf16 operands
-    into float32 (the model's default precision); the softmax
+    row ``r`` on its KV head's lanes and zeros elsewhere (``H`` = heads
+    padded to a bf16 sublane tile), scores are ``Qbd @ K^T -> [H, T]``
+    and the output ``P @ V -> [H, h*d]``, of which row ``r`` keeps its
+    KV head's lanes when the row is finished. Both products take bf16
+    operands into float32 (the model's default precision); the softmax
     statistics and the accumulator are float32.
+
+    Nothing that is the same for every sequence is rebuilt for one.
+    ``Qbd`` is block-diagonal: KV head ``k``'s ``group`` query heads
+    are rows ``[k * group, (k + 1) * group)`` on lanes ``[k * d, (k +
+    1) * d)``, which is ``q_ref``'s rows on those lanes as they come
+    (``q_ref`` / ``out_ref`` row ``j`` hold, on KV head ``k``'s lanes,
+    query head ``k * group + j``). Its zeros are written once a launch
+    (``qbd_ref``, under ``i == 0``: the grid is sequential) and a
+    sequence writes only the ``h_kv`` diagonal blocks, ``H x d``
+    elements whatever ``group`` is; the finished row reads the same
+    blocks of the accumulator, each times its rows' ``1 / l``, so an
+    output element is one accumulator element times one reciprocal.
+    No lane mask exists.
 
     With ``v_group > 1`` (differential attention) a row scores against
     its own KV head's lanes as ever and keeps, of ``P @ V``, the lanes
@@ -361,13 +374,16 @@ def _paged_decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_hbm,
     sink_ref = None
     if sink:
         sink_ref, *rest = rest
-    out_ref, kbuf, vbuf, sem, slot_ref, m_ref, l_ref, acc_ref = rest
+    (out_ref, kbuf, vbuf, sem, slot_ref, m_ref, l_ref, acc_ref,
+     qbd_ref) = rest
     i = pl.program_id(0)
     n_rows = pl.num_programs(0)
     layer = layer_ref[0]
     max_blocks = tables_ref.shape[1]
     step_tokens = blocks_per_step * block
-    hp, hd = acc_ref.shape[0], kbuf.shape[2]
+    h_kv = kbuf.shape[2] // head_dim
+    # the lanes of the accumulator that a KV head's rows keep
+    wide = v_group * (v_head_dim or head_dim)
 
     def n_blocks(row):
         return jnp.clip(pl.cdiv(lens_ref[row], block), 1, max_blocks)
@@ -399,49 +415,21 @@ def _paged_decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_hbm,
     def _first():                                 # noqa: ANN202
         slot_ref[0] = 0
         fetch(0, 0, 0, wait=False)
+        qbd_ref[...] = jnp.zeros_like(qbd_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
     length = lens_ref[i]
     n_steps = pl.cdiv(n_blocks(i), blocks_per_step)
-    # head r owns lanes [r * d, (r + 1) * d) of the flat h*d axis
-    lane = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 1)
-    row = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 0)
-    if group == 1:
-        lo = row * head_dim
-        own = (lane >= lo) & (lane < lo + head_dim)
-        owns = [own]
-        qbd = jnp.where(own, q_ref[...].astype(f32), 0.0).astype(bf16)
-    else:
-        # grouped queries: row r is query head r and owns the lanes of
-        # KV head r // group; q_ref / out_ref row j hold, on KV head k's
-        # lanes, query head k * group + j
-        lo = (row // group) * head_dim
-        own = (lane >= lo) & (lane < lo + head_dim)
-        owns = [own & (row % group == j) for j in range(group)]
-        qbd = sum(jnp.where(o, q_ref[j:j + 1, :].astype(f32), 0.0)
-                  for j, o in enumerate(owns)).astype(bf16)
-    keeps = owns
-    if v_group != 1:
-        kv = row // group
-        wide = v_group * head_dim
-        lo = (kv // v_group) * wide
-        keep = (lane >= lo) & (lane < lo + wide)
-        keeps = [keep & (kv % v_group == c) & (row % group == j)
-                 for c in range(v_group) for j in range(group)]
-    elif v_head_dim is not None:
-        # V heads of their own width: row r keeps KV head r // group's
-        # lanes of the [H, h_kv * v_head_dim] accumulator
-        lane = jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 1)
-        row = jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 0)
-        lo = (row // group) * v_head_dim
-        keep = (lane >= lo) & (lane < lo + v_head_dim)
-        keeps = [keep & (row % group == j) for j in range(group)]
+    for kv in range(h_kv):
+        lanes = slice(kv * head_dim, (kv + 1) * head_dim)
+        qbd_ref[kv * group:(kv + 1) * group, lanes] = \
+            q_ref[:, lanes].astype(f32)
     if sink_ref is None:
         m_ref[...] = jnp.full_like(m_ref, _PAGED_NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
     else:
         m_ref[...] = sink_ref[...]
         l_ref[...] = jnp.ones_like(l_ref)
-    acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def step(s, carry):
         slot = slot_ref[0]
@@ -458,7 +446,7 @@ def _paged_decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_hbm,
         base = s * step_tokens
         k = kbuf[slot].astype(bf16)               # [T, h*d]
         sc = jax.lax.dot_general(
-            qbd, k, (((1,), (1,)), ((), ())),
+            qbd_ref[...].astype(bf16), k, (((1,), (1,)), ((), ())),
             preferred_element_type=f32) * scale   # [H, T]
         tok = base + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
         sc = jnp.where(tok < length, sc, _PAGED_NEG_INF)
@@ -475,17 +463,22 @@ def _paged_decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_hbm,
         p = jnp.exp(sc - m_new)                   # masked: exactly 0
         l_ref[...] = corr * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
         m_ref[...] = m_new
-        acc_ref[...] = corr * acc_ref[...] + jnp.dot(
-            p.astype(bf16), v, preferred_element_type=f32)
+        # a sequence's first step starts from 0 times what the one
+        # before left (finite: zeros since ``i == 0``), not from a
+        # pass of zeros over the accumulator
+        acc_ref[...] = jnp.where(s == 0, 0.0, corr) * acc_ref[...] \
+            + jnp.dot(p.astype(bf16), v, preferred_element_type=f32)
         slot_ref[0] = 1 - slot
         return carry
 
     jax.lax.fori_loop(0, n_steps, step, 0)
-    inv_l = 1.0 / jnp.maximum(l_ref[...], 1e-30)
-    for j, o in enumerate(keeps):
-        out_ref[j:j + 1, :] = jnp.sum(
-            acc_ref[...] * jnp.where(o, inv_l, 0.0), axis=0,
-            keepdims=True).astype(out_ref.dtype)
+    l_ref[...] = 1.0 / jnp.maximum(l_ref[...], 1e-30)
+    for kv in range(h_kv):
+        rows = slice(kv * group, (kv + 1) * group)
+        vg, c = divmod(kv, v_group)
+        lanes = slice(vg * wide, (vg + 1) * wide)
+        out_ref[c * group:(c + 1) * group, lanes] = \
+            (acc_ref[rows, lanes] * l_ref[rows, :]).astype(out_ref.dtype)
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
@@ -567,6 +560,7 @@ def _paged_call(q, k_pool, v_pool, block_tables, lengths, layer, *sink,
             pltpu.VMEM((hp, 1), jnp.float32),     # running max
             pltpu.VMEM((hp, 1), jnp.float32),     # running sum
             pltpu.VMEM((hp, hdv), jnp.float32),   # output accumulator
+            pltpu.VMEM((hp, hd), jnp.float32),    # block-diagonal q
         ],
     )
     more = {}

@@ -6,6 +6,8 @@ live ones, tables out of pool order, both head shapes the chip runs,
 float32 and bf16 pools, and KV poisoned wherever ``lengths`` says it
 is not live. Then the engine: the same greedy tokens through the
 kernel as through the gather."""
+import functools
+
 import numpy as np
 import pytest
 
@@ -30,16 +32,17 @@ def _pools(h, d, n_blocks, block, dtype, seed, dv=None):
     return kq, np.array(k.astype(dtype)), np.array(v.astype(dtype))
 
 
-def _tables(lens, block, max_blocks, n_blocks, seed):
+def _tables(lens, block, max_blocks, n_blocks, seed, dead=None):
     """Each row's blocks drawn from a shuffled pool (block 0 is the
-    scratch block: dead rows and the padding name it)."""
+    scratch block: dead rows and the padding name it). ``dead`` names
+    the rows of length 1 that are dead; by default the odd ones."""
     order = np.random.default_rng(seed).permutation(
         np.arange(1, n_blocks))
     tables = np.zeros((len(lens), max_blocks), np.int32)
     nxt = 0
     for i, n in enumerate(lens):
-        if n == 1 and i % 2:            # a dead row: all scratch
-            continue
+        if n == 1 and (i % 2 if dead is None else i in dead):
+            continue                    # a dead row: all scratch
         need = -(-n // block)
         tables[i, :need] = order[nxt:nxt + need]
         nxt += need
@@ -61,13 +64,25 @@ def _poison(pool, tables, lens):
     return out.astype(pool.dtype)
 
 
+def _rows_match(got, want, lens, tag=()):
+    """Row by row, so that none hides behind the others; nothing of the
+    poisoned KV in the output."""
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert np.all(np.isfinite(got)), "poisoned KV reached the output"
+    for i in range(len(lens)):
+        assert chip_smoke.rel_err(got[i], want[i]) \
+            <= chip_smoke.KERNEL_REL_TOL, tag + (i, lens[i])
+    return got
+
+
 def _check(lens, *, h, d, block, max_blocks, dtype, seed=0, h_kv=None,
-           dv=None, sink=False):
+           dv=None, sink=False, v_group=1, dead=None):
     lens = list(lens)
     n_blocks = 2 + sum(-(-n // block) for n in lens)
     kq, k, v = _pools(h_kv or h, d, n_blocks, block, dtype, seed, dv)
     q = jax.random.normal(kq, (len(lens), h, d), jnp.float32)
-    tables = _tables(lens, block, max_blocks, n_blocks, seed)
+    tables = _tables(lens, block, max_blocks, n_blocks, seed, dead)
     lengths = jnp.asarray(lens, jnp.int32)
     more = {}
     if sink:
@@ -75,17 +90,13 @@ def _check(lens, *, h, d, block, max_blocks, dtype, seed=0, h_kv=None,
                                                (h,), jnp.float32)
     want = ap.paged_attention_reference(
         q, jnp.asarray(k)[None], jnp.asarray(v)[None],
-        jnp.asarray(tables), lengths, **more)
-    got = jax.jit(ap.paged_decode_attention)(
+        jnp.asarray(tables), lengths, v_group=v_group, **more)
+    got = jax.jit(functools.partial(ap.paged_decode_attention,
+                                    v_group=v_group))(
         q, jnp.asarray(_poison(k, tables, lens))[None],
         jnp.asarray(_poison(v, tables, lens))[None], jnp.asarray(tables),
         lengths, **more)
-    got, want = np.asarray(got), np.asarray(want)
-    assert got.shape == want.shape and got.dtype == want.dtype
-    assert np.all(np.isfinite(got)), "poisoned KV reached the output"
-    for i in range(len(lens)):            # row by row: none hides
-        assert chip_smoke.rel_err(got[i], want[i]) \
-            <= chip_smoke.KERNEL_REL_TOL, (i, lens[i])
+    _rows_match(got, want, lens)
 
 
 @pytest.fixture
@@ -167,6 +178,63 @@ def test_value_heads_of_their_own_width_and_a_sink(h, h_kv, d, dv, sink,
                                    rtol=1e-2)
 
 
+@pytest.mark.parametrize("lens,dead", [
+    ((1, 41, 17, 1, FULL, 9, 1), {0, 3, 6}), ((BLOCK + 3,), set())],
+    ids=["dead-first-middle-last", "one-row"])
+@pytest.mark.parametrize("more", [
+    {}, {"v_group": 2}, {"dv": 16}, {"sink": True},
+    {"dv": 16, "sink": True}],
+    ids=["plain", "v_group2", "v16", "sink", "v16-sink"])
+@pytest.mark.parametrize("g", [1, 2, 5, 8, 16])
+def test_every_group_size(three_blocks_a_step, g, more, lens, dead):
+    """The kernel writes a sequence's q into the diagonal blocks of a
+    scratch whose zeros are made at the launch's first row, and reads
+    the result out of the same blocks of the accumulator: so every
+    group size the cells run (1, 2, 5, 8, 16 query heads a KV head)
+    with a shared V group, V heads of their own width and a sink, on a
+    bucket whose first row is dead (it makes the zeros), with dead
+    rows in the middle and last, and on a bucket whose first row is
+    its only one. Steps of 3 blocks: the longest row takes three."""
+    _check(lens, h=2 * g, h_kv=2, d=32, block=BLOCK,
+           max_blocks=MAX_BLOCKS, dtype=jnp.bfloat16, seed=6 + g,
+           dead=dead, **more)
+
+
+def test_two_launches_of_one_program_share_nothing(three_blocks_a_step):
+    """Two layers through one traced call, the second with the bucket
+    the other way round and queries of its own: what a launch keeps
+    from its first row (the zeros round q's blocks, the accumulator's
+    first state) is rebuilt by the next launch, and nothing of the
+    first one's rows shows in the second's."""
+    h, h_kv, d, dv = 16, 2, 32, 16
+    lens = [1, FULL, 17, 1, 2 * BLOCK]
+    n_blocks = 2 + sum(-(-n // BLOCK) for n in lens)
+    kq, k0, v0 = _pools(h_kv, d, n_blocks, BLOCK, jnp.bfloat16, 8, dv)
+    _, k1, v1 = _pools(h_kv, d, n_blocks, BLOCK, jnp.bfloat16, 9, dv)
+    tables = _tables(lens, BLOCK, MAX_BLOCKS, n_blocks, 8, dead={0, 3})
+    q = jax.random.normal(kq, (2, len(lens), h, d), jnp.float32)
+    sink = jax.random.normal(jax.random.fold_in(kq, 1), (h,))
+    k, v = (jnp.stack([jnp.asarray(_poison(a, tables, lens))
+                       for a in pair]) for pair in ((k0, k1), (v0, v1)))
+    first = (jnp.asarray(tables), jnp.asarray(lens, jnp.int32), 0)
+    second = (jnp.asarray(tables[::-1].copy()),
+              jnp.asarray(lens[::-1], jnp.int32), 1)
+
+    @jax.jit
+    def both(q, k, v):
+        return (ap.paged_decode_attention(q[0], k, v, *first, sink=sink),
+                ap.paged_decode_attention(q[1], k, v, *second, sink=sink))
+    got = both(q, k, v)
+    clean_k, clean_v = jnp.stack([k0, k1]), jnp.stack([v0, v1])
+    for n, (out, args) in enumerate(zip(got, (first, second))):
+        want = ap.paged_attention_reference(q[n], clean_k, clean_v, *args,
+                                            sink=sink)
+        out = _rows_match(out, want, (lens, lens[::-1])[n], (n,))
+        # and alone in its own program the launch gives the same bits
+        alone = ap.paged_decode_attention(q[n], k, v, *args, sink=sink)
+        np.testing.assert_array_equal(np.asarray(alone), out)
+
+
 @pytest.mark.parametrize("layer", [0, 1])
 @pytest.mark.parametrize("h,h_kv", [(4, 4), (10, 2)], ids=["g1", "g5"])
 def test_a_layer_of_the_stacked_pool(three_blocks_a_step, h, h_kv, layer):
@@ -193,12 +261,9 @@ def test_a_layer_of_the_stacked_pool(three_blocks_a_step, h, h_kv, layer):
         q, stacked(_poison(k, tables, lens)),
         stacked(_poison(v, tables, lens)), jnp.asarray(tables), lengths,
         layer)                      # the layer traced, as a model's is not
-    got, want = np.asarray(got), np.asarray(want)
-    assert got.shape == want.shape == (len(lens), h, d)
-    assert np.all(np.isfinite(got)) and np.all(np.isfinite(want))
-    for i in range(len(lens)):
-        assert chip_smoke.rel_err(got[i], want[i]) \
-            <= chip_smoke.KERNEL_REL_TOL, (i, lens[i])
+    assert np.all(np.isfinite(np.asarray(want)))
+    got = _rows_match(got, want, lens)
+    assert got.shape == (len(lens), h, d)
     # one trace serves every layer: the index is an operand
     again = ap.paged_decode_attention(
         q, stacked(k), stacked(v), jnp.asarray(tables), lengths, layer)
