@@ -42,6 +42,18 @@ dispatched: ``live`` rows of ``bucket``, ``pool_live`` of
 ``pool_usable`` blocks, and ``grid_blocks``, the ``bucket x
 max_blocks`` table positions of which ``pool_live`` name live KV.
 
+**An admission's record** (one an admission episode, none a step).
+``generate.stall`` (``span_at``, no parent; ``iter`` of the iteration
+that closed it) is **what a row that was decoding waited between two
+tokens** across an admission: from the last emit before the episode's
+first prefill began to the first emit after it, with ``prefills`` and
+``prompt_tokens`` (summed) and ``rows`` (the rows of that first emit
+that were live before the episode: the inter-token gaps that crossed
+it). Prefills with no emit between them are one episode; an admission
+into an engine with no live row has nobody waiting and writes none.
+The same seconds go to the histogram
+``dl4j_generate_admission_stall_seconds{model}``.
+
 **The loop runs ahead of the device.** A dispatched step's ids are
 not pulled at once: the next pass builds the following step on the
 rows of the last one dispatched (their positions one further, its
@@ -75,9 +87,9 @@ compiled programs take the cache's arrays as one pytree
 head_dim]``, then the state arrays), **donated** for every model: the
 commit program writes the prompt's blocks and the decode step one row
 a sequence a layer into the buffers they were given, and the pool
-holds what they return. The spans then carry ``state_live`` of
-``state_slots`` (``generate.decode_step``) and ``state_slot``
-(``generate.prefill``).
+holds what they return. ``generate.prefill`` then carries the
+sequence's ``state_slot`` (how many are live is the pool's gauge,
+``dl4j_state_pool_slots``).
 
 A model that keeps **window rings** beside one shared K/V layer (it has
 ``cache_reads()``; the pool has ``window_bytes``) is served by the same
@@ -146,6 +158,15 @@ def _intertoken_hist() -> telemetry.Histogram:
         "gap between consecutive streamed tokens of one sequence — "
         "the decode-iteration latency a streaming client experiences "
         "(seconds)")
+
+
+def _stall_hist() -> telemetry.Histogram:
+    return telemetry.histogram(
+        "dl4j_generate_admission_stall_seconds",
+        "what a decoding row waits between two tokens when an admission "
+        "falls between them: the last token before the landing of the "
+        "steps in flight and the prefills to the first token after, "
+        "one observation an admission episode, per model (seconds)")
 
 
 def _decode_step_hist() -> telemetry.Histogram:
@@ -371,6 +392,12 @@ class DecodeEngine:
         #: the dispatched steps whose ids were not pulled yet
         self._inflight: "collections.deque[_Step]" = collections.deque()
         self._t_landed = 0.0
+        #: when the last step's tokens were handed out
+        self._t_emit = 0.0
+        #: the admission episode that no emit has closed yet: what
+        #: ``generate.stall`` will carry, ``t0`` and the ids of the
+        #: sequences that were live ``before`` it
+        self._stall: Optional[dict] = None
         self._lock = threading.Lock()
         self._worker: Optional[threading.Thread] = None
         #: the worker shutdown() swapped out, until it finishes its
@@ -640,6 +667,7 @@ class DecodeEngine:
             if self._pending.empty() and not self._live \
                     and self._held is None:
                 self._inflight.clear()  # nothing but holes is in them
+                self._stall = None      # and nobody waits for a token
                 # Idle — and only exit on shutdown/supersession while
                 # idle: every pending request was admitted and every
                 # admitted sequence retired, so no stream is stranded.
@@ -723,6 +751,7 @@ class DecodeEngine:
         temps = np.asarray([temperature], np.float32)
         topks = np.asarray([top_k], np.int32)
         whose.update(self._name_sample(temps, topks))
+        self._stall_grows(int(prompt.size))
         with telemetry.span(
                 "generate.prefill", model=self.name,
                 tokens=int(prompt.size), bucket=t,
@@ -870,10 +899,6 @@ class DecodeEngine:
                 return
         rows, b, inputs, sample = step
         pool = self.pool
-        counts = {}
-        if pool.state:
-            counts = {"state_live": pool.usable_slots - pool.free_slots,
-                      "state_slots": pool.usable_slots}
         got = None
         with telemetry.span(
                 "generate.decode_step", model=self.name,
@@ -881,7 +906,7 @@ class DecodeEngine:
                 bucket=b, grid_blocks=b * self.max_blocks,
                 pool_usable=pool.usable_blocks,
                 pool_live=pool.usable_blocks - pool.free_blocks,
-                **counts, **sample):
+                **sample):
             t0 = time.perf_counter()
             with telemetry.span("generate.dispatch",
                                 program="decode_step"):
@@ -933,6 +958,38 @@ class DecodeEngine:
         dispatching another."""
         while self._inflight:
             self._emit_step(*self._pull(self._inflight.popleft()))
+
+    def _stall_grows(self, prompt_tokens: int) -> None:
+        """A prefill is about to hold the decoding rows up: it opens
+        an admission episode, or joins the one that no emit has closed
+        yet. The rows that wait are those that had a token by the last
+        emit (not the ones this admission has just prefilled); with
+        none of them, as in an idle engine, nobody waits: no episode,
+        and no ``generate.stall``."""
+        stall = self._stall
+        if stall is None:
+            before = frozenset(
+                seq.seq_id for seq in self._live.values()
+                if seq.t_last <= self._t_emit)
+            if not before:
+                return
+            stall = self._stall = {
+                "t0": self._t_emit, "before": before, "prefills": 0,
+                "prompt_tokens": 0}
+        stall["prefills"] += 1
+        stall["prompt_tokens"] += prompt_tokens
+
+    def _stall_ends(self, stall: dict, rows, now: float) -> None:
+        """The first tokens after an admission episode are going out:
+        what a row that was decoding waited, as ``generate.stall`` and
+        in the histogram."""
+        t0, before = stall.pop("t0"), stall.pop("before")
+        telemetry.span_at(
+            "generate.stall", telemetry.us_of(t0) * 1e-6, now - t0,
+            model=self.name, iter=self._iter,
+            rows=sum(seq is not None and seq.seq_id in before
+                     for seq in rows), **stall)
+        _stall_hist().observe(now - t0, model=self.name)
 
     def _build_step(self, prev: Optional[_Step] = None):
         """Everything the host does before a step can be dispatched:
@@ -1041,6 +1098,10 @@ class DecodeEngine:
         occupancy.observe(sum(seq is not None for seq in rows)
                           / max(1, b))
         now = time.perf_counter()
+        if self._stall is not None:
+            stall, self._stall = self._stall, None
+            self._stall_ends(stall, rows, now)
+        self._t_emit = now
         eos = self.model.conf.eos_id
         retired = 0
         for i, seq in enumerate(rows):

@@ -650,7 +650,9 @@ class TestEngineSpans:
             i = it["args"]["iter"]
             mine = [e for e in ev if e is not it
                     and e["args"].get("iter") == i]
-            kids = sorted((e for e in mine if e["args"]["parent"]
+            # (a generate.stall has no parent: it is written after
+            # the fact, by the iteration that closed it)
+            kids = sorted((e for e in mine if e["args"].get("parent")
                            == "generate.iteration"),
                           key=lambda e: e["ts"])
             assert kids, i
@@ -666,7 +668,8 @@ class TestEngineSpans:
             assert edge <= it["ts"] + it["dur"]
             for step in (k for k in kids
                          if k["name"] == "generate.decode_step"):
-                inner = sorted((e for e in mine if e["args"]["parent"]
+                inner = sorted((e for e in mine
+                                if e["args"].get("parent")
                                 == "generate.decode_step"),
                                key=lambda e: e["ts"])
                 # the pull is of the step before: a step dispatched
@@ -801,7 +804,9 @@ class TestEngineSpans:
         ring = _spans()
         assert len(ring) > 40
         off = []
-        for name in {e["name"] for e in ring}:
+        # (generate.stall is written after the fact with ``span_at``:
+        # an interval that is over has no annotation to open)
+        for name in {e["name"] for e in ring} - {"generate.stall"}:
             mine = sorted((lo + telemetry.perf_counter_of(e["ts"]) - ta,
                            e["dur"] * 1e-6)
                           for e in ring if e["name"] == name)
@@ -982,6 +987,291 @@ class TestStepInFlight:
         assert t2 == self._alone(model, eng, [8, 3], 30)
         assert pool.live_blocks == 0
         assert eng.retraces_since_warmup() == 0
+
+
+class _Stepper:
+    """Parks the engine's loop after a pass when told to, so that a
+    test decides what is queued when the next pass looks."""
+
+    def __init__(self, eng):
+        self.free, self.sem = True, threading.Semaphore(0)
+        self.parked = threading.Event()
+        once = eng._decode_iteration
+
+        def gated():
+            once()
+            if not self.free:
+                self.parked.set()
+                assert self.sem.acquire(timeout=60)
+        eng._decode_iteration = gated
+
+    def park(self):
+        """Returns once the loop stands still at the end of a pass."""
+        self.parked.clear()
+        self.free = False
+        assert self.parked.wait(60)
+
+    def one_pass(self):
+        self.parked.clear()
+        self.sem.release()
+        assert self.parked.wait(60)
+
+    def go(self):
+        self.free = True
+        self.sem.release()
+
+
+def _named(ev, name, **args):
+    return sorted((e for e in ev if e["name"] == name
+                   and all(e["args"].get(k) == v
+                           for k, v in args.items())),
+                  key=lambda e: e["ts"])
+
+
+def _inside(inner, outer):
+    return outer["ts"] <= inner["ts"] and inner["ts"] + inner["dur"] \
+        <= outer["ts"] + outer["dur"]
+
+
+class TestAdmissionRecords:
+    """ISSUE 36: the admission measured where it happens. Counts and
+    order only; no time is asserted beyond ``end >= start`` and which
+    span an instant lies in.
+
+    An admission into an engine with **no live row** writes no
+    ``generate.stall`` at all (and observes nothing): nobody was
+    waiting between two tokens, and the last emit it would start from
+    belongs to another burst of work."""
+
+    @pytest.fixture(autouse=True)
+    def _clean_ring(self):
+        from deeplearning4j_tpu.common.telemetry import MetricsRegistry
+        MetricsRegistry._reset_for_tests()
+        yield
+        MetricsRegistry._reset_for_tests()
+
+    @staticmethod
+    def _observed():
+        from deeplearning4j_tpu.serving.generative import _stall_hist
+        return _stall_hist().count_of(model="t-gen")
+
+    @staticmethod
+    def _decoding(eng, n=40):
+        """One request decoding with steps in flight, the loop parked."""
+        ctl = _Stepper(eng)
+        s1 = eng.submit(np.array([5, 9, 2, 7]), n)
+        assert s1.next(timeout=30) is not None
+        assert s1.next(timeout=30) is not None
+        ctl.park()
+        return ctl, s1
+
+    @staticmethod
+    def _emit_at(ev, t):
+        """The one ``generate.emit`` that the instant lies in."""
+        (e,) = [e for e in _named(ev, "generate.emit")
+                if e["ts"] <= t <= e["ts"] + e["dur"]]
+        return e
+
+    def test_an_admission_adds_one_record_and_none_to_its_iteration(
+            self):
+        """The spans of the admitting iteration are what they were:
+        the landed pairs, ``generate.admit`` with its prefill, the
+        build and the restart. The stall is the one new record, and it
+        belongs to the iteration that closed it."""
+        from deeplearning4j_tpu.serving.generative import RUN_AHEAD
+        model, pool, eng = _engine()
+        ctl, s1 = self._decoding(eng)
+        s2 = eng.submit(np.array([8, 3]), 6)
+        ctl.go()
+        assert len(list(s2)) == 6 and len(list(s1)) == 38
+        eng.shutdown()
+        ev = _spans()
+        assert all(e["dur"] >= 0 for e in ev)
+        second = _named(ev, "generate.admit", admitted=1)[1]
+        i = second["args"]["iter"]
+        mine = [e for e in ev if e["args"].get("iter") == i]
+        assert not _named(mine, "generate.stall")
+        kids = sorted((e for e in mine if e["args"].get("parent")
+                       == "generate.iteration"), key=lambda e: e["ts"])
+        names = [e["name"] for e in kids]
+        landed = names.index("generate.admit")
+        assert 1 <= landed // 2 <= RUN_AHEAD
+        assert names == ["generate.pull", "generate.emit"] \
+            * (landed // 2) + ["generate.admit", "generate.build",
+                               "generate.decode_step"]
+        (prefill,) = _named(mine, "generate.prefill")
+        assert _inside(prefill, second)
+        assert not [e for e in mine
+                    if e["args"].get("parent") == "generate.prefill"]
+        # the restart is a step like any other: one dispatch of the
+        # decode program, nothing pulled behind it yet
+        assert [(e["name"], e["args"]["program"]) for e in mine
+                if e["args"].get("parent") == "generate.decode_step"] \
+            == [("generate.dispatch", "decode_step")]
+        assert kids[-1]["args"]["live"] == 2
+        assert {e["name"] for e in ev} == {
+            "generate.iteration", "generate.admit", "generate.prefill",
+            "generate.build", "generate.decode_step",
+            "generate.dispatch", "generate.pull", "generate.emit",
+            "generate.stall"}
+        assert len(_named(ev, "generate.dispatch")) \
+            == len(_named(ev, "generate.decode_step"))
+
+    def test_a_stall_carries_the_rows_that_waited(self):
+        model, pool, eng = _engine()
+        ctl, s1 = self._decoding(eng)
+        s2 = eng.submit(np.array([8, 3]), 6)
+        ctl.go()
+        list(s2), list(s1)
+        eng.shutdown()
+        ev = _spans()
+        (stall,) = _named(ev, "generate.stall")
+        a = stall["args"]
+        assert set(a) == {"model", "iter", "rows", "prefills",
+                          "prompt_tokens"}
+        assert a["rows"] == 1 and a["prefills"] == 1 \
+            and a["prompt_tokens"] == 2 and a["model"] == "t-gen"
+        # it starts in the last emit of the landing before the
+        # admission and ends in the first emit after it, whose
+        # iteration it names
+        admit = _named(ev, "generate.admit", admitted=1)[1]
+        opened = self._emit_at(ev, stall["ts"])
+        closed = self._emit_at(ev, stall["ts"] + stall["dur"])
+        assert opened["args"]["iter"] == admit["args"]["iter"] \
+            and opened["ts"] + opened["dur"] <= admit["ts"]
+        assert not [e for e in _named(ev, "generate.emit")
+                    if opened["ts"] < e["ts"] < closed["ts"]]
+        assert closed["args"]["tokens"] == 2
+        assert closed["args"]["iter"] == a["iter"] \
+            > admit["args"]["iter"]
+        (prefill,) = [e for e in _named(ev, "generate.prefill")
+                      if _inside(e, admit)]
+        assert _inside(prefill, stall)
+        assert self._observed() == 1
+
+    def test_two_requests_queued_together_are_one_episode(self):
+        model, pool, eng = _engine()
+        ctl, s1 = self._decoding(eng)
+        s2 = eng.submit(np.array([8, 3]), 6)
+        s3 = eng.submit(np.array([4, 4, 1]), 5)
+        ctl.go()
+        assert len(list(s2)) == 6 and list(s3)
+        list(s1)
+        eng.shutdown()
+        ev = _spans()
+        assert [e["args"]["admitted"]
+                for e in _named(ev, "generate.admit")][:2] == [1, 2]
+        (stall,) = _named(ev, "generate.stall")
+        a = stall["args"]
+        assert a["prefills"] == 2 and a["prompt_tokens"] == 5
+        assert a["rows"] == 1               # s1 alone was decoding
+        assert sum(_inside(e, stall)
+                   for e in _named(ev, "generate.prefill")) == 2
+        assert self._observed() == 1
+
+    def test_an_episode_closes_at_the_first_emit_after_wherever_it_is(
+            self):
+        """The restart step of one admission is landed by the next:
+        the emit that closes the first episode is one of the second
+        admission's landing, and opens the second."""
+        model, pool, eng = _engine()
+        ctl, s1 = self._decoding(eng)
+        s2 = eng.submit(np.array([8, 3]), 9)
+        ctl.one_pass()                      # admits s2, restarts
+        s3 = eng.submit(np.array([4, 4, 1]), 5)
+        ctl.go()
+        assert len(list(s2)) == 9 and list(s3)
+        list(s1)
+        eng.shutdown()
+        ev = _spans()
+        first, second = _named(ev, "generate.stall")
+        admits = _named(ev, "generate.admit", admitted=1)
+        assert first["args"]["iter"] == admits[2]["args"]["iter"] \
+            == admits[1]["args"]["iter"] + 1
+        closing = self._emit_at(ev, first["ts"] + first["dur"])
+        assert closing["args"]["parent"] == "generate.iteration"
+        assert closing["args"]["iter"] == admits[2]["args"]["iter"] \
+            and closing["ts"] + closing["dur"] <= admits[2]["ts"]
+        assert first["args"]["rows"] == 1 \
+            and closing["args"]["tokens"] == 2
+        # the second starts where the first ended: the same emit
+        assert abs(second["ts"] - first["ts"] - first["dur"]) <= 2
+        assert second["args"]["rows"] == 2
+        assert second["args"]["iter"] > first["args"]["iter"]
+        assert self._observed() == 2
+
+    def test_an_admission_into_an_idle_engine_writes_no_stall(self):
+        model, pool, eng = _engine()
+        assert len(list(eng.submit(np.array([5, 9, 2, 7]), 8))) == 8
+        time.sleep(0.2)                     # idle: the loop only polls
+        assert len(list(eng.submit(np.array([8, 3]), 8))) == 8
+        eng.shutdown()
+        ev = _spans()
+        assert len(_named(ev, "generate.prefill")) == 2
+        assert not _named(ev, "generate.stall")
+        assert self._observed() == 0
+
+    def test_the_benchmarks_reader_reads_this_engines_ring(self):
+        """``chipbench/readers/admit_stall.py`` over what a real engine
+        wrote: the names the program writes and the names the reader
+        looks for are the same names."""
+        from chipbench.readers import admit_stall
+        model, pool, eng = _engine()
+        ta = time.perf_counter()
+        ctl, s1 = self._decoding(eng)
+        s2 = eng.submit(np.array([8, 3]), 6)
+        ctl.go()
+        list(s2), list(s1)
+        eng.shutdown()
+        tb = time.perf_counter()
+        view = {"host_window": (ta, tb), "window": (50.0, 50.0 + tb - ta),
+                "records": {"t0": ta, "t_end": tb}}
+        ev = _spans()
+        (stall,) = _named(ev, "generate.stall")
+        assert admit_stall.read(view, "ms") \
+            == pytest.approx(stall["dur"] * 1e-3, abs=2e-3)
+        assert admit_stall.read(view, "ms", percentile=95) \
+            == admit_stall.read(view, "ms")
+        assert admit_stall.read(view, "gap_share") == pytest.approx(
+            100 / sum(e["args"]["tokens"]
+                      for e in _named(ev, "generate.emit")))
+
+    @pytest.mark.parametrize("buckets", [(2,), (2, 4)])
+    def test_a_landing_for_the_rows_sake_is_no_admission(self, buckets):
+        """More sequences than rows, or a smaller bucket: the steps in
+        flight are landed with no prefill behind them, so no episode
+        opens and no stall is written for it (nor for the burst into
+        the idle engine that began it)."""
+        model, pool, eng = _engine(decode_buckets=buckets)
+        ctl = _Stepper(eng)
+        ctl.free = False                    # all three admitted at once
+        streams = [eng.submit(np.array(p), n) for p, n in zip(
+            [[5, 9, 2, 7], [8, 3], [4, 4, 1]], (4, 9, 7))]
+        assert ctl.parked.wait(60)
+        ctl.go()
+        assert all(list(s) for s in streams)
+        eng.shutdown()
+        ev = _spans()
+        (admit,) = _named(ev, "generate.admit", admitted=3)
+        landed = [e for e in _named(ev, "generate.pull")
+                  if e["args"].get("parent") == "generate.iteration"
+                  and e["ts"] > admit["ts"]]
+        assert landed
+        assert not _named(ev, "generate.stall")
+        assert self._observed() == 0
+
+    @pytest.mark.parametrize("kind", ["decoder", "falcon-h1"])
+    def test_a_step_span_carries_no_count_of_state_slots(self, kind):
+        pool, eng = _cache_engine(kind)
+        list(eng.submit(np.array([5, 9, 2, 7]), 4))
+        eng.shutdown()
+        steps = _named(_spans(), "generate.decode_step")
+        assert steps and all(
+            not {"state_live", "state_slots"} & set(e["args"])
+            and e["args"]["live"] == 1 for e in steps)
+        assert bool(pool.state) == (kind != "decoder")
+        if pool.state:                      # the pool still says it
+            assert pool.report()["state"]["slots"]["live"] == 0
 
 
 def _mesh_1d():

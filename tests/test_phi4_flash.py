@@ -382,7 +382,9 @@ def test_the_spans_carry_what_each_kind_of_state_holds_and_reads():
     assert first["kv_tokens"] == 13 and first["ring_tokens"] == W
     assert first["window_read_tokens"] == 2 * W
     assert first["kv_read_tokens"] == 2 * 13 + 2 * W
-    assert first["state_live"] == 1 and first["state_slots"] == 4
+    assert first["live"] == 1 and "state_live" not in first
+    assert pool.report()["state"]["slots"] == {
+        "free": 4, "live": 0, "reserved": 1, "total": 5}
     assert [s["kv_tokens"] for s in steps] == [13, 14, 15, 16, 17]
 
 
