@@ -8,7 +8,9 @@ all live sequences, not one request. This module owns that loop:
   (through ``sdpa_core``, so the flash-attention ladder applies) on a
   per-prompt-bucket compiled program, its K/V scattered into the paged
   :class:`~deeplearning4j_tpu.serving.kvcache.KVBlockPool`, and its
-  first token sampled — the time-to-first-token span.
+  first token sampled — the time-to-first-token span. The three
+  programs are dispatched behind the decode steps in flight, and the
+  first token stays on the device until it is the oldest thing there.
 - **Decode**: every engine iteration runs ONE fused step over all live
   sequences — gather KV blocks via block tables, paged attention
   (Pallas kernel or dense-gather fallback via the ``paged_attention``
@@ -33,34 +35,47 @@ the same proof obligation as predict.
 Spans (``telemetry.span``, so also in a running ``jax.profiler`` trace):
 one pass of the loop that admitted or stepped is one
 ``generate.iteration``, and its children split the host's turn by
-cause — ``generate.admit`` (with a ``generate.prefill`` a request),
-``generate.build``, ``generate.decode_step`` (``generate.dispatch``
-then ``generate.pull``) and ``generate.emit``. They inherit the
-iteration's ``iter``; what no child covers is the iteration's self
-time. ``generate.decode_step`` carries the counts of the step it
-dispatched: ``live`` rows of ``bucket``, ``pool_live`` of
-``pool_usable`` blocks, and ``grid_blocks``, the ``bucket x
-max_blocks`` table positions of which ``pool_live`` name live KV.
+cause — ``generate.admit``, ``generate.build``,
+``generate.decode_step`` (``generate.dispatch`` then ``generate.pull``)
+and ``generate.emit``. They inherit the iteration's ``iter``; what no
+child covers is the iteration's self time. ``generate.decode_step``
+carries the counts of the step it dispatched: ``live`` rows of
+``bucket``, ``pool_live`` of ``pool_usable`` blocks, and
+``grid_blocks``, the ``bucket x max_blocks`` table positions of which
+``pool_live`` name live KV. ``generate.admit`` carries ``admitted``
+(requests that left the queue), ``behind`` (prefills dispatched while
+decode steps were in flight) and ``joined`` (those of them the next
+step took into its holes, with no landing); the counter
+``dl4j_generate_admissions_total{model,path=joined|landed|idle}``
+counts the prefills the same way (``idle``: nothing was in flight).
+A request's ``generate.prefill`` (``span_at``; ``parent``
+``generate.admit`` and ``iter`` of the admitting iteration, given
+explicitly) is written when its first token is pulled, and spans its
+dispatch to that pull: the steps queued ahead of it, its three
+programs, the read-back.
 
 **An admission's record** (one an admission episode, none a step).
 ``generate.stall`` (``span_at``, no parent; ``iter`` of the iteration
 that closed it) is **what a row that was decoding waited between two
-tokens** across an admission: from the last emit before the episode's
-first prefill began to the first emit after it, with ``prefills`` and
-``prompt_tokens`` (summed) and ``rows`` (the rows of that first emit
-that were live before the episode: the inter-token gaps that crossed
-it). Prefills with no emit between them are one episode; an admission
-into an engine with no live row has nobody waiting and writes none.
-The same seconds go to the histogram
+tokens** across an admission: from the emit of the last step
+dispatched before the episode's first prefill to the emit of the first
+step dispatched after its last, with ``prefills`` and
+``prompt_tokens`` (summed) and ``rows`` (the rows of that closing step
+that were live in the opening one: the inter-token gaps that crossed
+it). Prefills with no decode step dispatched between them are one
+episode; an admission with no step in flight has nobody waiting and
+writes none. The same seconds go to the histogram
 ``dl4j_generate_admission_stall_seconds{model}``.
 
 **The loop runs ahead of the device.** A dispatched step's ids are
 not pulled at once: the next pass builds the following step on the
 rows of the last one dispatched (their positions one further, its
 ids, still on the device, as the tokens) and dispatches it, until
-``RUN_AHEAD`` steps are queued behind the one whose ids the host then
-pulls and emits (``generate.pull`` and ``generate.emit`` are of the
-oldest step in flight, not of the one ``generate.dispatch`` sent).
+``RUN_AHEAD`` programs are queued behind the oldest in flight (a decode
+step, or the prefills queued before one, each counting one), which the
+host then pulls and emits (``generate.pull`` and ``generate.emit`` are
+of the oldest program in flight, not of the one ``generate.dispatch``
+sent).
 The device goes from step to step without waiting for the host's
 turn, and a stall of the host as long as the queued steps (a
 collector's or a hypervisor's pause: 105-125 ms now and then on the
@@ -68,12 +83,25 @@ chip's machines) does not starve it. Rows keep their places from step
 to step; a row whose last token a step in flight brings, or that has
 retired, is a hole (scratch block, scratch slot) in the next, and
 what a step computed for a row that retired meanwhile (EOS seen some
-steps late, a disconnect) is dropped. An admission lands every step
-in flight first (a ``generate.pull`` and ``generate.emit`` each,
-directly under the iteration), so a prefill never runs behind tokens
-that no client has yet, and the step after it is built afresh, its
-rows packed: a freed row is refilled ``RUN_AHEAD`` steps later than
-it could be.
+steps late, a disconnect) is dropped. **An admission joins the queue**
+and does not land it: the prefill, commit and first-token sample are
+dispatched right behind the steps in flight (the pool's arrays, passed
+from program to program, order them on the device, so a row's blocks
+and state slot can go to a joiner while steps that name their old
+sequence are still queued: those run before its commit), and the next
+step puts each joiner in a hole of the last one's rows, at its prompt
+length, its first token merged into that step's ids on the device
+(one small program, warmed a decode bucket). The admitting pass builds
+no step of its own (the next pass does; never two passes in a row):
+its host turn went on the prefills, and with a build and a dispatch
+besides its pull would come late for every row. What is in flight is
+pulled in device order, so a joiner's first token reaches its stream
+before any token of a step that carries it. Only where the joiners do
+not fit (no hole, or a sequence waits without a row) or the rows fit a
+smaller bucket does the loop land every step in flight (a
+``generate.pull`` and ``generate.emit`` each, directly under the
+iteration) and pack the rows afresh; so does an admission into an
+engine with no step in flight, which lands its first tokens.
 
 A model with recurrent state (state-space layers; it has
 ``state_shapes()``) gets a **state slot** a sequence from the same
@@ -132,8 +160,9 @@ from deeplearning4j_tpu.common.compilecache import RetraceGuard
 from deeplearning4j_tpu.serving.admission import DeadlineExceeded
 from deeplearning4j_tpu.serving.kvcache import KVBlockPool, PoolExhausted
 
-#: decode steps queued on the device behind the one whose ids the
-#: host waits for: 120 ms of cover at steps of 30 ms, a little more
+#: programs queued on the device behind the one whose results the
+#: host waits for (decode steps, and prefills with their commit and
+#: sample, one each): 120 ms of cover at steps of 30 ms, a little more
 #: than the 105-125 ms for which the chip's machines now and then
 #: stop a whole process. A freed row is refilled, and an EOS seen,
 #: this many steps later than it could be.
@@ -164,9 +193,20 @@ def _stall_hist() -> telemetry.Histogram:
     return telemetry.histogram(
         "dl4j_generate_admission_stall_seconds",
         "what a decoding row waits between two tokens when an admission "
-        "falls between them: the last token before the landing of the "
-        "steps in flight and the prefills to the first token after, "
-        "one observation an admission episode, per model (seconds)")
+        "falls between them: the token of the last step dispatched "
+        "before the prefills to that of the first step dispatched after "
+        "them, one observation an admission episode, per model "
+        "(seconds)")
+
+
+def _admissions_counter() -> telemetry.Counter:
+    return telemetry.counter(
+        "dl4j_generate_admissions_total",
+        "prefills dispatched, by model and how they met the decode "
+        "steps in flight: joined (queued behind them, each sequence "
+        "taking a hole of the next step) | landed (the steps in flight "
+        "were landed and the rows packed afresh) | idle (nothing was "
+        "in flight)")
 
 
 def _decode_step_hist() -> telemetry.Histogram:
@@ -314,7 +354,7 @@ class _Sequence:
 
     __slots__ = ("seq_id", "stream", "next_token", "position",
                  "generated", "flying", "max_tokens", "temperature",
-                 "top_k", "deadline", "t_last", "ctx")
+                 "top_k", "deadline", "t_last", "ctx", "first")
 
     def __init__(self, seq_id, stream, next_token, position,
                  max_tokens, temperature, top_k, deadline, t_last,
@@ -331,18 +371,41 @@ class _Sequence:
         self.deadline = deadline
         self.t_last = t_last                # last token emit instant
         self.ctx = ctx                      # request TraceContext
+        #: the prefill's sampled id, on the device, until a step takes
+        #: it as this row's token or the host pulls it
+        self.first = None
 
 
 class _Step:
     """A dispatched decode step whose ids are still on the device:
     the sequence of each row (None: a dead row or a hole), its bucket,
-    the ids, and when it was dispatched."""
+    the ids, when it was dispatched, the prefills dispatched between
+    the step before it and this one, and the admission episode whose
+    first end (``opens``) or last (``closes``) its tokens are."""
 
-    __slots__ = ("rows", "bucket", "ids", "t0", "counts")
+    __slots__ = ("rows", "bucket", "ids", "t0", "counts", "prefills",
+                 "opens", "closes")
 
     def __init__(self, rows, bucket, ids, t0, counts=None):
         self.rows, self.bucket, self.ids, self.t0 = rows, bucket, ids, t0
         self.counts = counts        # the model's step_counts, on the device
+        self.prefills = ()
+        self.opens = self.closes = None
+
+
+class _Prefill:
+    """A dispatched prefill whose first token is still on the device:
+    its sequence, that token and the prompt's routing counts (read back
+    by the pull), when it was dispatched, submitted and pulled, and
+    what its ``generate.prefill`` record carries."""
+
+    __slots__ = ("seq", "first", "counts", "t0", "t_submit", "t_pulled",
+                 "args")
+
+    def __init__(self, seq, first, counts, t0, t_submit, args):
+        self.seq, self.first, self.counts = seq, first, counts
+        self.t0, self.t_submit, self.t_pulled = t0, t_submit, None
+        self.args = args
 
 
 class DecodeEngine:
@@ -382,7 +445,7 @@ class DecodeEngine:
         self.guard = guard if guard is not None else RetraceGuard(
             f"generate:{name}",
             threshold=len(self.prompt_buckets)
-            + len(self.decode_buckets) + 2)
+            + 2 * len(self.decode_buckets) + 2)
         self._paged = paged
         self._seq_ids = itertools.count(1)
         self._pending: "_queue.Queue" = _queue.Queue()
@@ -391,12 +454,19 @@ class DecodeEngine:
         self._live: Dict[int, _Sequence] = {}
         #: the dispatched steps whose ids were not pulled yet
         self._inflight: "collections.deque[_Step]" = collections.deque()
+        #: the prefills dispatched since the last step: the next step
+        #: takes them (``_Step.prefills``), or a landing pulls them
+        self._parked: List[_Prefill] = []
+        #: the ``generate.admit`` records whose prefills wait for the
+        #: step that will take or land them, and whether the last pass
+        #: built no step
+        self._admissions: List[dict] = []
+        self._skipped = False
         self._t_landed = 0.0
-        #: when the last step's tokens were handed out
-        self._t_emit = 0.0
-        #: the admission episode that no emit has closed yet: what
-        #: ``generate.stall`` will carry, ``t0`` and the ids of the
-        #: sequences that were live ``before`` it
+        #: the admission episode that no decode step dispatched after
+        #: it has closed yet: what ``generate.stall`` will carry, the
+        #: ids of the sequences of the step before it (``before``) and,
+        #: once that step's tokens are out, when (``t0``)
         self._stall: Optional[dict] = None
         self._lock = threading.Lock()
         self._worker: Optional[threading.Thread] = None
@@ -513,12 +583,22 @@ class DecodeEngine:
             self._jits["decode"] = jax.jit(fn, donate_argnums=(1,))
         return self._jits["decode"]
 
+    def _merge_jit(self):
+        import jax
+        if "merge" not in self._jits:
+            def fn(ids, row, first):
+                # a joiner's first token, sampled by its prefill, as
+                # the token of the row it takes in the next step
+                return ids.at[row].set(first[0])
+            self._jits["merge"] = jax.jit(fn)
+        return self._jits["merge"]
+
     # -- warmup --------------------------------------------------------
     def warmup(self) -> float:
         """Compile every prompt bucket's prefill+commit and every
-        decode bucket's fused step (dummy data, blocked to
-        completion). The guard count freezes here — any later new
-        signature is a bucket miss."""
+        decode bucket's fused step and first-token merge (dummy data,
+        blocked to completion). The guard count freezes here — any
+        later new signature is a bucket miss."""
         import jax
         t0 = time.perf_counter()
         for t in self.prompt_buckets:
@@ -557,9 +637,12 @@ class DecodeEngine:
             ids, cache, *_ = self._decode_jit()(
                 self.params, self.pool.arrays, tokens, *rest)
             self.pool.update_arrays(*cache)
-            # and with its tokens as every step but the first after an
-            # admission gets them: the ids of the step before, still
-            # on the device
+            # and with its tokens as every step built on the one before
+            # gets them: that step's ids, still on the device, with a
+            # joiner's first token merged in
+            row = np.int32(0)
+            self.guard.record(ids, row, first)
+            ids = self._merge_jit()(ids, row, first)
             ids, cache, *_ = self._decode_jit()(
                 self.params, self.pool.arrays, ids, *rest)
             self.pool.update_arrays(*cache)
@@ -667,6 +750,8 @@ class DecodeEngine:
             if self._pending.empty() and not self._live \
                     and self._held is None:
                 self._inflight.clear()  # nothing but holes is in them
+                self._parked = []
+                self._settle(False)
                 self._stall = None      # and nobody waits for a token
                 # Idle — and only exit on shutdown/supersession while
                 # idle: every pending request was admitted and every
@@ -680,27 +765,57 @@ class DecodeEngine:
             self._iter += 1
             with telemetry.span("generate.iteration", model=self.name,
                                 iter=self._iter):
+                build = True
                 if not self._pending.empty() or self._held is not None:
-                    if self._held is None or self.pool.free_slots:
-                        # a prefill is coming: it does not run behind
-                        # tokens that no client has yet
-                        self._land()
-                    with telemetry.span("generate.admit") as args:
-                        args["admitted"] = self._admit_pending()
-                self._decode_iteration()
+                    with telemetry.span("generate.admit") as admit:
+                        prefills = self._admit_pending(admit)
+                    if admit["behind"]:
+                        self._admissions.append(admit)
+                        # a pass whose host turn went on prefills queued
+                        # behind the steps in flight builds no step (the
+                        # next one does: never two passes in a row), so
+                        # its pull keeps the device's beat, which a build
+                        # and a dispatch besides would make late for
+                        # every row
+                        build = self._skipped
+                    elif prefills:
+                        _admissions_counter().inc(
+                            prefills, model=self.name, path="idle")
+                self._skipped = not build
+                self._settle(self._decode_iteration(build))
 
-    def _admit_pending(self) -> int:
+    def _settle(self, queued: Optional[bool]) -> None:
+        """Whether the step built after the admissions of the last
+        passes took their prefills into the queue (``queued``; None:
+        no step was built): their records (the ring holds their args)
+        and the counter say so once it is known."""
+        if queued is None:
+            return
+        for admit in self._admissions:
+            admit["joined"] = admit["behind"] if queued else 0
+            _admissions_counter().inc(
+                admit["behind"], model=self.name,
+                path="joined" if queued else "landed")
+        self._admissions.clear()
+
+    def _admit_pending(self, args: dict) -> int:
         """Prefill every queued request (each its own bucket-padded
-        pass), then join it to the decode batch. Returns how many
-        left the queue."""
-        admitted = 0
+        pass) behind whatever is in flight, and join it to the live
+        sequences. Puts into ``args``, what ``generate.admit`` carries,
+        how many left the queue (``admitted``), the prefills dispatched
+        while steps were in flight (``behind``) and, for now, none of
+        them taken into a step's holes (``joined``: set once the step
+        after them is built, :meth:`_settle`). Returns how many
+        prefills it dispatched."""
+        in_flight = bool(self._inflight)
+        admitted = prefills = 0
         while True:
             item, self._held = self._held, None
             if item is None:
                 try:
                     item = self._pending.get_nowait()
                 except _queue.Empty:
-                    return admitted
+                    break
             (seq_id, prompt, max_tokens, temperature, top_k, deadline,
              stream, t_submit, ctx) = item
             if stream.cancelled or (deadline is not None
@@ -716,7 +831,7 @@ class DecodeEngine:
                 # every state slot is live: the head of the queue
                 # waits for a retirement, like one that finds no row
                 self._held = item
-                return admitted
+                break
             admitted += 1
             try:
                 self._prefill_one(seq_id, prompt, max_tokens,
@@ -725,6 +840,11 @@ class DecodeEngine:
             except BaseException as e:      # noqa: BLE001
                 self.pool.free(seq_id)
                 self._finish(stream, "error", e)
+                continue
+            prefills += 1
+        args.update(admitted=admitted,
+                    behind=prefills if in_flight else 0, joined=0)
+        return prefills
 
     def _prompt_bucket(self, n: int) -> int:
         for b in self.prompt_buckets:
@@ -734,71 +854,89 @@ class DecodeEngine:
 
     def _prefill_one(self, seq_id, prompt, max_tokens, temperature,
                      top_k, deadline, stream, t_submit, ctx=None):
+        """Dispatch the prompt's prefill, its commit into the pool and
+        its first token's sample behind whatever is in flight, and make
+        the sequence live with that token still on the device: the
+        host reads it when it is the oldest thing in flight
+        (:meth:`_hand_out`)."""
         import jax
         t_prefill = time.perf_counter()
-        whose = {"seq": seq_id}
+        t = self._prompt_bucket(prompt.size)
+        args = {"parent": "generate.admit", "iter": self._iter,
+                "model": self.name, "tokens": int(prompt.size),
+                "bucket": t,
+                "queue_ms": round((t_prefill - t_submit) * 1e3, 3),
+                "seq": seq_id}
         if ctx:
             # engine-side queue phase: submit -> prefill start
             ctx.phase_at("queue", t_submit, t_prefill)
-            whose["trace"] = ctx.trace_id
-        t = self._prompt_bucket(prompt.size)
+            args["trace"] = ctx.trace_id
         if self.pool.state:
-            whose["state_slot"] = self.pool.slot(seq_id)
+            args["state_slot"] = self.pool.slot(seq_id)
         if hasattr(self.model, "prefill_layer_positions"):
             done, dense = self.model.prefill_layer_positions(t)
-            whose.update(positions=t, layer_positions=done,
-                         layer_positions_dense=dense)
+            args.update(positions=t, layer_positions=done,
+                        layer_positions_dense=dense)
         temps = np.asarray([temperature], np.float32)
         topks = np.asarray([top_k], np.int32)
-        whose.update(self._name_sample(temps, topks))
+        args.update(self._name_sample(temps, topks))
         self._stall_grows(int(prompt.size))
-        with telemetry.span(
-                "generate.prefill", model=self.name,
-                tokens=int(prompt.size), bucket=t,
-                queue_ms=round((t_prefill - t_submit) * 1e3, 3),
-                **whose) as args:
-            tokens = np.zeros((1, t), np.int32)
-            tokens[0, :prompt.size] = prompt
-            length = np.asarray([prompt.size], np.int32)
-            self._record(tokens, length)
-            last, *new = self._prefill_jit()(self.params, tokens,
-                                             length)
-            counts = new.pop() if self._counted else None
-            # the bucket's K/V into the prompt's pool blocks (the
-            # blocks of the bucket past the prompt's last land in
-            # scratch block 0)
-            blocks = self.pool.padded_table(seq_id,
-                                            self.pool.blocks_for(t))
-            self._record(new[0], blocks)
-            self.pool.update_arrays(*self._commit_jit()(
-                self.pool.arrays, tuple(new), blocks,
-                *self._state_arg(np.int32(self.pool.slot(seq_id)))))
-            self._step += 1
-            key = jax.random.fold_in(self._rng, self._step)
-            first = int(np.asarray(self._sample_jit()(
-                last, key, temps, topks))[0])
-            args.update(self._name_counts(counts))
-        now = time.perf_counter()
-        _ttft_hist().observe(now - t_submit, model=self.name)
+        tokens = np.zeros((1, t), np.int32)
+        tokens[0, :prompt.size] = prompt
+        length = np.asarray([prompt.size], np.int32)
+        self._record(tokens, length)
+        last, *new = self._prefill_jit()(self.params, tokens, length)
+        counts = new.pop() if self._counted else None
+        # the bucket's K/V into the prompt's pool blocks (the blocks of
+        # the bucket past the prompt's last land in scratch block 0)
+        blocks = self.pool.padded_table(seq_id, self.pool.blocks_for(t))
+        self._record(new[0], blocks)
+        self.pool.update_arrays(*self._commit_jit()(
+            self.pool.arrays, tuple(new), blocks,
+            *self._state_arg(np.int32(self.pool.slot(seq_id)))))
+        self._step += 1
+        key = jax.random.fold_in(self._rng, self._step)
+        first = self._sample_jit()(last, key, temps, topks)
         if ctx is not None:
-            # the prefill forward + commit + first-token sample is
-            # this request's device phase (decode steps are shared
-            # across the live batch, attributed as instants instead)
-            ctx.phase_at("device", t_prefill, now)
             ctx.note(kv_blocks=len(self.pool.table(seq_id)),
                      prompt_tokens=int(prompt.size))
-        stream._put(first)
-        _tokens_counter().inc(model=self.name)
-        eos = self.model.conf.eos_id
-        if first == eos or max_tokens <= 1:
-            self.pool.free(seq_id)
-            self._finish(stream,
-                         "eos" if first == eos else "max_tokens")
-            return
-        self._live[seq_id] = _Sequence(
-            seq_id, stream, first, int(prompt.size), max_tokens,
-            temperature, top_k, deadline, now, ctx)
+        seq = self._live[seq_id] = _Sequence(
+            seq_id, stream, 0, int(prompt.size), max_tokens, temperature,
+            top_k, deadline, t_prefill, ctx)
+        seq.first = first
+        self._parked.append(
+            _Prefill(seq, first, counts, t_prefill, t_submit, args))
         _live_gauge().set(len(self._live), model=self.name)
+
+    def _hand_out(self, done: _Prefill) -> int:
+        """A prefill's first token, pulled, to its stream: the
+        ``generate.prefill`` record, time-to-first-token, the request's
+        device phase, and retirement on EOS or ``max_tokens``. Returns
+        1 where the sequence retired (0 where it had already left: a
+        disconnect or a deadline before its first token came back)."""
+        seq, tok = done.seq, done.first
+        telemetry.span_at(
+            "generate.prefill", telemetry.us_of(done.t0) * 1e-6,
+            done.t_pulled - done.t0, **done.args,
+            **self._name_counts(done.counts))
+        if self._live.get(seq.seq_id) is not seq:
+            return 0
+        now = time.perf_counter()
+        _ttft_hist().observe(now - done.t_submit, model=self.name)
+        if seq.ctx is not None:
+            # the steps queued ahead, the prefill forward, commit and
+            # first-token sample are this request's device phase
+            # (decode steps are shared across the live batch,
+            # attributed as instants instead)
+            seq.ctx.phase_at("device", done.t0, done.t_pulled)
+        seq.stream._put(tok)
+        _tokens_counter().inc(model=self.name)
+        seq.next_token, seq.first, seq.t_last = tok, None, now
+        eos = self.model.conf.eos_id
+        if tok == eos or seq.max_tokens <= 1:
+            self._retire(seq, "eos" if tok == eos else "max_tokens")
+            return 1
+        return 0
 
     def _decode_bucket(self, n: int) -> int:
         for b in self.decode_buckets:
@@ -872,31 +1010,41 @@ class DecodeEngine:
             idle.inc(named["moe_experts_held"] - named["moe_experts_hit"])
         return named
 
-    def _decode_iteration(self) -> None:
+    def _decode_iteration(self, build: bool = True) -> Optional[bool]:
         """ONE fused step over all live sequences (the iteration of
         iteration-level scheduling), dispatched behind the steps in
-        flight; the oldest of those is pulled and emitted once
-        ``RUN_AHEAD`` are queued behind it."""
+        flight; the oldest program in flight (a step, or the prefills
+        queued before it) is pulled and emitted once ``RUN_AHEAD`` are
+        queued behind it. Returns whether the step was built on the ones
+        in flight, with no landing; with ``build`` False it only pulls
+        what is due and returns None."""
         flying = self._inflight
         if not self._live:
             flying.clear()
-            return
+            self._parked = []
+            self._stall = None
+            return False
+        if not build:
+            if self._queued() > RUN_AHEAD:
+                self._emit_step(*self._pull_one())
+            return None
         step = None
         if flying:
             with telemetry.span("generate.build"):
                 step = self._build_step(flying[-1])
-            if step is None:
-                # the rows of the steps in flight will not do for
-                # another (a smaller bucket, a sequence without a
-                # row): land them, then pack the rows afresh
-                self._land()
+        queued = step is not None
         if step is None:
-            if not self._live:
-                return
-            with telemetry.span("generate.build"):
-                step = self._build_step()
+            # no step in flight (an admission into an idle engine), or
+            # its rows will not do for another (a joiner without a
+            # hole, a sequence without a row, a smaller bucket): land
+            # what is in flight, then pack the rows afresh
+            self._land()
+            if self._live:
+                with telemetry.span("generate.build"):
+                    step = self._build_step()
             if step is None:
-                return
+                self._stall = None      # nobody is left waiting
+                return False
         rows, b, inputs, sample = step
         pool = self.pool
         got = None
@@ -918,24 +1066,57 @@ class DecodeEngine:
             for seq in rows:
                 if seq is not None:
                     seq.flying += 1
-            flying.append(_Step(rows, b, ids, t0, *routed))
-            if len(flying) > RUN_AHEAD:
-                got = self._pull(flying.popleft())
+            dispatched = _Step(rows, b, ids, t0, *routed)
+            # the prefills run before it; the first step after an
+            # admission episode's prefills closes it
+            dispatched.prefills, self._parked = self._parked, []
+            dispatched.closes, self._stall = self._stall, None
+            flying.append(dispatched)
+            if self._queued() > RUN_AHEAD:
+                got = self._pull_one()
         if got is not None:
             self._emit_step(*got)
+        return queued
 
-    def _pull(self, step: _Step):
-        """A dispatched step, its ids on the host, and the seconds the
-        device had for it (since it was dispatched or the step before
-        it landed, whichever came later)."""
+    def _queued(self) -> int:
+        """The programs in flight: the decode steps, and the prefills
+        (each with its commit and sample) not pulled yet."""
+        return len(self._inflight) + len(self._parked) \
+            + sum(len(s.prefills) for s in self._inflight)
+
+    def _pull_one(self):
+        """The oldest in flight, read back (:meth:`_pull`): the prefills
+        queued before the oldest step while any are left, else that
+        step."""
+        oldest = self._inflight[0]
+        if oldest.prefills:
+            prefills, oldest.prefills = oldest.prefills, ()
+            return self._pull(prefills)
+        self._inflight.popleft()
+        return self._pull((), oldest)
+
+    def _pull(self, prefills, step: Optional[_Step] = None):
+        """Dispatched prefills and the step queued behind them (if
+        any), read back to the host in that order: ``(prefills, step,
+        ids, step_s)``, each prefill with its first token read, the
+        step's ids, and the seconds the device had for the step (since
+        it was dispatched or what ran before it landed, whichever came
+        later)."""
         with telemetry.span("generate.pull"):
+            for p in prefills:
+                p.first = int(np.asarray(p.first)[0])
+                if p.counts is not None:
+                    p.counts = np.asarray(p.counts)
+                p.t_pulled = self._t_landed = time.perf_counter()
+            if step is None:
+                return prefills, None, None, None
             ids = np.asarray(step.ids)
             if step.counts is not None:
                 step.counts = np.asarray(step.counts)
         now = time.perf_counter()
         step_s = now - max(step.t0, self._t_landed)
         self._t_landed = now
-        return step, ids, step_s
+        return prefills, step, ids, step_s
 
     def _still_live(self, rows) -> list:
         """``rows`` with None for every sequence that has retired."""
@@ -943,46 +1124,55 @@ class DecodeEngine:
                 and self._live.get(seq.seq_id) is seq else None
                 for seq in rows]
 
-    def _emit_step(self, step: _Step, ids, step_s) -> None:
-        # what the step computed for a row that retired meanwhile
-        # (EOS seen some steps late, a disconnect) is dropped
-        rows = self._still_live(step.rows)
-        with telemetry.span(
-                "generate.emit",
-                tokens=sum(seq is not None for seq in rows)) as args:
-            args["retired"] = self._emit(rows, step.bucket, ids, step_s)
-            args.update(self._name_counts(step.counts))
+    def _emit_step(self, prefills, step: Optional[_Step], ids,
+                   step_s) -> None:
+        """Hand out what one pull brought: the prefills' first tokens,
+        then the step's tokens."""
+        with telemetry.span("generate.emit") as args:
+            retired = sum(self._hand_out(p) for p in prefills)
+            # what the step computed for a row that retired meanwhile
+            # (EOS seen some steps late, a disconnect) is dropped
+            rows = self._still_live(step.rows) if step is not None else ()
+            args["tokens"] = sum(seq is not None for seq in rows)
+            if step is not None:
+                retired += self._emit(step, rows, ids, step_s)
+                args.update(self._name_counts(step.counts))
+            args["retired"] = retired
 
     def _land(self) -> None:
-        """Pull and emit every step in flight, oldest first, without
-        dispatching another."""
+        """Pull and hand out everything in flight, oldest first,
+        without dispatching another step."""
         while self._inflight:
-            self._emit_step(*self._pull(self._inflight.popleft()))
+            step = self._inflight.popleft()
+            self._emit_step(*self._pull(step.prefills, step))
+        if self._parked:
+            parked, self._parked = self._parked, []
+            self._emit_step(*self._pull(parked))
 
     def _stall_grows(self, prompt_tokens: int) -> None:
-        """A prefill is about to hold the decoding rows up: it opens
-        an admission episode, or joins the one that no emit has closed
-        yet. The rows that wait are those that had a token by the last
-        emit (not the ones this admission has just prefilled); with
-        none of them, as in an idle engine, nobody waits: no episode,
-        and no ``generate.stall``."""
+        """A prefill is about to be dispatched behind the steps in
+        flight and to hold the decoding rows up: it opens an admission
+        episode at the last step dispatched, or joins the one that no
+        step dispatched since has closed. The rows that wait are that
+        step's; with no step in flight, as in an idle engine, nobody
+        waits: no episode, and no ``generate.stall``."""
         stall = self._stall
         if stall is None:
-            before = frozenset(
-                seq.seq_id for seq in self._live.values()
-                if seq.t_last <= self._t_emit)
+            prev = self._inflight[-1] if self._inflight else None
+            before = prev is not None and frozenset(
+                seq.seq_id for seq in self._still_live(prev.rows)
+                if seq is not None)
             if not before:
                 return
-            stall = self._stall = {
-                "t0": self._t_emit, "before": before, "prefills": 0,
-                "prompt_tokens": 0}
+            stall = self._stall = prev.opens = {
+                "before": before, "prefills": 0, "prompt_tokens": 0}
         stall["prefills"] += 1
         stall["prompt_tokens"] += prompt_tokens
 
     def _stall_ends(self, stall: dict, rows, now: float) -> None:
-        """The first tokens after an admission episode are going out:
-        what a row that was decoding waited, as ``generate.stall`` and
-        in the histogram."""
+        """The tokens of the first step after an admission episode are
+        going out: what a row that was decoding waited, as
+        ``generate.stall`` and in the histogram."""
         t0, before = stall.pop("t0"), stall.pop("before")
         telemetry.span_at(
             "generate.stall", telemetry.us_of(t0) * 1e-6, now - t0,
@@ -998,8 +1188,12 @@ class DecodeEngine:
         (:meth:`_name_sample`). With ``prev``, the last
         step dispatched and still in flight, the rows keep their
         places, stand as many positions further as steps in flight
-        carry them, and take its ids as their tokens. None when no
-        row is left, or when the rows of ``prev`` will not do."""
+        carry them, and take its ids as their tokens; each joiner (a
+        sequence admitted since, its first token still on the device)
+        takes a hole at its prompt length, that token merged into its
+        row. None when no row is left, or when the rows of ``prev``
+        will not do: a sequence without a row that is no joiner, more
+        joiners than holes, or, with no joiner, a smaller bucket."""
         import jax
         now = time.monotonic()
         # pre-step retirement: cancelled / deadline sequences leave
@@ -1020,18 +1214,19 @@ class DecodeEngine:
                     self._retire(seq, "kv_pool", e)
             rows = list(self._live.values())[:self.decode_buckets[-1]]
             b = self._decode_bucket(len(rows))
+            joined = ()
         else:
-            # a row whose last token a step in flight brings, or
-            # that has retired, is a hole in this one
-            rows = [seq if seq is not None and seq.generated
-                    + seq.flying < seq.max_tokens else None
-                    for seq in self._still_live(prev.rows)]
-            n = sum(seq is not None for seq in rows)
+            rows, rowless = self._rows_after(prev)
+            holes = [i for i, seq in enumerate(rows) if seq is None]
             b = prev.bucket
-            if n != sum(seq.generated + seq.flying < seq.max_tokens
-                        for seq in self._live.values()) \
-                    or self._decode_bucket(n) != b:
-                return None             # a sequence without a row
+            if any(seq.first is None for seq in rowless) \
+                    or len(rowless) > len(holes) or not rowless \
+                    and self._decode_bucket(b - len(holes)) != b:
+                return None
+            # a step with joiners keeps its bucket: the next one packs
+            # the rows into a smaller one if they fit
+            for i, seq in zip(holes, rowless):
+                rows[i] = seq
             for i, seq in enumerate(rows):
                 if seq is not None:
                     try:
@@ -1039,6 +1234,8 @@ class DecodeEngine:
                     except PoolExhausted as e:
                         self._retire(seq, "kv_pool", e)
                         rows[i] = None
+            joined = [(i, seq) for i, seq in zip(holes, rowless)
+                      if rows[i] is seq]
         if not any(seq is not None for seq in rows):
             return None
         tokens = np.zeros((b,), np.int32)
@@ -1060,11 +1257,30 @@ class DecodeEngine:
         self._record(tokens, positions, tables, temps, topks)
         self._step += 1
         key = jax.random.fold_in(self._rng, self._step)
-        inputs = (tokens if prev is None else prev.ids, positions,
-                  tables, key, temps, topks,
+        ids = tokens if prev is None else prev.ids
+        for i, seq in joined:
+            row = np.int32(i)
+            self._record(ids, row, seq.first)
+            ids, seq.first = self._merge_jit()(ids, row, seq.first), None
+        inputs = (ids, positions, tables, key, temps, topks,
                   *self._state_arg(state_slots))
         return rows, b, inputs, {**self._name_sample(temps, topks),
                                  **self._cache_reads(rows, positions)}
+
+    def _rows_after(self, prev: _Step):
+        """The rows of a step built on ``prev``, padded to its bucket,
+        with a hole (None) where a sequence retired or a step in flight
+        brings its last token; and the live sequences that need a step
+        and have no row there (joiners, or sequences that wait)."""
+        rows = [seq if seq is not None and seq.generated
+                + seq.flying < seq.max_tokens else None
+                for seq in self._still_live(prev.rows)]
+        rows += [None] * (prev.bucket - len(rows))
+        placed = {seq.seq_id for seq in rows if seq is not None}
+        rowless = [seq for seq in self._live.values()
+                   if seq.generated + seq.flying < seq.max_tokens
+                   and seq.seq_id not in placed]
+        return rows, rowless
 
     def _cache_reads(self, rows, positions) -> dict:
         """The K/V tokens this step's live rows hold and its layers
@@ -1089,19 +1305,21 @@ class DecodeEngine:
                        * reads["kv_token_bytes"] + ring_bytes)
         return out
 
-    def _emit(self, rows, b, ids, step_s) -> int:
-        """Hand every row its token: meters, the stream's queue, the
-        request's ``inter_token`` instant, and retirement on EOS or
-        ``max_tokens``. Returns how many rows retired."""
+    def _emit(self, step: _Step, rows, ids, step_s) -> int:
+        """Hand every row of ``step`` still live (``rows``) its token:
+        meters, the stream's queue, the request's ``inter_token``
+        instant, and retirement on EOS or ``max_tokens``; and the ends
+        of the admission episodes the step bounds. Returns how many
+        rows retired."""
         step_hist, occupancy, tokens, gap_hist, *_ = self._meters()
         step_hist.observe(step_s)
         occupancy.observe(sum(seq is not None for seq in rows)
-                          / max(1, b))
+                          / max(1, step.bucket))
         now = time.perf_counter()
-        if self._stall is not None:
-            stall, self._stall = self._stall, None
-            self._stall_ends(stall, rows, now)
-        self._t_emit = now
+        if step.closes is not None:
+            self._stall_ends(step.closes, rows, now)
+        if step.opens is not None:
+            step.opens["t0"] = now
         eos = self.model.conf.eos_id
         retired = 0
         for i, seq in enumerate(rows):

@@ -55,3 +55,62 @@ def require_devices(n: int):
     have = len(jax.devices())
     if have < n:
         pytest.skip(f"needs {n} devices, have {have}")
+
+
+@pytest.fixture
+def joins_a_retired_row():
+    """The served models' engine test of an admission that joins the
+    steps in flight (see :func:`_joins_a_retired_row`)."""
+    return _joins_a_retired_row
+
+
+def _joins_a_retired_row(model, params, pool, eng):
+    """Two rows and two state slots. B decodes; A is cancelled after
+    three tokens, and C, submitted once A's stream has closed, is
+    prefilled behind the steps that still name A: it takes A's state
+    slot and blocks and, in the next step, A's row (the one hole of a
+    two-row bucket) with no landing; those steps write A's row before
+    C's commit. Every stream is the reference's greedy tokens. Returns
+    C's ``generate.prefill`` args."""
+    import numpy as np
+
+    from deeplearning4j_tpu.common import telemetry
+    rs = np.random.RandomState(11)
+    pa, pb, pc = (rs.randint(2, 90, n) for n in (7, 5, 9))
+    n_events = len(telemetry.trace_events())
+    b = eng.submit(pb, 24)
+    a = eng.submit(pa, 40)
+    head = [a.next(timeout=120) for _ in range(3)]
+    slot, blocks = pool.slot(a.seq_id), set(pool.table(a.seq_id))
+    a.cancel()
+    head += list(a)                 # the stream closes as A retires
+    c = eng.submit(pc, 6)
+    assert set(pool.table(c.seq_id)) & blocks
+    got_c, got_b = list(c), list(b)
+    eng.shutdown()
+    assert a.reason == "cancelled"
+    assert c.reason == b.reason == "max_tokens"
+    for prompt, got in ((pa, head), (pb, got_b), (pc, got_c)):
+        # greedy: every served token is what the reference's forward
+        # over the prompt and the served tokens puts first there
+        logits = np.asarray(model.forward(
+            params, np.asarray([list(prompt) + got], np.int32))[0])
+        at = np.arange(len(prompt) - 1, len(prompt) + len(got) - 1)
+        assert got == logits[at].argmax(-1).tolist()
+    assert len(got_b) == 24 and len(got_c) == 6
+    assert eng.retraces_since_warmup() == 0
+    assert pool.free_slots == 2 and pool.free_blocks == pool.usable_blocks
+    events = [e for e in telemetry.trace_events()[n_events:]
+              if e.get("ph") == "X"]
+    (prefill,) = [e["args"] for e in events if e["name"]
+                  == "generate.prefill" and e["args"]["seq"] == c.seq_id]
+    assert prefill["state_slot"] == slot
+    i = prefill["iter"]
+    (admit,) = [e["args"] for e in events if e["name"] == "generate.admit"
+                and e["args"]["iter"] == i]
+    assert admit["admitted"] == admit["behind"] == admit["joined"] == 1
+    # the admitting pass builds no step: the next one takes C in
+    (step,) = [e["args"] for e in events if e["name"]
+               == "generate.decode_step" and e["args"]["iter"] == i + 1]
+    assert step["live"] == step["bucket"] == 2
+    return prefill

@@ -381,3 +381,13 @@ def test_the_spans_and_gauges_carry_the_state_counts():
     assert pool.state_bytes == 2 * 5 * (4 * 8 * 16 + 3 * 96) * 4
     assert 'dl4j_state_pool_slots{pool="t-h1e",state="free"} 4' in text
     assert f'dl4j_state_pool_bytes{{pool="t-h1e"}} {pool.state_bytes}' in text
+
+
+def test_a_joiner_takes_the_row_slot_and_blocks_of_a_retired_sequence(
+        joins_a_retired_row):
+    """The state slot a cancelled sequence held goes to the request
+    admitted behind the steps that still update it: their writes land
+    before its commit, and its state is its own prompt's."""
+    model, params, pool, eng = _engine(state_slots=3, decode_buckets=(2,))
+    prefill = joins_a_retired_row(model, params, pool, eng)
+    assert prefill["tokens"] == 9 and prefill["bucket"] == 16

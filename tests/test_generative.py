@@ -339,7 +339,12 @@ class TestDecodeEngine:
         from deeplearning4j_tpu.serving.generative import \
             _sample_path_counter
         MetricsRegistry._reset_for_tests()
-        model, pool, eng = _engine()
+        # an eos outside the vocabulary: the sampled request's key folds
+        # in the step count at its admission, which the loop's timing
+        # sets, so no draw of it may end the request early
+        conf = DecoderConfig.tiny()
+        model, pool, eng = _engine(DecoderConfig(
+            **{**conf.__dict__, "eos_id": conf.vocab_size}))
         try:
             # of one length: the reference compiles once a length
             prompts = [np.array([5, 9, 2, 7]), np.array([8, 3, 6, 1])]
@@ -398,8 +403,16 @@ class TestDecodeEngine:
             assert text.count("stablehlo.case") == 1
             assert len(jax.tree.leaves(lowered.in_avals)) \
                 == len(jax.tree.leaves(args))
+            # (and the merge of a joiner's first token into a step's
+            # ids, one ``ids.at[row].set`` and nothing else)
             assert set(eng._jits) == {"prefill", "commit", "sample",
-                                      "decode"}
+                                      "decode", "merge"}
+            merge = eng._merge_jit().lower(
+                np.zeros((4,), np.int32), np.int32(0),
+                np.zeros((1,), np.int32)).as_text()
+            assert "stablehlo.scatter" in merge \
+                or "dynamic_update_slice" in merge
+            assert "stablehlo.sort" not in merge
         finally:
             eng.shutdown()
 
@@ -687,8 +700,14 @@ class TestEngineSpans:
         steps = [e for e in ev if e["name"] == "generate.decode_step"]
         pulls = [e for e in ev if e["name"] == "generate.pull"]
         # every step is pulled once, some of them after later ones
-        # were dispatched
-        assert len(pulls) == len(steps)
+        # were dispatched; an admission into an engine with no step in
+        # flight adds one pull, of its first tokens, and prefills queued
+        # behind steps are pulled on their own or with their step
+        idle = [e for e in ev if e["name"] == "generate.admit"
+                and e["args"]["admitted"] and not e["args"]["behind"]]
+        assert idle
+        assert len(steps) + len(idle) <= len(pulls) \
+            <= len(steps) + len(_named(ev, "generate.prefill"))
         assert any(e["args"]["parent"] == "generate.decode_step"
                    for e in pulls)
         # every span of the family belongs to some iteration
@@ -804,9 +823,11 @@ class TestEngineSpans:
         ring = _spans()
         assert len(ring) > 40
         off = []
-        # (generate.stall is written after the fact with ``span_at``:
-        # an interval that is over has no annotation to open)
-        for name in {e["name"] for e in ring} - {"generate.stall"}:
+        # (generate.stall and generate.prefill are written after the
+        # fact with ``span_at``: an interval that is over has no
+        # annotation to open)
+        for name in {e["name"] for e in ring} - {"generate.stall",
+                                                 "generate.prefill"}:
             mine = sorted((lo + telemetry.perf_counter_of(e["ts"]) - ta,
                            e["dur"] * 1e-6)
                           for e in ring if e["name"] == name)
@@ -860,12 +881,15 @@ class TestStepInFlight:
         for pair in inner[RUN_AHEAD:]:
             assert [e["name"] for e in pair] == [
                 "generate.dispatch", "generate.pull"]
-        # the last steps land under the iteration that finds no row
-        # left for another
-        last = [e for e in ev if e["name"] == "generate.pull"
-                and e["args"].get("parent") == "generate.iteration"]
-        assert len(last) == RUN_AHEAD
-        assert all(e["ts"] > steps[-1]["ts"] for e in last)
+        # the first token lands under the iteration that admitted it
+        # into an idle engine, before the first step; the last steps
+        # under the one that finds no row left for another
+        landed = sorted((e for e in ev if e["name"] == "generate.pull"
+                         and e["args"].get("parent")
+                         == "generate.iteration"), key=lambda e: e["ts"])
+        assert len(landed) == RUN_AHEAD + 1
+        assert landed[0]["ts"] < steps[0]["ts"]
+        assert all(e["ts"] > steps[-1]["ts"] for e in landed[1:])
 
     @pytest.mark.parametrize("lengths", [(3, 9, 6), (9, 3, 6),
                                          (2, 2, 12), (12, 5, 5)])
@@ -892,8 +916,11 @@ class TestStepInFlight:
         if all(len(g) == n for g, n in zip(got, lengths)):
             # a row that ends on max_tokens leaves a hole and the loop
             # runs on: nothing lands but at the very end
+            # (after the landing of the first tokens, which came back
+            # before the first step was built)
             landed = [e for e in _spans() if e["name"] == "generate.pull"
-                      and e["args"].get("parent") == "generate.iteration"]
+                      and e["args"].get("parent") == "generate.iteration"
+                      and e["ts"] > steps[0]["ts"]]
             assert len(landed) == min(RUN_AHEAD, len(steps))
             assert all(e["ts"] > steps[-1]["ts"] for e in landed)
         lives = [a["args"]["live"] for a in steps]
@@ -905,47 +932,86 @@ class TestStepInFlight:
         """Three sequences on two rows: the third steps when a row is
         free; and with two buckets the rows are packed into the
         smaller one once they fit. Either way the step in flight is
-        landed and the rows are built afresh."""
+        landed and the rows are built afresh; the one admission (all
+        three at once, into an idle engine) joined nothing."""
         model, pool, eng = _engine(decode_buckets=buckets)
+        ctl = _Stepper(eng)
+        ctl.free = False                    # all three admitted at once
         prompts, lengths = [[5, 9, 2, 7], [8, 3], [4, 4, 1]], (4, 9, 7)
         streams = [eng.submit(np.array(p), n)
                    for p, n in zip(prompts, lengths)]
+        assert ctl.parked.wait(60)
+        ctl.go()
         got = [list(s) for s in streams]
         eng.shutdown()
         for p, n, g in zip(prompts, lengths, got):
             assert g == self._alone(model, eng, p, n)
         assert pool.live_blocks == 0
         assert eng.retraces_since_warmup() == 0
-        used = {e["args"]["bucket"] for e in _spans()
+        ev = _spans()
+        used = {e["args"]["bucket"] for e in ev
                 if e["name"] == "generate.decode_step"}
         assert used == set(buckets)
+        (admit,) = _named(ev, "generate.admit")
+        assert (admit["args"]["admitted"], admit["args"]["behind"],
+                admit["args"]["joined"]) == (3, 0, 0)
+        first = _named(ev, "generate.decode_step")[0]
+        assert [e for e in _named(ev, "generate.pull",
+                                  parent="generate.iteration")
+                if first["ts"] < e["ts"]]
 
-    def test_an_admission_lands_the_steps_in_flight_first(self):
+    def test_an_admission_with_a_hole_joins_the_queued_steps(self):
+        """The prefill is dispatched behind the steps in flight, not
+        after a landing; the next step takes the joiner into a hole of
+        its bucket; the joiner's first token reaches its stream before
+        its second; both streams are the tokens each gets alone."""
+        from deeplearning4j_tpu.common import telemetry, tracectx
         model, pool, eng = _engine(decode_buckets=(4,))
         s1 = eng.submit(np.array([5, 9, 2, 7]), 40)
-        assert s1.next(timeout=30) is not None
-        assert s1.next(timeout=30) is not None
-        s2 = eng.submit(np.array([8, 3]), 6)
+        head = [s1.next(timeout=30), s1.next(timeout=30)]
+        ctx = tracectx.start("t-gen", "generate")
+        s2 = eng.submit(np.array([8, 3]), 6, ctx=ctx)
         t2 = list(s2)
-        t1 = list(s1)
+        t1 = head + list(s1)
         eng.shutdown()
         assert t2 == self._alone(model, eng, [8, 3], 6)
-        assert len(t1) == 38
+        assert t1 == self._alone(model, eng, [5, 9, 2, 7], 40)
+        assert eng.retraces_since_warmup() == 0
         ev = _spans()
-        admits = [e for e in ev if e["name"] == "generate.admit"
-                  and e["args"]["admitted"]]
-        assert len(admits) == 2
-        second = admits[1]
-        before = [e for e in ev
-                  if e["args"]["iter"] == second["args"]["iter"]
-                  and e["args"].get("parent") == "generate.iteration"
-                  and e["ts"] < second["ts"]]
-        from deeplearning4j_tpu.serving.generative import RUN_AHEAD
-        names = [e["name"] for e in sorted(before,
-                                           key=lambda e: e["ts"])]
-        assert 1 <= len(names) // 2 <= RUN_AHEAD
-        assert names == ["generate.pull", "generate.emit"] \
-            * (len(names) // 2)
+        admits = _named(ev, "generate.admit")
+        assert [(a["args"]["admitted"], a["args"]["behind"],
+                 a["args"]["joined"]) for a in admits] == [(1, 0, 0),
+                                                           (1, 1, 1)]
+        i = admits[1]["args"]["iter"]
+
+        def kids(j):
+            return [k["name"] for k in sorted(
+                (k for k in ev if k["args"].get("iter") == j
+                 and k["args"].get("parent") == "generate.iteration"),
+                key=lambda k: k["ts"])]
+        # no landing: nothing is pulled before the admission, and after
+        # it the one program that is due, the oldest step in flight;
+        # the admitting pass builds no step, the next one does
+        assert kids(i) == ["generate.admit", "generate.pull",
+                           "generate.emit"]
+        assert kids(i + 1)[:2] == ["generate.build",
+                                   "generate.decode_step"]
+        (step,) = _named(ev, "generate.decode_step", iter=i + 1)
+        assert step["args"]["live"] == 2 and step["args"]["bucket"] == 4
+        # the joiner's first token is read back once it is the oldest
+        # thing in flight, and handed out before its second
+        (prefill,) = _named(ev, "generate.prefill", seq=s2.seq_id)
+        assert prefill["args"]["parent"] == "generate.admit" \
+            and prefill["args"]["iter"] == i
+        second = [e for e in telemetry.trace_events()
+                  if e["name"] == "req.inter_token"
+                  and e["args"]["trace"] == ctx.trace_id
+                  and e["args"]["index"] == 1]
+        assert len(second) == 1
+        assert prefill["ts"] + prefill["dur"] <= second[0]["ts"]
+        counted = telemetry.counter("dl4j_generate_admissions_total", "")
+        assert counted.value(model="t-gen", path="idle") == 1
+        assert counted.value(model="t-gen", path="joined") == 1
 
     @pytest.mark.parametrize("how", ["eos", "cancel", "deadline"])
     def test_what_a_step_computed_for_a_retired_row_is_dropped(
@@ -998,11 +1064,12 @@ class _Stepper:
         self.parked = threading.Event()
         once = eng._decode_iteration
 
-        def gated():
-            once()
+        def gated(*a):
+            queued = once(*a)
             if not self.free:
                 self.parked.set()
                 assert self.sem.acquire(timeout=60)
+            return queued
         eng._decode_iteration = gated
 
     def park(self):
@@ -1072,13 +1139,31 @@ class TestAdmissionRecords:
                 if e["ts"] <= t <= e["ts"] + e["dur"]]
         return e
 
+    def _steps_of(self, ev, i):
+        """The decode steps dispatched by iterations ``i - 1`` and
+        ``i + 1`` (before and after an admission at ``i``, whose pass
+        dispatches none) and the emits that handed their tokens out:
+        each program in flight is pulled by the pass that queues
+        ``RUN_AHEAD`` more behind it, and the admission's pass queues a
+        prefill where it queues no step."""
+        from deeplearning4j_tpu.serving.generative import RUN_AHEAD
+        out = []
+        for j in (i - 1, i + 1):
+            (step,) = _named(ev, "generate.decode_step", iter=j)
+            (emit,) = _named(ev, "generate.emit", iter=j + RUN_AHEAD)
+            out.append((step, emit))
+        return out
+
     def test_an_admission_adds_one_record_and_none_to_its_iteration(
             self):
-        """The spans of the admitting iteration are what they were:
-        the landed pairs, ``generate.admit`` with its prefill, the
-        build and the restart. The stall is the one new record, and it
-        belongs to the iteration that closed it."""
-        from deeplearning4j_tpu.serving.generative import RUN_AHEAD
+        """The admitting iteration lands nothing and builds no step:
+        after ``generate.admit`` it pulls and emits the oldest step in
+        flight, which is due, and the next iteration builds and
+        dispatches the step that takes the joiner, a step like any
+        other. The prefill's record is written when its first token is
+        pulled (its ``parent`` and ``iter`` the admission's); the stall
+        is the one new record, and it belongs to the iteration that
+        closed it."""
         model, pool, eng = _engine()
         ctl, s1 = self._decoding(eng)
         s2 = eng.submit(np.array([8, 3]), 6)
@@ -1088,27 +1173,34 @@ class TestAdmissionRecords:
         ev = _spans()
         assert all(e["dur"] >= 0 for e in ev)
         second = _named(ev, "generate.admit", admitted=1)[1]
+        assert second["args"]["behind"] == second["args"]["joined"] == 1
         i = second["args"]["iter"]
         mine = [e for e in ev if e["args"].get("iter") == i]
-        assert not _named(mine, "generate.stall")
-        kids = sorted((e for e in mine if e["args"].get("parent")
-                       == "generate.iteration"), key=lambda e: e["ts"])
-        names = [e["name"] for e in kids]
-        landed = names.index("generate.admit")
-        assert 1 <= landed // 2 <= RUN_AHEAD
-        assert names == ["generate.pull", "generate.emit"] \
-            * (landed // 2) + ["generate.admit", "generate.build",
-                               "generate.decode_step"]
+        after = [e for e in ev if e["args"].get("iter") == i + 1]
+        assert not _named(mine + after, "generate.stall")
+
+        def kids(of):
+            return sorted((e for e in of if e["args"].get("parent")
+                           == "generate.iteration"),
+                          key=lambda e: e["ts"])
+        assert [e["name"] for e in kids(mine)] == [
+            "generate.admit", "generate.pull", "generate.emit"]
+        assert [e["name"] for e in kids(after)] == [
+            "generate.build", "generate.decode_step", "generate.emit"]
         (prefill,) = _named(mine, "generate.prefill")
-        assert _inside(prefill, second)
-        assert not [e for e in mine
+        assert prefill["args"]["parent"] == "generate.admit"
+        assert second["ts"] <= prefill["ts"] \
+            <= second["ts"] + second["dur"]
+        assert prefill["ts"] + prefill["dur"] > kids(after)[-1]["ts"]
+        assert not [e for e in ev
                     if e["args"].get("parent") == "generate.prefill"]
-        # the restart is a step like any other: one dispatch of the
-        # decode program, nothing pulled behind it yet
-        assert [(e["name"], e["args"]["program"]) for e in mine
+        # a step like any other: one dispatch of the decode program,
+        # the pull of the oldest program in flight behind it
+        assert [(e["name"], e["args"].get("program")) for e in after
                 if e["args"].get("parent") == "generate.decode_step"] \
-            == [("generate.dispatch", "decode_step")]
-        assert kids[-1]["args"]["live"] == 2
+            == [("generate.dispatch", "decode_step"),
+                ("generate.pull", None)]
+        assert kids(after)[1]["args"]["live"] == 2
         assert {e["name"] for e in ev} == {
             "generate.iteration", "generate.admit", "generate.prefill",
             "generate.build", "generate.decode_step",
@@ -1118,6 +1210,10 @@ class TestAdmissionRecords:
             == len(_named(ev, "generate.decode_step"))
 
     def test_a_stall_carries_the_rows_that_waited(self):
+        """In device order: from the emit of the last step dispatched
+        before the prefill to the emit of the first dispatched after
+        it; between them only the prefill's first token is pulled and
+        handed out, the one program that ran between the two steps."""
         model, pool, eng = _engine()
         ctl, s1 = self._decoding(eng)
         s2 = eng.submit(np.array([8, 3]), 6)
@@ -1131,22 +1227,22 @@ class TestAdmissionRecords:
                           "prompt_tokens"}
         assert a["rows"] == 1 and a["prefills"] == 1 \
             and a["prompt_tokens"] == 2 and a["model"] == "t-gen"
-        # it starts in the last emit of the landing before the
-        # admission and ends in the first emit after it, whose
-        # iteration it names
         admit = _named(ev, "generate.admit", admitted=1)[1]
-        opened = self._emit_at(ev, stall["ts"])
-        closed = self._emit_at(ev, stall["ts"] + stall["dur"])
-        assert opened["args"]["iter"] == admit["args"]["iter"] \
-            and opened["ts"] + opened["dur"] <= admit["ts"]
-        assert not [e for e in _named(ev, "generate.emit")
-                    if opened["ts"] < e["ts"] < closed["ts"]]
-        assert closed["args"]["tokens"] == 2
-        assert closed["args"]["iter"] == a["iter"] \
-            > admit["args"]["iter"]
+        (_, opened), (closing, closed) = self._steps_of(
+            ev, admit["args"]["iter"])
+        assert opened is self._emit_at(ev, stall["ts"])
+        assert closed is self._emit_at(ev, stall["ts"] + stall["dur"])
+        (between,) = [e for e in _named(ev, "generate.emit")
+                      if opened["ts"] < e["ts"] < closed["ts"]]
+        assert between["args"]["tokens"] == 0 \
+            and between["args"]["iter"] == closed["args"]["iter"] - 1
+        assert closing["args"]["live"] == closed["args"]["tokens"] == 2
+        assert closed["args"]["iter"] == a["iter"]
         (prefill,) = [e for e in _named(ev, "generate.prefill")
-                      if _inside(e, admit)]
-        assert _inside(prefill, stall)
+                      if e["args"]["iter"] == admit["args"]["iter"]]
+        assert prefill["ts"] < stall["ts"] \
+            < prefill["ts"] + prefill["dur"] <= between["ts"] \
+            + between["dur"] <= closed["ts"]
         assert self._observed() == 1
 
     def test_two_requests_queued_together_are_one_episode(self):
@@ -1159,25 +1255,57 @@ class TestAdmissionRecords:
         list(s1)
         eng.shutdown()
         ev = _spans()
-        assert [e["args"]["admitted"]
-                for e in _named(ev, "generate.admit")][:2] == [1, 2]
+        assert [(e["args"]["admitted"], e["args"]["joined"])
+                for e in _named(ev, "generate.admit")][:2] \
+            == [(1, 0), (2, 2)]
         (stall,) = _named(ev, "generate.stall")
         a = stall["args"]
         assert a["prefills"] == 2 and a["prompt_tokens"] == 5
         assert a["rows"] == 1               # s1 alone was decoding
-        assert sum(_inside(e, stall)
+        # both first tokens are read back inside it, with its last step
+        end = stall["ts"] + stall["dur"]
+        assert sum(stall["ts"] <= e["ts"] + e["dur"] <= end
                    for e in _named(ev, "generate.prefill")) == 2
+        assert self._observed() == 1
+
+    def test_admissions_in_two_passes_in_a_row_are_one_episode(self):
+        """A pass that admits behind the steps in flight builds no
+        step, but never two passes in a row: the second admitting pass
+        builds the step that takes both joiners, which closes the one
+        episode they make."""
+        model, pool, eng = _engine()
+        ctl, s1 = self._decoding(eng)
+        s2 = eng.submit(np.array([8, 3]), 6)
+        ctl.one_pass()                      # admits s2, builds no step
+        s3 = eng.submit(np.array([4, 4, 1]), 5)
+        ctl.go()
+        assert len(list(s2)) == 6 and list(s3)
+        list(s1)
+        eng.shutdown()
+        ev = _spans()
+        admits = _named(ev, "generate.admit", admitted=1)
+        i = admits[1]["args"]["iter"]
+        assert admits[2]["args"]["iter"] == i + 1
+        assert [e["args"]["joined"] for e in admits] == [0, 1, 1]
+        assert not _named(ev, "generate.decode_step", iter=i)
+        (step,) = _named(ev, "generate.decode_step", iter=i + 1)
+        assert step["args"]["live"] == 3
+        (stall,) = _named(ev, "generate.stall")
+        assert stall["args"]["prefills"] == 2 \
+            and stall["args"]["rows"] == 1
         assert self._observed() == 1
 
     def test_an_episode_closes_at_the_first_emit_after_wherever_it_is(
             self):
-        """The restart step of one admission is landed by the next:
-        the emit that closes the first episode is one of the second
-        admission's landing, and opens the second."""
+        """Two admissions with a step dispatched between them are two
+        episodes: the step after the first admitting pass closes the
+        first and, being the last step before the second prefill, opens
+        the second; its emit is both ends."""
         model, pool, eng = _engine()
         ctl, s1 = self._decoding(eng)
         s2 = eng.submit(np.array([8, 3]), 9)
-        ctl.one_pass()                      # admits s2, restarts
+        ctl.one_pass()                      # admits s2
+        ctl.one_pass()                      # dispatches the step for it
         s3 = eng.submit(np.array([4, 4, 1]), 5)
         ctl.go()
         assert len(list(s2)) == 9 and list(s3)
@@ -1186,18 +1314,23 @@ class TestAdmissionRecords:
         ev = _spans()
         first, second = _named(ev, "generate.stall")
         admits = _named(ev, "generate.admit", admitted=1)
-        assert first["args"]["iter"] == admits[2]["args"]["iter"] \
-            == admits[1]["args"]["iter"] + 1
-        closing = self._emit_at(ev, first["ts"] + first["dur"])
-        assert closing["args"]["parent"] == "generate.iteration"
-        assert closing["args"]["iter"] == admits[2]["args"]["iter"] \
-            and closing["ts"] + closing["dur"] <= admits[2]["ts"]
+        assert admits[2]["args"]["iter"] == admits[1]["args"]["iter"] + 2
+        assert [e["args"]["joined"] for e in admits] == [0, 1, 1]
+        (_, opened), (closing, closed) = self._steps_of(
+            ev, admits[1]["args"]["iter"])
+        assert opened is self._emit_at(ev, first["ts"])
+        assert closed is self._emit_at(ev, first["ts"] + first["dur"])
+        assert first["args"]["iter"] == closed["args"]["iter"]
         assert first["args"]["rows"] == 1 \
-            and closing["args"]["tokens"] == 2
+            and closed["args"]["tokens"] == 2
         # the second starts where the first ended: the same emit
         assert abs(second["ts"] - first["ts"] - first["dur"]) <= 2
+        (_, reopened), (_, reclosed) = self._steps_of(
+            ev, admits[2]["args"]["iter"])
+        assert reopened is closed
+        assert reclosed is self._emit_at(ev, second["ts"] + second["dur"])
         assert second["args"]["rows"] == 2
-        assert second["args"]["iter"] > first["args"]["iter"]
+        assert second["args"]["iter"] == first["args"]["iter"] + 2
         assert self._observed() == 2
 
     def test_an_admission_into_an_idle_engine_writes_no_stall(self):
@@ -1211,30 +1344,47 @@ class TestAdmissionRecords:
         assert not _named(ev, "generate.stall")
         assert self._observed() == 0
 
-    def test_the_benchmarks_reader_reads_this_engines_ring(self):
-        """``chipbench/readers/admit_stall.py`` over what a real engine
-        wrote: the names the program writes and the names the reader
-        looks for are the same names."""
-        from chipbench.readers import admit_stall
+    @pytest.mark.parametrize("joiners", [1, 2])
+    def test_the_benchmarks_reader_reads_this_engines_ring(self, joiners):
+        """``chipbench/readers/admit_stall.py`` and ``span_attr``, as
+        the benchmark's metric files name them, over what a real engine
+        wrote: the names the program writes and the names the readers
+        look for are the same names: one or two joiners, admitted
+        together into the holes of a bucket of four. ``span_attr`` also
+        reads the share of the prefills dispatched behind the steps in
+        flight that joined them, ``joined`` over ``behind``."""
+        from chipbench import harness
+        from chipbench.readers import span_attr
         model, pool, eng = _engine()
         ta = time.perf_counter()
         ctl, s1 = self._decoding(eng)
-        s2 = eng.submit(np.array([8, 3]), 6)
+        streams = [eng.submit(np.array(p), 6)
+                   for p in ([8, 3], [4, 4, 1])[:joiners]]
         ctl.go()
-        list(s2), list(s1)
+        [list(s) for s in streams], list(s1)
         eng.shutdown()
         tb = time.perf_counter()
         view = {"host_window": (ta, tb), "window": (50.0, 50.0 + tb - ta),
                 "records": {"t0": ta, "t_end": tb}}
         ev = _spans()
         (stall,) = _named(ev, "generate.stall")
-        assert admit_stall.read(view, "ms") \
+        got = harness.read_metrics(
+            ["admit_stall_ms_p50", "admit_stall_ms_p95", "admit_gap_share",
+             "prefill_ms_p50"], view, "t-gen")
+        assert got["admit_stall_ms_p50"] \
             == pytest.approx(stall["dur"] * 1e-3, abs=2e-3)
-        assert admit_stall.read(view, "ms", percentile=95) \
-            == admit_stall.read(view, "ms")
-        assert admit_stall.read(view, "gap_share") == pytest.approx(
+        assert got["admit_stall_ms_p95"] == got["admit_stall_ms_p50"]
+        assert got["admit_gap_share"] == pytest.approx(
             100 / sum(e["args"]["tokens"]
                       for e in _named(ev, "generate.emit")))
+        # every prefill admitted behind the steps in flight joined them
+        assert span_attr.read(view, span="generate.admit", num="joined",
+                              den="behind", stat="mean") == 100.0
+        behind = [e for e in _named(ev, "generate.admit")
+                  if e["args"]["behind"]]
+        assert [e["args"]["joined"] for e in behind] == [joiners]
+        # the prefill spans reach their first tokens' pull
+        assert got["prefill_ms_p50"] > 0
 
     @pytest.mark.parametrize("buckets", [(2,), (2, 4)])
     def test_a_landing_for_the_rows_sake_is_no_admission(self, buckets):
@@ -1253,12 +1403,47 @@ class TestAdmissionRecords:
         eng.shutdown()
         ev = _spans()
         (admit,) = _named(ev, "generate.admit", admitted=3)
+        assert admit["args"]["joined"] == admit["args"]["behind"] == 0
         landed = [e for e in _named(ev, "generate.pull")
                   if e["args"].get("parent") == "generate.iteration"
                   and e["ts"] > admit["ts"]]
         assert landed
         assert not _named(ev, "generate.stall")
         assert self._observed() == 0
+
+    def test_more_joiners_than_holes_land_the_steps_and_pack_afresh(
+            self):
+        """Two requests behind steps whose bucket of two has one hole:
+        both are dispatched behind the queue, then the next step does
+        not fit them, so what is in flight lands (first tokens and
+        all) and the rows are packed afresh, one sequence waiting for a
+        row. ``generate.admit`` says so (``joined`` 0 of ``behind``
+        2), and so does the counter; every stream is its own run's."""
+        from deeplearning4j_tpu.common import telemetry
+        model, pool, eng = _engine(decode_buckets=(2,))
+        ctl, s1 = self._decoding(eng)
+        s2 = eng.submit(np.array([8, 3]), 6)
+        s3 = eng.submit(np.array([4, 4, 1]), 5)
+        ctl.go()
+        t2, t3 = list(s2), list(s3)
+        list(s1)
+        eng.shutdown()
+        alone = TestStepInFlight._alone
+        assert t2 == alone(model, eng, [8, 3], 6)
+        assert t3 == alone(model, eng, [4, 4, 1], 5)
+        assert eng.retraces_since_warmup() == 0 and pool.live_blocks == 0
+        ev = _spans()
+        admit = _named(ev, "generate.admit", admitted=2)[0]
+        assert admit["args"]["behind"] == 2 and admit["args"]["joined"] == 0
+        i = admit["args"]["iter"]
+        assert _named(ev, "generate.pull", iter=i,
+                      parent="generate.iteration")
+        counted = telemetry.counter("dl4j_generate_admissions_total", "")
+        assert counted.value(model="t-gen", path="landed") == 2
+        assert counted.value(model="t-gen", path="joined") == 0
+        # the episode's rows waited through the landing and the restart
+        (stall,) = _named(ev, "generate.stall")
+        assert stall["args"]["prefills"] == 2 and stall["args"]["rows"] == 1
 
     @pytest.mark.parametrize("kind", ["decoder", "falcon-h1"])
     def test_a_step_span_carries_no_count_of_state_slots(self, kind):

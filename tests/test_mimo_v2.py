@@ -463,3 +463,18 @@ def test_layer_kinds_are_named_in_the_lowered_program(want):
         np.zeros((1, 4), i32), np.zeros((1,), i32)).as_text(debug_info=True)
     for scope in ("mixer.full", "mixer.window", "ffn.experts", "moe.dense"):
         assert scope in text
+
+
+def test_a_joiner_takes_the_row_slot_and_blocks_of_a_retired_sequence(
+        joins_a_retired_row):
+    """The rings and blocks a cancelled sequence held go to the request
+    admitted behind the steps that still write them; the prompt's
+    routing counts, pulled with its first token, are on its
+    ``generate.prefill``."""
+    model, params, pool, eng = _engine(state_slots=3, decode_buckets=(2,))
+    prefill = joins_a_retired_row(model, params, pool, eng)
+    assert prefill["tokens"] == 9
+    assert prefill["moe_rows_all"] == 9 * 4 * 3
+    assert prefill["moe_experts_held"] == 12
+    assert prefill["moe_experts_hit"] <= prefill["moe_rows"] \
+        <= prefill["moe_rows_all"]

@@ -399,3 +399,13 @@ def test_mixer_kinds_are_named_in_the_lowered_program(want):
     for kind in ("mamba", "window", "full", "gmu", "cross"):
         assert f"mixer.{kind}" in text
 
+
+
+def test_a_joiner_takes_the_row_slot_and_blocks_of_a_retired_sequence(
+        joins_a_retired_row):
+    """The slot a cancelled sequence held (its Mamba state and its
+    window rings) goes to the request admitted behind the steps that
+    still write it: their writes land before its commit."""
+    model, params, pool, eng = _engine(state_slots=3, decode_buckets=(2,))
+    prefill = joins_a_retired_row(model, params, pool, eng)
+    assert prefill["tokens"] == 9 and prefill["positions"] == 16
