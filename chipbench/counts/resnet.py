@@ -1,4 +1,4 @@
-"""Operations and bytes of a bottleneck ResNet, from its sizes alone."""
+"""Operations of a bottleneck ResNet, from its sizes alone."""
 
 
 def conv_sites(cfg):
@@ -43,11 +43,3 @@ def train_flops(cfg):
     _, oh, ow, kh, kw, ci, co = sites[0]
     return 3 * forward_flops(cfg) - 2 * oh * ow * kh * kw * ci * co
 
-
-def bn_bytes(cfg, act_bytes=2):
-    """Bytes one sample's batch-norm sites need, forward and backward, as
-    stand-alone passes over the activation in the compute type: statistics read
-    x; normalise reads x and writes y; backward's two sums read x and dy; dx
-    reads x and dy and writes dx: eight passes."""
-    sites, _ = conv_sites(cfg)
-    return sum(8 * oh * ow * co * act_bytes for _, oh, ow, _, _, _, co in sites)

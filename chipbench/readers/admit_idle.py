@@ -1,18 +1,22 @@
 """The device's idle time round an admission, from the device's trace alone. On the device
-an admission is the cluster of programs of other kinds (prefill, commit, first-token
-sample; one such triple a request admitted) between two programs of ``kind`` (the decode
-step); its idle time is the complement of the union of ``XLA Ops`` from the end of the step
-before the cluster to the start of the step after it. A percentile over the clusters that
-lie whole inside the traced window, in ms.
+an admission is the cluster of programs of other kinds (prefill, commit and first-token
+sample, one such triple a request admitted, and the merge of first tokens into a step)
+between two programs of ``kind`` (the decode step); its idle time is the complement of the
+union of ``XLA Ops`` from the end of the step before the cluster to the start of the step
+after it. A percentile over the clusters that lie whole inside the traced window, in ms.
 
 ``phase`` splits a cluster's idle by the device's own edges: ``before`` the cluster's first
-program starts (the host pulls and hands out the last step in flight, then prepares and
-dispatches the prefill), ``after`` its last program ends (the host pulls the first token,
-builds a step afresh and dispatches it) and ``within``, the rest (between one request's
-sample and the next one's prefill where an admission took several, and inside the
-programs). The three add up to the cluster's idle; medians need not. The host's spans could
-not split it finer: the pairs that bound the skew between the two clocks at an admission
-leave 1.8-2.5 ms (a launch one way, a completion the other), as wide as the pieces."""
+program starts, ``after`` its last program ends, and ``within``, the rest (between one
+request's programs and the next one's where an admission took several, and inside the
+programs). Where the admission joins the queued steps, its programs are dispatched behind
+the steps in flight and the next step behind them, so ``before`` and ``after`` are launch
+gaps of microseconds; where an engine lands its queue first, ``before`` holds the host's
+hand-out of the last step and the prefill's dispatch, and ``after`` the first token's pull
+and a step built afresh. The three add up to the cluster's idle; medians need not. The
+host's spans could not split it finer: the pairs that bound the skew between the two clocks
+at an admission leave 1.8-2.5 ms (a launch one way, a completion the other), as wide as the
+pieces."""
+import bisect
 import sys
 
 import numpy as np
@@ -48,9 +52,12 @@ def split(view, kind):
         return None
     busy = tr.union(dev["ops"], lo, hi)
     idle = [(a[1], b[0]) for a, b in zip([[lo, lo]] + busy, busy + [[hi, hi]]) if b[0] > a[1]]
+    starts = [s for s, _ in idle]
 
     def idle_in(a, b):
-        return sum(max(0.0, min(e, b) - max(s, a)) for s, e in idle)
+        # the gaps are disjoint and in order: only those from the one holding a to b count
+        near = idle[max(bisect.bisect_right(starts, a) - 1, 0):bisect.bisect_left(starts, b)]
+        return sum(max(0.0, min(e, b) - max(s, a)) for s, e in near)
 
     parts = {p: [] for p in ("total",) + PHASES}
     for a_end, b_start, mods in found:

@@ -2,17 +2,16 @@
 the traced window did (counted from shapes by the configuration's ``counts`` module),
 over a time from the trace times the chip's peak.
 
-``work``: ``train`` (FLOPs of the whole steps in the window), ``bn`` (bytes of their
-batch-norm sites), ``generate`` (FLOPs of every token that arrived in the window, prefill
-or decode), ``decode`` / ``paged_attention`` (FLOPs / attention bytes of the tokens of
-the whole decode steps in the window).
+``work``: ``train`` (FLOPs of the whole steps in the window), ``generate`` (FLOPs of
+every token that arrived in the window, prefill or decode), ``decode`` /
+``paged_attention`` (FLOPs / attention bytes of the tokens of the whole decode steps in
+the window).
 ``over``: ``window`` (its length, times the chips), ``programs`` (the device time of the
 programs the work was done by) or ``ops`` (the device time of the operations inside
 them whose short name matches ``ops``)."""
 from chipbench import trace as tr
 
-KIND = {"train": "train_step", "bn": "train_step", "decode": "decode_step",
-        "paged_attention": "decode_step"}
+KIND = {"train": "train_step", "decode": "decode_step", "paged_attention": "decode_step"}
 
 
 def _arrivals(view):
@@ -33,9 +32,8 @@ def read(view, work, over, ops=None):
         mods = tr.modules_of(dev, KIND[work], lo, hi)
         if len(mods) < 2:
             return None
-        if work in ("train", "bn"):
-            samples = len(mods) * view["records"]["samples_per_step"]
-            amount = samples * (counts.train_flops(cfg) if work == "train" else counts.bn_bytes(cfg))
+        if work == "train":
+            amount = len(mods) * view["records"]["samples_per_step"] * counts.train_flops(cfg)
         else:
             # A step's tokens reach the client just after its program ends: take the
             # tokens of every whole step of the window but the first.
@@ -58,6 +56,5 @@ def read(view, work, over, ops=None):
         seconds = tr.op_seconds(dev, ops, mods[0][0], mods[-1][1], KIND[work])
     if not seconds:
         return None
-    bytes_bound = work in ("bn", "paged_attention")
-    peak = view["peaks"]["hbm_bytes_per_s" if bytes_bound else "bf16_flops"]
+    peak = view["peaks"]["hbm_bytes_per_s" if work == "paged_attention" else "bf16_flops"]
     return 100.0 * amount / (seconds * peak)

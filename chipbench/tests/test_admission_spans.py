@@ -3,6 +3,7 @@
 import json
 import os
 
+import numpy as np
 import pytest
 
 from chipbench import harness
@@ -96,6 +97,31 @@ def test_admit_idle_counts_the_idle_between_two_requests_of_one_admission_within
     assert sum(v for p, v in got.items() if p != "total") == pytest.approx(got["total"])
 
 
+def test_admit_idle_sums_the_gaps_round_each_cluster_as_a_walk_over_all_of_them():
+    """Thousands of short operations, hundreds of gaps: the reader looks only at the gaps
+    near a cluster and reads what a sum over every gap of the window reads."""
+    from chipbench import trace as tr
+    from chipbench.readers import admit_idle
+    view = admission_view(prefills=2)
+    dev = view["trace"]["devices"][0]
+    lo, hi = view["window"]
+    rs = np.random.RandomState(7)
+    starts = np.sort(rs.uniform(lo, hi, 4000))
+    dev["ops"] = sorted(dev["ops"] + [(s, s + d, "%copy.1 = f32[] copy()") for s, d
+                                      in zip(starts, rs.uniform(1e-6, 2e-5, starts.size))])
+    busy = tr.union(dev["ops"], lo, hi)
+    idle = [(a[1], b[0]) for a, b in zip([[lo, lo]] + busy, busy + [[hi, hi]]) if b[0] > a[1]]
+    walk = lambda a, b: sum(max(0.0, min(e, b) - max(s, a)) for s, e in idle)  # noqa: E731
+    want = {"total": [], "before": [], "after": []}
+    for a_end, b_start, mods in admit_idle.clusters(dev, "decode_step", lo, hi):
+        want["total"].append(walk(a_end, b_start))
+        want["before"].append(walk(a_end, mods[0][0]))
+        want["after"].append(walk(max(m[1] for m in mods), b_start))
+    got = admit_idle.split(view, "decode_step")
+    assert len(idle) > 500 and len(want["total"]) == 3
+    assert {p: got[p] for p in want} == want
+
+
 @pytest.mark.parametrize("skew", [SKEW, 0.0, 3.3 * MS])
 @pytest.mark.parametrize("how", ["parent", "no_ring"])
 def test_admit_idle_needs_no_record_of_the_program_and_no_clock_but_the_devices(skew, how):
@@ -138,15 +164,56 @@ STALL = ["admit_stall_ms_p50", "admit_stall_ms_p95", "admit_gap_share"]
 IDLE = ["admit_idle_ms_p50"] + [f"admit_idle_ms_p50.{p}" for p in ("before", "within", "after")]
 
 
+def served_cells(bench):
+    """The cells that report the rate of served tokens."""
+    return {w for m in bench["end_to_end"] if m["name"] == "gen_tok_per_s" for w in m["workloads"]}
+
+
+#: the admission's entries in ``per_layer``: source, the end-to-end metric each moves, unit
+ENTRIES = {**dict.fromkeys(STALL[:2], ("program_span", "itl_p95_ms", "ms")),
+           STALL[2]: ("program_counter", "itl_p95_ms", "%"),
+           **dict.fromkeys(IDLE, ("device_trace", "gen_tok_per_s", "ms")),
+           "admit_joined_share": ("program_counter", "gen_tok_per_s", "%")}
+
+
+def check_admission_entries(bench, root=harness.ROOT):
+    """The admission's entries in ``bench``, by name and wherever they stand in
+    ``per_layer``: their fields, every served cell among their workloads, and their files
+    under ``root``, none of them ``required``."""
+    mine = {m["name"]: m for m in bench["per_layer"] if m["name"] in ENTRIES}
+    assert set(mine) == set(ENTRIES)
+    for name, m in mine.items():
+        assert (m["source"], m["moves"], m["unit"]) == ENTRIES[name]
+        assert set(m["workloads"]) == served_cells(bench) and m["layer"] == "generative engine"
+        with open(os.path.join(root, "chipbench", "metrics", f"{name}.json")) as f:
+            assert not json.load(f).get("required")
+
+
 def test_the_admission_metrics_are_read_by_name_and_the_stall_falls_silent_on_the_parents_program():
     got = harness.read_metrics(STALL + IDLE, admission_view(), "x")
     assert set(got) == set(STALL + IDLE) and got["admit_idle_ms_p50.after"] == pytest.approx(5.2)
     assert set(harness.read_metrics(STALL + IDLE, admission_view(parent=True), "x")) == set(IDLE)
-    bench = json.load(open(os.path.join(harness.ROOT, "BENCHMARK.json")))
-    served = [w["name"] for w in bench["workloads"] if w["config"] != "resnet50"]
-    mine = [m for m in bench["per_layer"] if m["name"] in STALL + IDLE]
-    assert [m["name"] for m in mine] == STALL + IDLE == [m["name"] for m in bench["per_layer"][-7:]]
-    assert all(m["workloads"] == served and m["layer"] == "generative engine" for m in mine)
-    assert [m["source"] for m in mine] == ["program_span"] * 2 + ["program_counter"] + ["device_trace"] * 4
-    assert not any(harness.load_json("chipbench", "metrics", f"{n}.json").get("required")
-                   for n in STALL + IDLE)
+    check_admission_entries(json.load(open(os.path.join(harness.ROOT, "BENCHMARK.json"))))
+
+
+def admits(*found):
+    """A ring of ``generate.admit`` spans, one a ``(behind, joined)``; None for a span of a
+    program whose admission has neither (it lands its queue before every prefill)."""
+    ring = []
+    for i, bj in enumerate(found):
+        args = {"iter": i + 1, "admitted": 1}
+        if bj is not None:
+            args.update(behind=bj[0], joined=bj[1])
+        ring.append((100 + 0.01 * i, 100.002 + 0.01 * i, "generate.admit", args))
+    return {"ring": ring, "records": {"t0": 99.0, "t_end": 101.0}}
+
+
+@pytest.mark.parametrize("found, want", [
+    (((1, 1), (2, 2), (1, 1)), 100.0),          # every joiner found a hole
+    (((1, 1), (2, 0), (1, 1), (0, 0)), 200 / 3),  # one landed; an idle engine's is no admission behind
+    (((0, 0), (0, 0)), None),                   # nothing was ever in flight
+    ((None, None), None),                       # a program that lands its queue: no such counts
+], ids=["all_joined", "one_landed", "none_behind", "landing_program"])
+def test_admit_joined_share_is_the_mean_over_the_admissions_behind_the_steps(found, want):
+    got = harness.read_metrics(["admit_joined_share"], admits(*found), "x")
+    assert got == ({} if want is None else {"admit_joined_share": pytest.approx(want)})

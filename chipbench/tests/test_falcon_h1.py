@@ -141,15 +141,10 @@ def chained_view(steps=8, skew=-1.2 * MS):
 
 
 def test_the_gaps_readers_read_a_loop_that_keeps_a_step_in_flight():
-    """The device no longer waits for the host between steps: the accepted readers find
-    the gap that is left, lay no more than it under any phase, and do not raise (they pair
-    a program with the dispatch nearest its start, which is now the next step's)."""
-    from chipbench.readers import gap_split, program_gap, span_attr
+    """The device no longer waits for the host between steps: the gap reader finds the
+    gap that is left, and the span reader reads the steps' counts without raising."""
+    from chipbench.readers import program_gap, span_attr
     view = chained_view()
     assert program_gap.read(view, "decode_step") == pytest.approx(0.02, abs=1e-6)
-    got = {p: gap_split.read(view, "decode_step", p, required=True)
-           for p in gap_split.PHASES + ("unattributed",)}
-    assert all(0.0 <= v <= 0.02 + 1e-6 for v in got.values())
-    assert sum(got.values()) == pytest.approx(0.02, abs=1e-6)
     assert span_attr.read(view, "generate.decode_step", "live", "bucket",
                           required=True) == pytest.approx(100 * 30 / 32)

@@ -44,24 +44,6 @@ def engine_view(steps=6, admit_before=3, ring=True):
     return view
 
 
-def test_gap_split_lays_the_engines_spans_on_the_gaps_and_finds_the_skew(capsys):
-    from chipbench.readers import gap_split, program_gap
-    view = engine_view()
-    # four gaps between decode steps (the one the admit falls in does not count)
-    assert program_gap.read(view, "decode_step") == pytest.approx(6.2, abs=1e-6)
-    got = {p: gap_split.read(view, "decode_step", p) for p in gap_split.PHASES + ("unattributed",)}
-    err = capsys.readouterr().err
-    assert err.count("gap_split decode_step") == 1      # worked out once, read six times
-    off = float(err.split("skew ")[1].split(" ms")[0])
-    assert off == pytest.approx(SKEW / MS, abs=0.1) and "0.700 ms wide, 4 gaps" in err
-    # the planted phases, each to the 0.05 ms by which the interval's midpoint misses
-    # launch latency after dispatch has returned lies under the next pull: unattributed
-    want = {"pull": 0.3, "emit": 2.0, "admit": 0.0, "build": 3.0, "dispatch": 0.3, "unattributed": 0.6}
-    assert got == {p: pytest.approx(v, abs=0.06) for p, v in want.items()}
-    assert sum(got.values()) == pytest.approx(6.2, abs=1e-6)
-    assert gap_split.read(view, "no_such_kind", "pull") is None
-
-
 def test_span_ms_and_span_attr_on_spans_made_by_hand():
     from chipbench.readers import span_attr, span_ms
     view = engine_view()
@@ -80,19 +62,16 @@ def test_span_ms_and_span_attr_on_spans_made_by_hand():
     assert span_ms.read(view, "generate.emit") is None
 
 
-NEW_GEN = [f"engine_gap_ms_p50.{p}" for p in ("pull", "emit", "admit", "build", "dispatch", "unattributed")] \
-    + ["decode_occupancy_share", "kv_pool_peak_share"]
+NEW_GEN = ["decode_occupancy_share", "kv_pool_peak_share"]
 
 
 def test_the_new_metrics_are_read_by_name_and_the_required_ones_may_not_fall_silent(monkeypatch):
     got = harness.read_metrics(NEW_GEN, engine_view(), "x")
     assert set(got) == set(NEW_GEN) and got["kv_pool_peak_share"] == pytest.approx(93.75)
     empty = engine_view(ring=False)         # a program with the clock and no generate.* span
-    assert harness.read_metrics(NEW_GEN[:5] + NEW_GEN[-1:], empty, "x") == {}
-    for name, said in (("engine_gap_ms_p50.unattributed", "no generate.* spans"),
-                       ("decode_occupancy_share", "no generate.decode_step span")):
-        with pytest.raises(RuntimeError, match=said):
-            harness.read_metrics([name], engine_view(ring=False), "x")
+    assert harness.read_metrics(["kv_pool_peak_share"], empty, "x") == {}
+    with pytest.raises(RuntimeError, match="no generate.decode_step span"):
+        harness.read_metrics(["decode_occupancy_share"], empty, "x")
     assert harness.read_metrics(["fit_host_ms_p50"], empty, "x") == {}
     # a program from before the one clock (the parent of PR 26) has nothing to place:
     # every new metric is left out, and none raises
